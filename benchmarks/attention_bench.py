@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.dirname(_HERE))
 sys.path.insert(0, _HERE)
 
 # the shared pure-function timing protocol (3-step post-compile warmup),
-# so attention rows are measured like every other hw_session row
+# so attention rows are measured like every other benchmark row
 from train_step_segments import timeit  # noqa: E402
 
 

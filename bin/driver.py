@@ -102,7 +102,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "numerics, ~N x lower optimizer memory")
     p.add_argument("--steps-per-call", type=int, default=1,
                    help="optimizer steps per dispatch (device loop; spmd=jit). "
-                        "Amortizes host dispatch when the runtime is tunneled")
+                        "Amortizes host dispatch latency")
     p.add_argument("--tp", type=int, default=None,
                    help="model-axis size for --spmd tp / fsdp_tp (mesh "
                         "becomes {data: N/tp, model: tp}; required for "
@@ -227,11 +227,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "regressions against")
     # cold-start performance (fluxdistributed_tpu.compilation)
     p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="enable JAX's persistent compilation cache here "
-                        "(topology-namespaced subdir): the next run on "
-                        "the same topology reads its XLA compiles from "
-                        "disk — attempt N+1 of a short TPU grant window "
-                        "skips attempt N's cold start")
+                   help="directory of JAX's persistent compilation "
+                        "cache (always on: the next run on the same "
+                        "topology reads its XLA compiles from disk). "
+                        "JAX_COMPILATION_CACHE_DIR wins when set; "
+                        "default: .jax_cache/ in the checkout")
     p.add_argument("--aot", default=None, metavar="DIR",
                    help="serialized train-step executables: load the "
                         "compiled step from DIR when topology + argument "
@@ -370,7 +370,7 @@ def main(argv=None) -> int:
     import jax
 
     import fluxdistributed_tpu as fd
-    from fluxdistributed_tpu import models, optim
+    from fluxdistributed_tpu import compilation, models, optim
     from fluxdistributed_tpu.data import SyntheticDataset
     from fluxdistributed_tpu.train import prepare_training, train
     from fluxdistributed_tpu.train.logging import ConsoleLogger, NullLogger
@@ -694,7 +694,7 @@ def main(argv=None) -> int:
         spmd=args.spmd,
         zero1=args.zero1,
         steps_per_call=args.steps_per_call,
-        cache_dir=args.compile_cache,
+        cache_dir=compilation.resolve_cache_dir(args.compile_cache),
         aot=args.aot,
         warmup=args.prewarm,
         strict_checks=args.strict_checks,
@@ -906,6 +906,13 @@ def main(argv=None) -> int:
             print(f"final eval: {parts}")
     if multihost.is_coordinator():
         print(f"done: {int(task.state.step)} steps, {task.num_missed} missed")
+    if task.num_missed:
+        # OOM-skip keeps a run alive, it does not make it a success: a
+        # run that skipped batches (all of them, at worst) must not
+        # look green to whatever started it
+        print(f"{task.num_missed} batch(es) were skipped on device OOM — "
+              "exit code 1 (reduce --batch-size)", file=sys.stderr)
+        return 1
     return 0
 
 

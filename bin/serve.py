@@ -181,10 +181,11 @@ def build_parser():
                         "port — the first request pays decode latency, "
                         "not the engine's whole compile pool (LM mode)")
     p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="enable JAX's persistent compilation cache here "
-                        "(topology-namespaced): a restarted server reads "
-                        "its XLA compiles from disk instead of redoing "
-                        "them")
+                   help="directory of JAX's persistent compilation "
+                        "cache (always on: a restarted server reads its "
+                        "XLA compiles from disk instead of redoing "
+                        "them).  JAX_COMPILATION_CACHE_DIR wins when "
+                        "set; default: .jax_cache/ in the checkout")
     p.add_argument("--aot-dir", default=None, metavar="DIR",
                    help="serialized-executable pool for the engine's "
                         "programs: load from disk when topology+model "
@@ -238,8 +239,7 @@ def make_lm_app(args):
     from fluxdistributed_tpu import compilation, models
     from fluxdistributed_tpu.serve import LMEngine
 
-    if args.compile_cache:
-        compilation.enable_persistent_cache(args.compile_cache)
+    compilation.enable_persistent_cache(args.compile_cache)
 
     model_fn = getattr(models, args.model, None)
     if model_fn is None or not args.model.startswith("lm_"):
@@ -313,10 +313,9 @@ def make_app(args):
     from fluxdistributed_tpu import models as models_lib
     from fluxdistributed_tpu.data.preprocess import preprocess
 
-    if args.compile_cache:
-        from fluxdistributed_tpu import compilation
+    from fluxdistributed_tpu import compilation
 
-        compilation.enable_persistent_cache(args.compile_cache)
+    compilation.enable_persistent_cache(args.compile_cache)
 
     factory = getattr(models_lib, args.model, None)
     if factory is None:

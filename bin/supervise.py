@@ -1,11 +1,12 @@
 #!/usr/bin/env python
 """Trainer supervisor — keep a ``bin/driver.py`` run finishing itself.
 
-The trainer-side analogue of the router's ``SupervisedReplica`` and the
-tested-Python generalization of ``benchmarks/hw_watch.sh``: spawn the
-driver, watch its heartbeats, classify every exit, and restart within a
-bounded budget — so a grant window survives crashes, preemptions AND
-wedged collectives with zero human input::
+The trainer-side analogue of the router's ``SupervisedReplica``: spawn
+the driver, watch its heartbeats, classify every exit, and restart
+within a bounded budget — so a run survives crashes, preemptions AND
+wedged collectives with zero human input.  The supervisor itself never
+imports jax: the chip belongs to the one driver child it runs at a
+time::
 
     python bin/supervise.py --ledger run/ledger.json -- \
         python bin/driver.py --model lm_tiny ... \

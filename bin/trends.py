@@ -15,8 +15,7 @@ topology fingerprint)::
 
     # backfill the ledger from archived round files (idempotent by
     # source basename — phase/retryable/probe_attempts preserved)
-    python bin/trends.py --ingest 'benchmarks/hw/BENCH_r*.json' \
-        'benchmarks/hw/MULTICHIP_r*.json'
+    python bin/trends.py --ingest 'BENCH_r*.json' 'MULTICHIP_r*.json'
 
     # one human-readable account of how a round died: newest flight
     # dump + supervisor episode ledger + bench phase status merged
@@ -67,8 +66,7 @@ def main(argv=None) -> int:
     p.add_argument("--supervisor-ledger", default=None, metavar="PATH",
                    help="supervisor episode ledger for --postmortem")
     p.add_argument("--bench-status", default=None, metavar="PATH",
-                   help="bench.py --resumable status JSON for "
-                        "--postmortem")
+                   help="a bench phase/status JSON for --postmortem")
     p.add_argument("--limit", type=int, default=20, metavar="N",
                    help="newest records to render (default 20)")
     args = p.parse_args(argv)

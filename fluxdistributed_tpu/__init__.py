@@ -13,7 +13,6 @@ The package targets full parity with the reference's exported surface
 below are the currently implemented subset.
 """
 
-from . import compat  # noqa: F401 — must precede any jax-surface use
 from . import (
     compilation,
     data,

@@ -1,184 +1,12 @@
-"""Toolchain compatibility shims.
+"""Memory-observability normalisers.
 
-The framework is written against the current JAX surface
-(``jax.shard_map`` with the ``check_vma`` keyword, PEP 680 ``tomllib``).
-Older toolchains — e.g. a Python 3.10 / jax 0.4.x image — carry the same
-functionality under earlier names (``jax.experimental.shard_map`` with
-``check_rep``, the ``tomli`` backport).  Importing this module (the
-package ``__init__`` does, before anything touches jax) installs
-forwarders so the rest of the codebase is written ONCE against the
-modern names:
-
-* ``jax.shard_map`` — forwarded to ``jax.experimental.shard_map`` when
-  absent, translating ``check_vma=`` to the old ``check_rep=`` spelling.
-* ``tomllib`` — aliased to ``tomli`` in ``sys.modules`` when the stdlib
-  module is missing (Python < 3.11), so plain ``import tomllib`` works.
-* :func:`configure_compilation_cache` — the persistent-compilation-
-  cache config knobs (``jax_compilation_cache_dir`` et al.) under their
-  several historical spellings; on a build with none of them the call
-  warns and reports False instead of crashing, so cache enablement is
-  always safe to leave on.
-* :func:`compiled_memory_analysis` / :func:`device_memory_stats` — the
-  memory-observability surface (``Compiled.memory_analysis()``,
-  ``Device.memory_stats()``) normalized to plain dicts, returning None
-  on builds/backends without it (CPU devices report no memory stats;
-  some jax builds lack ``memory_analysis`` entirely).  Every consumer
-  (obs.memstats, the HBM gauges, bin/fit.py) treats None as
-  "unavailable", never an error.
-
-No-ops on a modern toolchain.
+``Compiled.memory_analysis()`` and ``Device.memory_stats()`` as plain
+dicts, None where the backend reports nothing (CPU devices report no
+memory stats).  Every consumer (obs.memstats, the HBM gauges,
+bin/fit.py) treats None as "unavailable", never an error.
 """
 
 from __future__ import annotations
-
-import sys
-
-import jax
-
-# True when this process runs the pre-VMA shard_map (jax <= 0.4.x).  The
-# legacy tracer does NOT insert the psum that the modern varying-manual-
-# axes transpose adds when differentiating w.r.t. a replicated input
-# inside shard_map — code relying on that implicit gradient reduction
-# (dp.make_train_step_shardmap) must branch on this flag and reduce
-# explicitly.
-LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
-
-
-def _install_shard_map() -> None:
-    if hasattr(jax, "shard_map"):
-        return
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    legacy_params = inspect.signature(_legacy).parameters
-
-    def shard_map(f=None, /, **kwargs):
-        if f is None:  # used as @partial(jax.shard_map, mesh=..., ...)
-            # keep kwargs untranslated in the curried form: translation
-            # must run exactly once, at the final call, or an explicit
-            # check_vma=True would be clobbered by the re-entry default
-            import functools
-
-            return functools.partial(shard_map, **kwargs)
-        if "check_vma" not in legacy_params:
-            # the legacy replication checker predates the modern varying-
-            # manual-axes inference and rejects valid programs (e.g. the
-            # psum implicit in differentiating w.r.t. replicated params),
-            # so it is only enabled on explicit request
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            else:
-                kwargs.setdefault("check_rep", False)
-        return _legacy(f, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-def _install_tree_paths() -> None:
-    """jax.tree.{leaves,flatten,map}_with_path appeared after 0.4.37;
-    forward them to the long-stable jax.tree_util spellings."""
-    import jax.tree
-    import jax.tree_util as tu
-
-    for name, impl in (
-        ("leaves_with_path", tu.tree_leaves_with_path),
-        ("flatten_with_path", tu.tree_flatten_with_path),
-        ("map_with_path", tu.tree_map_with_path),
-    ):
-        if not hasattr(jax.tree, name):
-            setattr(jax.tree, name, impl)
-
-
-def _install_vma_stubs() -> None:
-    """``jax.typeof`` / ``jax.lax.pcast`` are the VMA-era typing surface
-    (pipeline code uses them to mark values varying before ppermute).
-    The legacy tracer has no replication typing — every value is
-    effectively varying — so a no-op pcast and an aval-returning typeof
-    (whose missing ``.vma`` attribute makes callers' ``getattr(...,
-    frozenset())`` guards take the convert path harmlessly) are exactly
-    faithful."""
-    import jax.core
-    from jax import lax
-
-    if not hasattr(jax, "typeof"):
-        jax.typeof = jax.core.get_aval
-    if not hasattr(lax, "pcast"):
-        lax.pcast = lambda x, axis_name, *, to: x
-
-
-def _try_config_update(name: str, value) -> bool:
-    """``jax.config.update`` that reports instead of raising on a knob
-    this jax build does not define (the error type varies by version:
-    AttributeError on modern builds, KeyError/ValueError historically)."""
-    try:
-        jax.config.update(name, value)
-        return True
-    except (AttributeError, KeyError, ValueError, TypeError):
-        return False
-
-
-def configure_compilation_cache(
-    cache_dir: str,
-    *,
-    min_entry_size_bytes=None,
-    min_compile_time_secs=None,
-) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``.
-
-    Tries the config-option spelling first (``jax_compilation_cache_dir``
-    — jax >= 0.4.x), then the ``compilation_cache.set_cache_dir`` API of
-    older builds.  The threshold knobs
-    (``jax_persistent_cache_min_entry_size_bytes`` /
-    ``jax_persistent_cache_min_compile_time_secs``) are best-effort: a
-    build without them keeps its defaults silently — they tune WHAT gets
-    cached, not whether caching works.
-
-    Returns True when a cache directory was installed by either path;
-    False (after a one-line warning) when this jax has no persistent
-    cache at all — callers treat that as "enablement is a no-op", never
-    an error.
-    """
-    installed = _try_config_update("jax_compilation_cache_dir", cache_dir)
-    if not installed:
-        try:  # pre-config-option spelling
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.set_cache_dir(cache_dir)  # type: ignore[attr-defined]
-            installed = True
-        except Exception:  # noqa: BLE001 — absence, not failure
-            installed = False
-    if not installed:
-        import warnings
-
-        warnings.warn(
-            "this jax build has no persistent compilation cache "
-            "(jax_compilation_cache_dir / compilation_cache.set_cache_dir "
-            "both absent); cold-start caching is disabled",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return False
-    if min_entry_size_bytes is not None:
-        _try_config_update(
-            "jax_persistent_cache_min_entry_size_bytes", min_entry_size_bytes)
-    if min_compile_time_secs is not None:
-        _try_config_update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs)
-    # jax decides once per process whether the cache is usable and then
-    # memoizes the answer; clear that memo so enabling the cache AFTER
-    # an early compile (a REPL, a test that ran first) still takes
-    # effect for every later compile
-    try:
-        from jax._src import compilation_cache as _icc
-
-        _icc.reset_cache()
-    except Exception:  # noqa: BLE001 — older layouts; memo just stays
-        pass
-    return True
-
 
 #: CompiledMemoryStats fields we normalize, in the XLA spelling minus
 #: the ``_size_in_bytes`` suffix.  ``peak`` is derived: the XLA
@@ -234,22 +62,3 @@ def device_memory_stats(device) -> "dict | None":
     if not st:
         return None
     return dict(st)
-
-
-def _install_tomllib() -> None:
-    if "tomllib" in sys.modules:
-        return
-    try:
-        import tomllib  # noqa: F401 — stdlib (3.11+): nothing to do
-    except ModuleNotFoundError:
-        try:
-            import tomli
-        except ModuleNotFoundError:
-            return  # registry.load_registry will raise its own ImportError
-        sys.modules["tomllib"] = tomli
-
-
-_install_shard_map()
-_install_tree_paths()
-_install_vma_stubs()
-_install_tomllib()

@@ -1,19 +1,17 @@
 """Cold-start performance subsystem: persistent compile cache + AOT
 executables + warmup.
 
-Every hardware benchmark round to date (BENCH_r01-r05) died inside XLA
-cold-start compilation: the measurement itself takes seconds, but the
-first process to touch the chip pays minutes of compilation before a
-single step runs, and short TPU grant windows expire first.  The
-compile-once/execute-many XLA contract (arXiv:1810.09868) means none of
-that work is inherently per-process — this module makes it durable:
+The first process to touch a chip pays minutes of XLA compilation
+before a single step runs, while the measurement itself takes seconds.
+The compile-once/execute-many XLA contract (arXiv:1810.09868) means none
+of that work is inherently per-process — this module makes it durable:
 
 * :func:`enable_persistent_cache` — one call turns on JAX's persistent
   compilation cache (disk-backed, content-addressed by HLO + compile
-  options), namespaced per topology so a CPU dev box and a TPU slice
-  never collide in one directory.  Config-name differences across jax
-  versions are absorbed by :func:`compat.configure_compilation_cache`
-  (no-op with a warning, never a crash, on builds without the knobs).
+  options + topology) at the ONE directory :func:`resolve_cache_dir`
+  names: ``JAX_COMPILATION_CACHE_DIR`` when set, else an explicit
+  ``--compile-cache DIR``, else the fixed ``.jax_cache/`` of the
+  checkout.
 * AOT helpers — :func:`aot_compile` (``lower → compile``),
   :func:`save_executable` / :func:`load_executable` (serialize the
   compiled XLA executable itself to disk, fingerprint-stamped), and
@@ -40,13 +38,11 @@ import re
 import time
 from typing import Any, Optional, Sequence
 
-from . import compat
-
 __all__ = [
     "enable_persistent_cache",
     "persistent_cache_dir",
+    "resolve_cache_dir",
     "topology_fingerprint",
-    "topology_namespace",
     "abstract_signature",
     "callable_tag",
     "config_tag",
@@ -61,7 +57,7 @@ __all__ = [
 #: format tag embedded in every serialized executable; bumping it
 #: invalidates all on-disk executables at once (they fall back to a
 #: fresh compile, never to a crash)
-AOT_MAGIC = "fdtpu-aot-v1"
+AOT_MAGIC = "fdtpu-aot-v2"
 
 #: filename suffix for serialized executables
 AOT_SUFFIX = ".jaxexec"
@@ -96,35 +92,46 @@ def topology_fingerprint(mesh=None, tag: str = "") -> str:
     return hashlib.sha256("|".join(parts).encode()).hexdigest()[:16]
 
 
-def topology_namespace() -> str:
-    """Human-readable per-topology subdirectory for the persistent
-    cache: ``tpu-tpu-v5-lite-d8p1-jax0.4.37``.  jax's own cache key
-    already covers all of this — the namespace exists so one shared
-    cache root stays inspectable (which entries belong to which
-    machine) and so an rsync of one topology's entries is possible."""
-    import jax
-    import jaxlib
+#: the variable jax itself reads for its persistent-cache directory
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    dev = jax.devices()[0]
-    kind = re.sub(r"[^a-z0-9]+", "-", str(
-        getattr(dev, "device_kind", "") or dev.platform).lower()).strip("-")
-    return (f"{dev.platform}-{kind}-d{jax.device_count()}"
-            f"p{jax.process_count()}-jax{jax.__version__}-{jaxlib.__version__}")
+#: where the cache lives when neither the variable nor an explicit
+#: directory says otherwise: ONE fixed, git-ignored path in the checkout.
+#: The directory is part of how a later process finds the entries again,
+#: so it is never built from a temp name, a pid or a time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def resolve_cache_dir(explicit: Optional[str] = None) -> str:
+    """THE compile-cache rule, shared by ``bench.py``, ``bin/driver.py``,
+    ``bin/serve.py``, ``prepare_training`` and ``chip_smoke.py``:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` set → exactly that directory (jax
+       read it at import; nothing in code overrides it);
+    2. else an explicit directory (``--compile-cache DIR``);
+    3. else :data:`DEFAULT_CACHE_DIR`.
+    """
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        return env
+    if explicit:
+        return os.path.abspath(os.path.expanduser(explicit))
+    return DEFAULT_CACHE_DIR
 
 
 def enable_persistent_cache(
-    cache_dir: Optional[str],
+    cache_dir: Optional[str] = None,
     *,
     min_entry_size_bytes: int = -1,
     min_compile_time_secs: float = 0.0,
-    namespace: bool = True,
-) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+) -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`resolve_cache_dir` ``(cache_dir)`` and return that directory.
 
-    Returns the RESOLVED directory (namespaced per topology unless
-    ``namespace=False``), or ``None`` when ``cache_dir`` is falsy or
-    this jax build has no persistent cache (warned, never raised —
-    the compat shim).  Thresholds default to "cache everything":
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set this sets NO directory in
+    code — jax already holds the variable's value — and only applies
+    the thresholds.  Those default to "cache everything":
     ``min_entry_size_bytes=-1`` (jax's use-min-compile-time sentinel)
     and ``min_compile_time_secs=0.0`` — on TPU the compiles that matter
     are all multi-second, and on CPU (tests, smoke runs) the point is
@@ -135,16 +142,22 @@ def enable_persistent_cache(
     once-per-task cache-usage check is reset).
     """
     global _cache_dir
-    if not cache_dir:
-        return None
-    path = os.path.abspath(os.path.expanduser(cache_dir))
-    if namespace:
-        path = os.path.join(path, topology_namespace())
-    os.makedirs(path, exist_ok=True)
-    if not compat.configure_compilation_cache(
-            path, min_entry_size_bytes=min_entry_size_bytes,
-            min_compile_time_secs=min_compile_time_secs):
-        return None
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    path = resolve_cache_dir(cache_dir)
+    if not os.environ.get(CACHE_DIR_ENV):
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update(
+        "jax_persistent_cache_min_entry_size_bytes", min_entry_size_bytes)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs)
+    # jax decides once per process whether the cache is usable and then
+    # memoizes the answer; clear that memo so enabling the cache AFTER
+    # an early compile (a REPL, a test that ran first) still takes
+    # effect for every later compile
+    cc.reset_cache()
     _cache_dir = path
     # surface enablement in the registry: a scrape answers "is this
     # process even using the cache" without reading logs
@@ -159,7 +172,7 @@ def enable_persistent_cache(
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The resolved cache directory of the last successful
+    """The resolved cache directory of the last
     :func:`enable_persistent_cache` call in this process (None when the
     cache was never enabled here)."""
     return _cache_dir
@@ -262,6 +275,11 @@ def save_executable(path: str, compiled, *, fingerprint: Optional[str] = None) -
     blob = pickle.dumps({
         "magic": AOT_MAGIC,
         "fingerprint": fingerprint or topology_fingerprint(),
+        # the devices the program was compiled for, in assignment order:
+        # deserialize_and_load otherwise assumes EVERY device of the
+        # backend and a 1-device program then refuses its 1-shard args
+        "device_ids": [
+            d.id for d in compiled.runtime_executable().local_devices()],
         "payload": payload,
         "in_tree": in_tree,
         "out_tree": out_tree,
@@ -281,6 +299,7 @@ def load_executable(path: str, *, fingerprint: Optional[str] = None):
     format-magic mismatch, or a topology fingerprint mismatch: every
     load site falls back to a fresh compile, so a stale artifact can
     only ever cost the compile it failed to save."""
+    import jax
     from jax.experimental.serialize_executable import deserialize_and_load
 
     expected = fingerprint or topology_fingerprint()
@@ -289,8 +308,10 @@ def load_executable(path: str, *, fingerprint: Optional[str] = None):
             blob = pickle.loads(f.read())
         if blob.get("magic") != AOT_MAGIC or blob.get("fingerprint") != expected:
             return None
+        by_id = {d.id: d for d in jax.devices()}
         return deserialize_and_load(
-            blob["payload"], blob["in_tree"], blob["out_tree"])
+            blob["payload"], blob["in_tree"], blob["out_tree"],
+            execution_devices=[by_id[i] for i in blob["device_ids"]])
     except Exception:  # noqa: BLE001 — any load failure means "recompile"
         return None
 

@@ -9,10 +9,13 @@ antialiased triangle-filter resize, center crop, normalize, batched over
 an internal C++ thread pool.  ctypes releases the GIL for the whole batch
 call, so ingest runs fully parallel to the training step dispatch.
 
-The library is compiled on first use (g++, ~1s) and cached at
-``native/build/libfdnative.so``.  Everything degrades gracefully: if the
-toolchain or libjpeg is missing, callers fall back to the PIL path in
-``preprocess.py`` (same output contract, looser perf).
+The library is compiled on first use (g++, ~1s) from
+``native/fd_native.cpp`` as committed into the git-ignored
+``native/build/libfdnative.so`` — a fresh checkout has no ``build/`` and
+builds it here.  If the toolchain or libjpeg is missing the build's
+failure is reported ONCE, loudly (a ``RuntimeWarning`` carrying the
+compiler's own message), and callers then use the PIL path in
+``preprocess.py`` (same output contract, slower).
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -60,11 +64,20 @@ def _build() -> bool:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _SO)
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+        # reported once per process (_load tries once), never swallowed:
+        # a run that silently decodes through PIL is a slower run
+        detail = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            f"native ingest library failed to build ({' '.join(cmd)}): "
+            f"{type(e).__name__}: {e}\n"
+            f"{detail.decode(errors='replace')[-2000:]}\n"
+            "falling back to the PIL decode path",
+            RuntimeWarning, stacklevel=2)
         return False
 
 

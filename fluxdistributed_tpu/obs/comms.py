@@ -57,6 +57,8 @@ __all__ = [
 #: without dashes, so both layers key identically)
 JAXPR_COLLECTIVES = {
     "psum": "all_reduce",
+    # what psum of a varying value traces to under check_vma=True
+    "psum_invariant": "all_reduce",
     "pmin": "all_reduce",
     "pmax": "all_reduce",
     "psum_scatter": "reduce_scatter",
@@ -119,7 +121,7 @@ def _sub_jaxprs(eqn):
     """Every sub-jaxpr in an equation's params (pjit jaxpr, scan body,
     cond branches, while cond/body, custom-vjp call_jaxpr, remat, ...),
     labeled so branch alternatives can merge at max instead of sum."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def _as_jaxpr(v):
         if isinstance(v, ClosedJaxpr):

@@ -34,6 +34,7 @@ from __future__ import annotations
 import contextlib
 import sys
 import threading
+import weakref
 from typing import Callable, Optional
 
 from .metrics import Registry, get_registry
@@ -61,101 +62,85 @@ CACHE_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 _lock = threading.Lock()
 _installed = False
 _steady = False
-_registry: Optional[Registry] = None
+# the listener's targets: the process registry always, plus any
+# registry a caller installed (a run with its own Observation registry).
+# Weak: a finished run's private registry must not be fed forever.
+_extra: "weakref.WeakSet[Registry]" = weakref.WeakSet()
 _warn: Callable[[str], None] = lambda msg: print(msg, file=sys.stderr)
+
+_COUNTERS = (
+    ("fdtpu_jax_compiles_total", "XLA backend compiles"),
+    ("fdtpu_jax_compile_seconds_total", "XLA backend compile seconds"),
+    ("fdtpu_jax_steady_recompiles_total",
+     "compiles observed AFTER steady state was declared "
+     "(any nonzero value means something is recompiling)"),
+    ("fdtpu_jax_cache_hits_total",
+     "XLA compiles served from the persistent compilation cache"),
+    ("fdtpu_jax_cache_misses_total",
+     "XLA compiles the persistent compilation cache could not serve"),
+    ("fdtpu_jax_cache_saved_seconds_total",
+     "compile wall seconds skipped by persistent-cache hits"),
+    ("fdtpu_jax_trace_seconds_total", "jaxpr trace seconds"),
+)
+_HELP = dict(_COUNTERS)
+
+
+def _inc(name: str, amount: float = 1.0) -> None:
+    for reg in (get_registry(), *_extra):
+        reg.counter(name, _HELP[name]).inc(amount)
 
 
 def _listener(event: str, duration: float, **kwargs) -> None:
-    reg = _registry
-    if reg is None:  # pragma: no cover — install() always binds one
-        return
     if event == BACKEND_COMPILE_EVENT:
-        reg.counter(
-            "fdtpu_jax_compiles_total", "XLA backend compiles"
-        ).inc()
-        reg.counter(
-            "fdtpu_jax_compile_seconds_total", "XLA backend compile seconds"
-        ).inc(duration)
+        _inc("fdtpu_jax_compiles_total")
+        _inc("fdtpu_jax_compile_seconds_total", duration)
         if _steady:
-            reg.counter(
-                "fdtpu_jax_steady_recompiles_total",
-                "compiles observed AFTER steady state was declared "
-                "(any nonzero value means something is recompiling)",
-            ).inc()
+            _inc("fdtpu_jax_steady_recompiles_total")
             _warn(
                 f"obs.jaxmon: steady-state RECOMPILE ({duration:.2f}s) — "
                 "an input shape/dtype or static argument changed after "
                 "warmup; check bucket sizes and batch shapes"
             )
     elif event == TRACE_EVENT:
-        reg.counter(
-            "fdtpu_jax_trace_seconds_total", "jaxpr trace seconds"
-        ).inc(duration)
+        _inc("fdtpu_jax_trace_seconds_total", duration)
     elif event == CACHE_SAVED_EVENT:
-        reg.counter(
-            "fdtpu_jax_cache_saved_seconds_total",
-            "compile wall seconds skipped by persistent-cache hits",
-        ).inc(max(duration, 0.0))
+        _inc("fdtpu_jax_cache_saved_seconds_total", max(duration, 0.0))
 
 
 def _event_listener(event: str, **kwargs) -> None:
     """Plain (non-duration) monitoring events: the persistent
     compilation cache's hit/miss stream."""
-    reg = _registry
-    if reg is None:  # pragma: no cover — install() always binds one
-        return
     if event == CACHE_HIT_EVENT:
-        reg.counter(
-            "fdtpu_jax_cache_hits_total",
-            "XLA compiles served from the persistent compilation cache",
-        ).inc()
+        _inc("fdtpu_jax_cache_hits_total")
     elif event == CACHE_MISS_EVENT:
-        reg.counter(
-            "fdtpu_jax_cache_misses_total",
-            "XLA compiles the persistent compilation cache could not serve",
-        ).inc()
+        _inc("fdtpu_jax_cache_misses_total")
 
 
 def install(registry: Optional[Registry] = None,
             warn: Optional[Callable[[str], None]] = None) -> None:
-    """Register the monitoring listener (idempotent; first registry
-    passed wins — JAX has no listener deregistration, so the binding is
-    process-lifetime)."""
-    global _installed, _registry, _warn
+    """Register the monitoring listener (idempotent — JAX has no
+    listener deregistration, so the binding is process-lifetime).  The
+    counters always land in the process registry; a ``registry`` passed
+    here receives them too, for as long as it lives — never instead:
+    which run installed first must not decide where every later run's
+    compile counters go."""
+    global _installed, _warn
     import jax.monitoring
 
     with _lock:
-        if registry is not None and _registry is None:
-            _registry = registry
-        if _registry is None:
-            _registry = get_registry()
         if warn is not None:
             _warn = warn
-        if _installed:
-            return
+        targets = [get_registry()]
+        if registry is not None and registry is not targets[0]:
+            _extra.add(registry)
+            targets.append(registry)
         # pre-register so /metrics shows explicit zeros before the
         # first compile (absence would read as "not instrumented")
-        _registry.counter("fdtpu_jax_compiles_total", "XLA backend compiles")
-        _registry.counter(
-            "fdtpu_jax_compile_seconds_total", "XLA backend compile seconds"
-        )
-        _registry.counter(
-            "fdtpu_jax_steady_recompiles_total",
-            "compiles observed AFTER steady state was declared "
-            "(any nonzero value means something is recompiling)",
-        )
-        _registry.counter(
-            "fdtpu_jax_cache_hits_total",
-            "XLA compiles served from the persistent compilation cache",
-        )
-        _registry.counter(
-            "fdtpu_jax_cache_misses_total",
-            "XLA compiles the persistent compilation cache could not serve",
-        )
-        _registry.counter(
-            "fdtpu_jax_cache_saved_seconds_total",
-            "compile wall seconds skipped by persistent-cache hits",
-        )
+        for reg in targets:
+            for name, help_ in _COUNTERS:
+                reg.counter(name, help_)
+        if _installed:
+            return
         jax.monitoring.register_event_duration_secs_listener(_listener)
         jax.monitoring.register_event_listener(_event_listener)
         _installed = True
@@ -193,30 +178,24 @@ def steady_state():
 
 
 def compile_count() -> float:
-    reg = _registry or get_registry()
-    return reg.value("fdtpu_jax_compiles_total")
+    return get_registry().value("fdtpu_jax_compiles_total")
 
 
 def compile_seconds() -> float:
-    reg = _registry or get_registry()
-    return reg.value("fdtpu_jax_compile_seconds_total")
+    return get_registry().value("fdtpu_jax_compile_seconds_total")
 
 
 def cache_hits() -> float:
-    reg = _registry or get_registry()
-    return reg.value("fdtpu_jax_cache_hits_total")
+    return get_registry().value("fdtpu_jax_cache_hits_total")
 
 
 def cache_misses() -> float:
-    reg = _registry or get_registry()
-    return reg.value("fdtpu_jax_cache_misses_total")
+    return get_registry().value("fdtpu_jax_cache_misses_total")
 
 
 def compile_seconds_saved() -> float:
-    reg = _registry or get_registry()
-    return reg.value("fdtpu_jax_cache_saved_seconds_total")
+    return get_registry().value("fdtpu_jax_cache_saved_seconds_total")
 
 
 def steady_recompiles() -> float:
-    reg = _registry or get_registry()
-    return reg.value("fdtpu_jax_steady_recompiles_total")
+    return get_registry().value("fdtpu_jax_steady_recompiles_total")

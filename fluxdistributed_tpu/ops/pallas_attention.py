@@ -188,7 +188,7 @@ def _flash_kernel(
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
         # LSE of a fully-masked row is ~NEG_INF; its backward tiles are
         # all-masked anyway, so the value is never observed.
-        lse_ref[0] = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
+        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
 
 
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -209,8 +209,8 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0]
     v = v_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
     s = scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [block_q, block_k] f32
@@ -397,11 +397,13 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+            # per-row stats ride as [BH, 1, Tq] rows: a (1, block_q)
+            # block of a 2-D [BH, Tq] array is not (8, 128)-tileable
+            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, tq_p), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, tq_p), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -410,7 +412,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k,
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return _unfold(out, b, h, tq), lse[:, :tq]
+    return _unfold(out, b, h, tq), lse[:, 0, :tq]
 
 
 @functools.partial(
@@ -445,7 +447,8 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
         )
     lse_p = jnp.pad(
         lse, ((0, 0), (0, tq_p - tq)), constant_values=_LSE_PAD
-    )
+    )[:, None]
+    delta = delta[:, None]
 
     nq, nk = tq_p // block_q, tk_p // block_k
     bh = b * h
@@ -459,7 +462,9 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     q_spec_i = pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0))
     kv_spec_j = pl.BlockSpec(
         (1, block_k, d), lambda bh_, i, j: (kv_bh(bh_), j, 0))
-    row_spec_i = pl.BlockSpec((1, block_q), lambda bh_, i, j: (bh_, i))
+    # lse/delta as [BH, 1, Tq] rows (see the forward's LSE out_spec)
+    row_spec_i = pl.BlockSpec(
+        (1, 1, block_q), lambda bh_, i, j: (bh_, 0, i))
     # dKV grid is (b*hkv, j, t) where the inner axis t enumerates the
     # nq q-blocks of each of the `group` query heads sharing this KV
     # head: t = member * nq + qi.
@@ -468,7 +473,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     kv_spec_outer = pl.BlockSpec(
         (1, block_k, d), lambda bh_, j, t: (bh_, j, 0))
     row_spec_inner = pl.BlockSpec(
-        (1, block_q), lambda bh_, j, t: (q_bh(bh_, t), t % nq))
+        (1, 1, block_q), lambda bh_, j, t: (q_bh(bh_, t), 0, t % nq))
 
     common = dict(
         scale=scale, causal=causal, tk_valid=tk, causal_offset=tk - tq,

@@ -23,10 +23,12 @@ engine's cache layouts NATIVELY (ROADMAP Open item 2):
   skipped, and the gather/reshape the XLA path pays per step never
   happens.
 
-Grouped-query attention is native: the grid runs over ``B × Hkv`` and
-each program attends all ``H/Hkv`` query heads of its group against the
-SHARED KV block ([group, block] score tiles — decode's MXU utilization
-comes from the group dimension).  Quantized caches (int8 / fp8 K/V with
+Grouped-query attention is native: the grid runs over ``B × blocks``,
+each program holds one whole-row K/V block (all ``Hkv`` heads — the
+only block of ``[.., Hkv, D]`` a TPU can tile) and, per KV head,
+attends all ``H/Hkv`` query heads of its group against the SHARED KV
+slice ([group, block] score tiles — decode's MXU utilization comes from
+the group dimension).  Quantized caches (int8 / fp8 K/V with
 per-row-per-head scales, ``models/transformer_lm.py``) dequantize
 INSIDE the kernel — HBM traffic shrinks by the storage dtype, and the
 f32 dequant rides the VPU between the DMA and the MXU.
@@ -165,15 +167,20 @@ def _dequant(x, scale):
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(refs, *, scale, window, sinks, hkv, block_rows,
+def _decode_kernel(refs, *, scale, window, sinks, hkv, d, block_rows,
                    windowed, quant, paged):
-    """One (slot×KV-head, KV-block) grid step of flash decode.
+    """One (slot, KV-block) grid step of flash decode, all KV heads.
 
     ``refs`` is the flat pallas argument list: scalar-prefetch refs
     first (idx; page table too when paged), then inputs (q, k, v
     [, slot_pos][, k_scale, v_scale]), then the output and the
     (acc, m, l) scratch.  KV innermost — the grid is sequential per
     core, so scratch carries the online softmax across blocks.
+
+    K/V blocks arrive as ``[block_rows, Hkv*D]`` — the cache's own row
+    layout with the two minor axes merged (a free reshape), because a
+    one-head ``(block_rows, 1, D)`` block of ``[.., Hkv, D]`` is not
+    (8, 128)-tileable on TPU.  Each head is a static lane slice.
     """
     i = 0
     if paged:
@@ -192,10 +199,9 @@ def _decode_kernel(refs, *, scale, window, sinks, hkv, block_rows,
     o_ref = refs[i]; i += 1
     acc_ref, m_ref, l_ref = refs[i:]
 
-    bh = pl.program_id(0)
+    b = pl.program_id(0)
     j = pl.program_id(1)
     nk = pl.num_programs(1)
-    b = bh // hkv
     group = q_ref.shape[2]
 
     @pl.when(j == 0)
@@ -208,13 +214,13 @@ def _decode_kernel(refs, *, scale, window, sinks, hkv, block_rows,
     if windowed:
         # ring slots carry their global position (-1 = unwritten); band
         # semantics are recovered from positions, never from slot order
-        sp = sp_ref[0]  # [block_rows] int32
+        sp = sp_ref[...].reshape(1, block_rows)  # int32 row
         allow = (sp >= 0) & (sp <= cursor)
         band = sp > cursor - window
         if sinks:
             band |= sp < sinks
         allow &= band
-        allow = jnp.broadcast_to(allow[None, :], (group, block_rows))
+        allow = jnp.broadcast_to(allow, (group, block_rows))
     else:
         pos = j * block_rows + jax.lax.broadcasted_iota(
             jnp.int32, (group, block_rows), 1)
@@ -223,22 +229,23 @@ def _decode_kernel(refs, *, scale, window, sinks, hkv, block_rows,
         allow &= pt_ref[b, j] >= 0  # unbound page: every row dead
 
     def _body():
-        q = q_ref[0, 0]  # [group, D]
-        k = k_ref[0, :, 0]  # [block_rows, D]
-        v = v_ref[0, :, 0]
-        if quant:
-            k = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-            v = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [group, block_rows]
-        p, corr, m_new, l_new = online_softmax_update(
-            s, m_ref[:, 0], l_ref[:, 0], mask=allow)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        for h in range(hkv):  # static: one lane slice per KV head
+            q = q_ref[0, h]  # [group, D]
+            k = k_ref[0, :, h * d:(h + 1) * d]  # [block_rows, D]
+            v = v_ref[0, :, h * d:(h + 1) * d]
+            if quant:
+                k = k.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+                v = v.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+            s = scale * jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [group, block_rows]
+            p, corr, m_new, l_new = online_softmax_update(
+                s, m_ref[h, :, 0], l_ref[h, :, 0], mask=allow)
+            acc_ref[h] = acc_ref[h] * corr[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
 
     # dead blocks (above every cursor / out of band / unbound page)
     # skip the MXU entirely — this is where decode cost becomes
@@ -247,8 +254,8 @@ def _decode_kernel(refs, *, scale, window, sinks, hkv, block_rows,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 def _pad_rows(x, block, fill=0):
@@ -327,38 +334,38 @@ def _flash_decode_impl(q, k, v, idx, slot_pos, k_scale, v_scale,
     windowed = window is not None
     quant = k_scale is not None
 
-    in_specs = [
-        pl.BlockSpec((1, 1, group, d), lambda bh, j, idx: (bh // hkv, bh % hkv, 0, 0)),
-        pl.BlockSpec((1, block_k, 1, d), lambda bh, j, idx: (bh // hkv, j, bh % hkv, 0)),
-        pl.BlockSpec((1, block_k, 1, d), lambda bh, j, idx: (bh // hkv, j, bh % hkv, 0)),
-    ]
-    args = [q4, kp, vp]
+    # whole-row blocks over the merged [.., Hkv*D] minor axis (see
+    # _decode_kernel); the grid walks slots x KV blocks
+    qo_spec = pl.BlockSpec((1, hkv, group, d), lambda b_, j, idx: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, block_k, hkv * d), lambda b_, j, idx: (b_, j, 0))
+    in_specs = [qo_spec, kv_spec, kv_spec]
+    args = [q4, kp.reshape(b, -1, hkv * d), vp.reshape(b, -1, hkv * d)]
     if windowed:
+        # [B, 1, R] rows: a (1, block_k) block of [B, R] is not tileable
         in_specs.append(
-            pl.BlockSpec((1, block_k), lambda bh, j, idx: (bh // hkv, j)))
-        args.append(_pad_rows(slot_pos, block_k, fill=-1).astype(jnp.int32))
+            pl.BlockSpec((1, 1, block_k), lambda b_, j, idx: (b_, 0, j)))
+        args.append(_pad_rows(slot_pos, block_k, fill=-1)
+                    .astype(jnp.int32)[:, None])
     if quant:
-        spec = pl.BlockSpec(
-            (1, block_k, 1), lambda bh, j, idx: (bh // hkv, j, bh % hkv))
+        spec = pl.BlockSpec((1, block_k, hkv), lambda b_, j, idx: (b_, j, 0))
         in_specs += [spec, spec]
         args += [_pad_rows(k_scale, block_k).astype(jnp.float32),
                  _pad_rows(v_scale, block_k).astype(jnp.float32)]
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, sinks=sinks, hkv=hkv,
-        block_rows=block_k, windowed=windowed, quant=quant, paged=False)
+        d=d, block_rows=block_k, windowed=windowed, quant=quant, paged=False)
     out = pl.pallas_call(
         lambda *refs: kernel(refs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b * hkv, nb),
+            grid=(b, nb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, group, d), lambda bh, j, idx: (bh // hkv, bh % hkv, 0, 0)),
+            out_specs=qo_spec,
             scratch_shapes=[
-                pltpu.VMEM((group, d), jnp.float32),
-                pltpu.VMEM((group, _LANES), jnp.float32),
-                pltpu.VMEM((group, _LANES), jnp.float32),
+                pltpu.VMEM((hkv, group, d), jnp.float32),
+                pltpu.VMEM((hkv, group, _LANES), jnp.float32),
+                pltpu.VMEM((hkv, group, _LANES), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
@@ -452,47 +459,44 @@ def _flash_decode_paged_impl(q, k_pool, v_pool, page_table, idx, slot_pos,
     windowed = window is not None
     quant = k_scale is not None
 
-    def kv_map(bh, j, pt, idx):
+    def kv_map(b_, j, pt, idx):
         # THE page-table walk: the physical block this grid step DMAs
         # is named by the slot's page table (clamped for -1; the kernel
         # masks the whole block via pt[b, j] < 0)
-        return (jnp.maximum(pt[bh // hkv, j], 0), 0, bh % hkv, 0)
+        return (jnp.maximum(pt[b_, j], 0), 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, group, d),
-                     lambda bh, j, pt, idx: (bh // hkv, bh % hkv, 0, 0)),
-        pl.BlockSpec((1, bs, 1, d), kv_map),
-        pl.BlockSpec((1, bs, 1, d), kv_map),
-    ]
-    args = [q4, k_pool, v_pool]
+    # whole-row blocks over the merged [.., Hkv*D] minor axis (see
+    # _decode_kernel); the grid walks slots x pages
+    qo_spec = pl.BlockSpec(
+        (1, hkv, group, d), lambda b_, j, pt, idx: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, hkv * d), kv_map)
+    in_specs = [qo_spec, kv_spec, kv_spec]
+    args = [q4, k_pool.reshape(nb_pool, bs, hkv * d),
+            v_pool.reshape(nb_pool, bs, hkv * d)]
     if windowed:
-        in_specs.append(
-            pl.BlockSpec((1, bs), lambda bh, j, pt, idx: (bh // hkv, j)))
-        args.append(slot_pos.astype(jnp.int32))
+        # [B, P, 1, bs]: one page's positions as a full (1, bs) tile
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, bs), lambda b_, j, pt, idx: (b_, j, 0, 0)))
+        args.append(slot_pos.astype(jnp.int32).reshape(b, pages, 1, bs))
     if quant:
-        spec = pl.BlockSpec(
-            (1, bs, 1),
-            lambda bh, j, pt, idx: (jnp.maximum(pt[bh // hkv, j], 0), 0,
-                                    bh % hkv))
+        spec = pl.BlockSpec((1, bs, hkv), kv_map)
         in_specs += [spec, spec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, sinks=sinks, hkv=hkv,
-        block_rows=bs, windowed=windowed, quant=quant, paged=True)
+        d=d, block_rows=bs, windowed=windowed, quant=quant, paged=True)
     out = pl.pallas_call(
         lambda *refs: kernel(refs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b * hkv, pages),
+            grid=(b, pages),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, group, d),
-                lambda bh, j, pt, idx: (bh // hkv, bh % hkv, 0, 0)),
+            out_specs=qo_spec,
             scratch_shapes=[
-                pltpu.VMEM((group, d), jnp.float32),
-                pltpu.VMEM((group, _LANES), jnp.float32),
-                pltpu.VMEM((group, _LANES), jnp.float32),
+                pltpu.VMEM((hkv, group, d), jnp.float32),
+                pltpu.VMEM((hkv, group, _LANES), jnp.float32),
+                pltpu.VMEM((hkv, group, _LANES), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),

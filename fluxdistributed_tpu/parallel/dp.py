@@ -38,7 +38,6 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import mesh as mesh_lib
-from .. import tree as tree_lib
 from ..optim import Optimizer
 from . import collectives
 
@@ -186,8 +185,8 @@ def make_train_step(
     loader's ``chunk=K`` layout) and ``lax.scan``s the step over them,
     returning metrics stacked ``[K]``.  Each step consumes a DIFFERENT
     batch — semantics identical to K separate calls — but the host pays
-    one dispatch instead of K, which matters when dispatch crosses a
-    network tunnel or the host is slow relative to the step.
+    one dispatch instead of K, which matters when host dispatch latency
+    is large or the host is slow relative to the step.
 
     ``guard=True`` adds ``metrics["guard"]`` — the
     :func:`guard_sentinel` ``[poisoned_loss, grad_norm]`` vector (per
@@ -340,7 +339,6 @@ def make_train_step_shardmap(
     """
     repl_spec = P()
     batch_spec = P(axis)
-    nshards = mesh.shape[axis]
     with_rng = _accepts_rng(loss_fn)
 
     @partial(
@@ -348,6 +346,7 @@ def make_train_step_shardmap(
         mesh=mesh,
         in_specs=(repl_spec, batch_spec),
         out_specs=(repl_spec, repl_spec),
+        check_vma=False,
     )
     def step(state: TrainState, batch):
         def lossf(params):
@@ -365,19 +364,16 @@ def make_train_step_shardmap(
         (loss, (new_mstate, _)), grads = jax.value_and_grad(lossf, has_aux=True)(
             state.params
         )
-        # Differentiating w.r.t. the replicated (P()) params already
-        # psums the cotangent across the mesh axis (the transpose of
-        # replication); the reference's mean semantics
-        # (sync_buffer's divide-by-N, src/ddp_tasks.jl:103-106) is then
-        # a division by the shard count, not another collective.  A
-        # pre-VMA shard_map tracer inserts NO such psum, so there the
-        # mean is one explicit collective instead.
-        from ..compat import LEGACY_SHARD_MAP
-
-        if LEGACY_SHARD_MAP:
-            grads = collectives.pmean(grads, axis)
-        else:
-            grads = tree_lib.div(grads, nshards)
+        # check_vma=False: the tracer does no replication typing, so the
+        # gradient w.r.t. the replicated (P()) params is this device's
+        # LOCAL gradient and nothing is reduced implicitly.  The mean is
+        # therefore one explicit collective — sync_buffer's
+        # accumulate-then-divide (src/ddp_tasks.jl:103-106) as a pmean.
+        # Explicit over check_vma=True's implicit psum because a loss
+        # may contain pallas_call / custom_vjp ops that carry no
+        # varying-axes types, and because the collective ledger
+        # (obs/comms.py) then reads the schedule as written.
+        grads = collectives.pmean(grads, axis)
         loss = jax.lax.pmean(loss, axis)
         # Mutable model state (BatchNorm running stats) is per-shard →
         # average it across replicas so replicas stay identical.

@@ -345,12 +345,10 @@ def make_train_step_zero1_shardmap(
         new_mstate = collectives.pmean(new_mstate, axis)
         # ZeRO-1 gradient exchange: sum-reduce-scatter the flat padded
         # grads, then mean — each device holds grad slice i of N at half
-        # the wire bytes of DP's all-reduce.  (A VMA-era tracer will have
-        # already psummed the cotangent of the replicated params; there
-        # the scatter degenerates to slicing the local 1/N chunk, which
-        # XLA's all-reduce-reassociation folds back into a reduce-scatter.)
-        from ..compat import LEGACY_SHARD_MAP
-
+        # the wire bytes of DP's all-reduce.  check_vma=False means the
+        # tracer reduces nothing implicitly (grads are device-local), so
+        # the reduce_scatter below is THE gradient collective; explicit
+        # because the schedule is the point of this variant.
         i = jax.lax.axis_index(axis)
 
         def local_chunk(tree):
@@ -363,11 +361,8 @@ def make_train_step_zero1_shardmap(
                 is_leaf=_is_none,
             )
 
-        flat_g = _flatten_tree(grads, nshards)
-        if LEGACY_SHARD_MAP:
-            flat_g = collectives.reduce_scatter(flat_g, axis)
-        else:
-            flat_g = local_chunk(flat_g)
+        flat_g = collectives.reduce_scatter(
+            _flatten_tree(grads, nshards), axis)
         flat_g = jax.tree.map(
             lambda g: None if g is None else g / nshards, flat_g, is_leaf=_is_none
         )

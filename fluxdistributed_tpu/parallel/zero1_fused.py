@@ -302,8 +302,6 @@ def make_train_step_zero1_fused(
         model_state=jax.tree.map(lambda _: repl_spec, state.model_state),
         step=repl_spec,
     )
-    from ..compat import LEGACY_SHARD_MAP
-
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
@@ -328,12 +326,10 @@ def make_train_step_zero1_fused(
         flat_g = pack_tree(grads, nshards)
         i = jax.lax.axis_index(axis)
         chunk = flat_g.shape[0] // nshards
-        if LEGACY_SHARD_MAP:
-            # ONE collective for the whole tree (the fusion's wire half)
-            flat_g = collectives.reduce_scatter({"g": flat_g}, axis)["g"]
-        else:
-            # VMA tracers psummed the replicated-param cotangent already
-            flat_g = jax.lax.dynamic_slice_in_dim(flat_g, i * chunk, chunk)
+        # check_vma=False: grads are device-local (no implicit psum of
+        # the replicated-param cotangent), so this is THE gradient
+        # collective — ONE for the whole tree (the fusion's wire half)
+        flat_g = collectives.reduce_scatter({"g": flat_g}, axis)["g"]
         flat_g = flat_g / nshards
         flat_p = jax.lax.dynamic_slice_in_dim(
             pack_tree(state.params, nshards), i * chunk, chunk)

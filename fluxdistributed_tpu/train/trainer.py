@@ -29,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import copy
 import dataclasses
+import os
 import sys
 import time
 from typing import Any, Callable, Iterable, Optional, Sequence
@@ -195,8 +196,8 @@ def prepare_training(
     stacks K per-step batches and the compiled program ``lax.scan``s K
     optimizer steps per dispatch — identical math and identical sampled
     data (sub-batch j of item c equals step c·K+j of an unchunked run),
-    but the host pays one dispatch per K steps.  Worthwhile when the
-    runtime sits behind a network tunnel or the host is slow; cadences
+    but the host pays one dispatch per K steps.  Worthwhile when host
+    dispatch latency is large or the host is slow; cadences
     in ``train`` (print/eval/checkpoint) then tick once per K steps.
     Supported for ``spmd='jit'``.
 
@@ -213,10 +214,13 @@ def prepare_training(
 
     Cold-start controls (:mod:`fluxdistributed_tpu.compilation`):
 
-    * ``cache_dir`` enables JAX's persistent compilation cache there
-      (namespaced per topology) BEFORE any compile in this call, so the
-      next process on the same topology reads every XLA compile from
-      disk instead of redoing it.
+    * ``cache_dir`` enables JAX's persistent compilation cache BEFORE
+      any compile in this call, so the next process on the same
+      topology reads every XLA compile from disk instead of redoing it.
+      The directory follows ``compilation.resolve_cache_dir``: where
+      ``JAX_COMPILATION_CACHE_DIR`` is set the cache lives exactly
+      there (and is enabled whatever ``cache_dir`` says), else at
+      ``cache_dir``.
     * ``aot`` names a directory of serialized train-step executables:
       the compiled step is loaded from disk when a file matching this
       topology + argument signature exists, else compiled NOW (at
@@ -257,9 +261,9 @@ def prepare_training(
     """
     from ..data.loader import apply_transform
 
-    if cache_dir:
-        from .. import compilation
+    from .. import compilation
 
+    if cache_dir or os.environ.get(compilation.CACHE_DIR_ENV):
         compilation.enable_persistent_cache(cache_dir)
 
     if spmd == "dp":  # explicit-name alias for the auto-sharded DP path
@@ -758,10 +762,14 @@ def prepare_training(
         topk=tuple(topk),
         batch_axes=batch_axes,
     )
+    # a handful of state leaves (the step counter; any scalar the
+    # optimizer creates from literals) are born uncommitted on one
+    # device, while the step RETURNS them committed to the replicated
+    # sharding: left alone, the second step call sees a new input
+    # signature and compiles the whole train step a second time
+    task.state = _commit_replicated_stragglers(task.state, mesh)
 
     if aot or warmup:
-        from .. import compilation
-
         dummy = _dummy_batch(
             dataset, transform, batch_size, mesh, steps_per_call, seed,
             axis=batch_axes)
@@ -825,12 +833,6 @@ def prepare_training(
                 f"{stats['seconds']:.1f}s) pre-paid before step 0")
 
     if strict_checks:
-        # a handful of state leaves (the step counter; any scalar the
-        # optimizer creates from literals) are born on one device and
-        # legitimately commit to their replicated sharding at the first
-        # call — do that HERE so the transfer-guarded call only trips on
-        # transfers that would recur every step
-        task.state = _commit_replicated_stragglers(task.state, mesh)
         task.step_fn = _strict_first_call(task.step_fn, "train step")
         task.eval_fn = _strict_first_call(task.eval_fn, "eval step")
 
@@ -841,12 +843,11 @@ def _commit_replicated_stragglers(state, mesh: Mesh):
     """Commit any single-device state leaf to the replicated sharding on
     ``mesh``.  Mode-specific prepare paths device_put their whole state;
     the plain DP paths leave computation-born scalars (``state.step``)
-    uncommitted, and ``strict_checks`` must not report the one-time
-    step-0 commit of those as a hot-path transfer."""
+    uncommitted.  Holds on a one-device mesh too: there the step still
+    returns ``NamedSharding`` leaves, a different jit signature from the
+    ``SingleDeviceSharding`` they were born with."""
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
-    if mesh.size <= 1:
-        return state
     repl = NamedSharding(mesh, PartitionSpec())
 
     def fix(x):

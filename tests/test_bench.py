@@ -1,8 +1,8 @@
-"""Guard the driver-facing benchmark harness.
+"""Guard the benchmark harness.
 
-bench.py is the artifact the round driver executes on real hardware; a
-breakage there records a failed round, so its construction path and
-always-emit-JSON contract get CI coverage on the fake mesh.
+bench.py measures on the chip; its construction path, and the contract
+that a failed or chipless run exits NON-ZERO with an error line that
+names the device, get CI coverage on the fake mesh.
 """
 
 from __future__ import annotations
@@ -48,12 +48,14 @@ def test_fused_steps_advance_state(bench_mod):
 
 def test_step_flops_and_mfu(bench_mod):
     """Cost analysis counts a sane FLOP total WITHOUT a second compile;
-    mfu_pct is None on CPU (unknown peak) and arithmetic on a known one."""
+    an unknown device_kind (the CPU here) is an error, not None, and a
+    known one is plain arithmetic."""
     step, state, b = bench_mod.build_step(batch=8, size=32, donate=False)
     fl = bench_mod.step_flops(step, state, b)
     # ResNet-50 fwd+bwd at 32x32 is ~0.25 GFLOP/img -> total well over 1e8
     assert fl > 1e8, fl
-    assert bench_mod.mfu_pct(fl, dt=0.01, nchips=8) is None  # cpu device_kind
+    with pytest.raises(RuntimeError, match="no bf16 peak.*'cpu'"):
+        bench_mod.mfu_pct(fl, dt=0.01, nchips=8)
     # direct arithmetic check against a fake peak table entry
     bench_mod._PEAK_BF16_TFLOPS["cpu"] = 1.0  # device_kind == "cpu" on host
     try:
@@ -84,77 +86,36 @@ def test_build_step_variant_knobs(bench_mod):
     assert float(m["loss"]) > 0
 
 
-def test_main_emits_error_json_and_rc0_on_failure(bench_mod, monkeypatch, capsys):
-    """main() must print the JSON line and return normally no matter how
-    the measurement subprocess dies — crash, hang (TimeoutExpired), or
-    garbage output (the 2026-07-30 unavailable-backend scenario)."""
-    import subprocess
-
-    def boom(*a, **kw):
-        raise subprocess.TimeoutExpired(cmd="bench", timeout=1)
-
-    monkeypatch.setattr(subprocess, "run", boom)
-    monkeypatch.setattr(bench_mod.time, "sleep", lambda s: None)
-    bench_mod.main()  # must not raise
+def test_main_emits_error_json_and_nonzero_rc_on_failure(bench_mod, capsys):
+    """No chip → main() returns NON-ZERO, and the one JSON line it prints
+    is an error line: it names the device jax found and carries no value
+    under the metric's name (a CPU timing is never a device number)."""
+    assert bench_mod.main() == 1
     line = capsys.readouterr().out.strip().splitlines()[-1]
     out = json.loads(line)
-    assert out["unit"] == "images/sec/chip"
-    assert "timed out" in out["error"]
-    # the cold-start ledger rides the ERROR json too, so a timed-out
-    # round says whether the window went to compilation or the hardware
-    # (no child ran here, so the forensic defaults apply)
-    assert out["phase"] == "unknown"
-    assert out["compile_seconds"] == 0.0
-    assert out["cache_hits"] == 0 and out["cache_misses"] == 0
-    # the static-health stamp rides the error JSON too: a zero artifact
-    # still records whether the code it ran was lint-clean (shape only —
+    assert "value" not in out and "unit" not in out
+    assert "platform 'cpu'" in out["error"]
+    assert (out["platform"], out["device_kind"], out["device_count"]) == (
+        "cpu", "cpu", 8)
+    assert out["phase"] == "backend_init"
+    # the cold-start ledger rides the ERROR json too
+    assert out["compile_seconds"] >= 0.0
+    assert out["cache_hits"] >= 0 and out["cache_misses"] >= 0
+    # the static-health stamp rides the error JSON too (shape only —
     # repo lint cleanliness is bin/lint.py --check's gate, and WIP code
     # with a finding must not fail an unrelated bench test)
     assert {"findings", "new", "by_rule"} <= set(out["lint"])
-    # the robustness stamp rides the error JSON too: a dead round
-    # records the fault/watchdog/guard counters it saw (or that it saw
-    # none — the stamp is never absent)
     assert isinstance(out["guard"], dict)
-    # the memory stamp rides the error JSON too: a dead round records
-    # the HBM state at death ({"available": false} here — CPU has no
-    # memory_stats, the None-safe degradation, never a crash)
+    # CPU has no memory_stats: unavailable, never fake zeros
     assert out["memory"] == {"available": False}
 
-    class FakeDone:
-        returncode = 1
-        stdout = "not json\nalso not json"
-        stderr = "injected failure"
 
-    monkeypatch.setattr(subprocess, "run", lambda *a, **kw: FakeDone())
-    bench_mod.main()
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    out = json.loads(line)
-    assert "injected failure" in out["error"]
-
-    class FakeOK:
-        returncode = 0
-        stdout = 'preamble\n{"metric": "m", "value": 1.0, "unit": "images/sec/chip", "vs_baseline": 1.0}'
-        stderr = ""
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **kw: FakeOK())
-    bench_mod.main()
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    assert json.loads(line)["value"] == 1.0
-
-
-def test_status_file_snapshots_phase_and_compile_ledger(bench_mod, tmp_path):
-    """The bounded subprocess drops phase + compile-counter snapshots;
-    main() folds the last one into the error JSON on a dead attempt."""
-    path = str(tmp_path / "status.json")
-    bench_mod._write_status(path, "compile")
-    snap = json.loads(open(path).read())
-    assert snap["phase"] == "compile"
-    for key in ("compile_seconds", "cache_hits", "cache_misses"):
-        assert key in snap
-    # the memory stamp relays through the child status file like the
-    # guard stamp — dead hw rounds record memory state at death
-    assert snap["memory"] == {"available": False}
-    bench_mod._write_status(None, "ignored")  # disabled path: no raise
+def test_unknown_device_kind_is_an_error(bench_mod):
+    with pytest.raises(RuntimeError, match="add it to _PEAK_BF16_TFLOPS"):
+        bench_mod.require_tpu({"platform": "tpu", "device_kind": "TPU v99",
+                               "device_count": 1})
+    bench_mod.require_tpu({"platform": "tpu", "device_kind": "TPU v5 lite",
+                           "device_count": 1})
 
 
 def test_memory_stamp_static_bytes(bench_mod):
@@ -175,9 +136,8 @@ def test_memory_stamp_static_bytes(bench_mod):
 
 
 def _tiny_build_step(batch, **kw):
-    """A stand-in for build_step so the resumable state machine is
-    testable in seconds: same (step, state, batch) contract, trivial
-    compile."""
+    """A stand-in for build_step so main() is testable in seconds: same
+    (step, state, batch) contract, trivial compile."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -190,101 +150,60 @@ def _tiny_build_step(batch, **kw):
     return step, jnp.zeros(()), {"image": np.ones((batch, 2), np.float32)}
 
 
-def test_resumable_warm_then_measure(bench_mod, tmp_path, monkeypatch, capsys):
-    """Attempt N warms (AOT serialized, ledger advances to 'warmed'),
-    attempt N+1 loads the executable and emits a real number with
-    attempts/interrupted_at provenance."""
-    monkeypatch.setattr(bench_mod, "build_step", _tiny_build_step)
-    monkeypatch.setattr(bench_mod, "step_flops", lambda *a: 0.0)
-    monkeypatch.setenv("FDTPU_COMPILE_CACHE_DIR", "")  # no cache dir churn
-    monkeypatch.setenv("FDTPU_AOT_DIR", str(tmp_path / "aot"))
-    ledger = str(tmp_path / "ledger.json")
-
-    # a huge measure margin forces the warm-only outcome (models a
-    # budget that only covers the cold half)
-    rc = bench_mod.resumable_main(
-        ["--ledger", ledger, "--budget", "300", "--steps", "2",
-         "--measure-margin", "1e9"])
-    assert rc == 0
-    warmed = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert warmed["warmed"] is True and warmed["value"] == 0.0
-    assert warmed["resumable"]["state"] == "warmed"
-    assert warmed["resumable"]["attempts"] == 1
-    assert any(f.startswith("bench_step-")
-               for f in os.listdir(tmp_path / "aot"))
-
-    rc = bench_mod.resumable_main(
-        ["--ledger", ledger, "--budget", "300", "--steps", "2"])
-    assert rc == 0
+def test_main_error_json_carries_retryable(bench_mod, monkeypatch, capsys):
+    """Error lines classify themselves, and every one is a non-zero
+    exit: a backend that is not there is worth another attempt, a code
+    failure past it is not."""
+    assert bench_mod.main() == 1
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["value"] > 0
-    assert out["aot_loaded"] is True, "attempt 2 must SKIP the compile"
-    assert out["measure_steps"] == 2
-    assert out["resumable"] == {
-        "attempts": 2, "interrupted_at": None, "state": "measured",
-        "ledger": ledger}
-
-
-def test_resumable_error_json_classifies_retryable(
-        bench_mod, tmp_path, monkeypatch, capsys):
-    """A code failure in the build phase emits retryable: false (the
-    watcher stops); a backend-unavailable failure emits retryable: true
-    (the watcher backs off and retries)."""
-    from fluxdistributed_tpu import faults
-
-    ledger = str(tmp_path / "ledger.json")
+    assert out["phase"] == "backend_init" and out["retryable"] is True
 
     def broken(batch, **kw):
         raise TypeError("injected code bug")
 
+    # steer past the platform check in the test, not through an option
+    monkeypatch.setattr(bench_mod, "require_tpu", lambda info: None)
     monkeypatch.setattr(bench_mod, "build_step", broken)
-    rc = bench_mod.resumable_main(["--ledger", ledger, "--budget", "60"])
-    assert rc == 0
+    assert bench_mod.main() == 1
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["value"] == 0.0
-    assert out["phase"] == "build"
-    assert out["retryable"] is False
-    assert out["resumable"]["interrupted_at"] == "build"
+    assert out["phase"] == "build" and out["retryable"] is False
+    assert "injected code bug" in out["error"]
 
-    # simulated backend-unavailable on init: the acquire_backend
-    # retries are exhausted by the plan, and the death is retryable
-    faults.install_plan(faults.FaultPlan().backend_unavailable(99))
-    try:
-        rc = bench_mod.resumable_main(
-            ["--ledger", str(tmp_path / "l2.json"), "--budget", "10"])
-    finally:
-        faults.clear_plan()
-    assert rc == 0
+
+def test_main_success_line_names_the_device(bench_mod, monkeypatch, capsys):
+    """The success path end to end (platform check and peak table
+    steered in the test): rc 0 and a result line that carries platform,
+    device_kind and device count."""
+    monkeypatch.setattr(bench_mod, "require_tpu", lambda info: None)
+    monkeypatch.setattr(bench_mod, "build_step", _tiny_build_step)
+    monkeypatch.setattr(bench_mod, "step_flops", lambda *a: 0.0)
+    monkeypatch.setattr(bench_mod, "time_compiled_step",
+                        lambda *a, **kw: (0.01, 5))
+    monkeypatch.setitem(bench_mod._PEAK_BF16_TFLOPS, "cpu", 1.0)
+    for stamp in ("lint_stamp", "pp_plan_stamp", "layout_pick_stamp"):
+        monkeypatch.setattr(bench_mod, stamp, lambda: {"skipped": True})
+    assert bench_mod.main() == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["phase"] == "backend_init"
-    assert out["retryable"] is True
+    assert out["value"] > 0 and out["unit"] == "images/sec/chip"
+    assert (out["platform"], out["device_kind"], out["device_count"]) == (
+        "cpu", "cpu", 8)
 
 
-def test_main_error_json_carries_retryable(bench_mod, monkeypatch, capsys):
-    """The classic bounded-subprocess path classifies its error JSON
-    too, so hw_watch.sh can gate its backoff on it."""
+@pytest.mark.parametrize("env_dir", ["set", None])
+def test_cache_dir_follows_the_one_rule(env_dir, tmp_path):
+    """bench.py keeps its compile cache where JAX_COMPILATION_CACHE_DIR
+    says, exactly; unset, at the fixed .jax_cache/ of the checkout."""
     import subprocess
 
-    def boom(*a, **kw):
-        raise subprocess.TimeoutExpired(cmd="bench", timeout=1)
-
-    monkeypatch.setattr(subprocess, "run", boom)
-    monkeypatch.setattr(bench_mod.time, "sleep", lambda s: None)
-    bench_mod.main()
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    # a timeout with no phase marker died in backend territory
-    assert out["retryable"] is True
-
-
-def test_default_cache_dir_env_override(bench_mod, monkeypatch):
-    """FDTPU_COMPILE_CACHE_DIR overrides the benchmarks/hw default;
-    empty string disables caching entirely."""
-    import os
-
-    monkeypatch.delenv("FDTPU_COMPILE_CACHE_DIR", raising=False)
-    assert bench_mod.default_cache_dir().endswith(
-        os.path.join("benchmarks", "hw", "xla_cache"))
-    monkeypatch.setenv("FDTPU_COMPILE_CACHE_DIR", "/somewhere/else")
-    assert bench_mod.default_cache_dir() == "/somewhere/else"
-    monkeypatch.setenv("FDTPU_COMPILE_CACHE_DIR", "")
-    assert bench_mod.default_cache_dir() is None
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "FDTPU_RUNS_LEDGER": ""}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(repo, ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    p = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
+                       capture_output=True, text=True, timeout=300, env=env)
+    assert p.returncode == 1, p.stderr[-2000:]  # chipless: non-zero
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["compile_cache_dir"] == want
+    assert out["platform"] == "cpu"

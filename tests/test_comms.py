@@ -87,35 +87,40 @@ def test_pp_1f1b_ppermute_signature(variants):
     v = variants["pp_1f1b"]
     assert _by_key(jaxpr_collectives(v.fn, v.args)) == {
         ("ppermute", ("pipe",)): 20,
-        ("all_reduce", ("pipe",)): 2,
+        ("all_reduce", ("pipe",)): 4,
         ("all_reduce", ("data",)): 16,
     }
 
 
 def test_context_ring_signature(variants):
     """Ring attention rotates KV shards with ppermute on the seq axis
-    — the context-parallel signature (psums from the shard_map
-    transpose carry no named axes on this tracer; their count is
-    pinned, their axis honestly None)."""
+    — the context-parallel signature, and the ONLY explicit collective:
+    q/k/v enter the shard_map sharded on every mesh axis, so its
+    transpose has nothing to reduce (the gradient mean over the batch
+    is GSPMD's, outside the jaxpr)."""
     v = variants["context"]
     sig = _by_key(jaxpr_collectives(v.fn, v.args))
-    assert sig[("ppermute", ("seq",))] == 16
-    assert sig[("all_reduce", None)] == 6
-    assert set(sig) == {("ppermute", ("seq",)), ("all_reduce", None)}
+    assert sig == {("ppermute", ("seq",)): 16}
 
 
 # ---- HLO layer: GSPMD-inserted collectives --------------------------------
 
 def test_dp_gspmd_hlo_all_reduce_only(variants):
     """The GSPMD dp step's jaxpr carries NO collectives (XLA inserts
-    them) — the compiled-HLO layer sees exactly the all-reduces the
-    shard_map twin writes explicitly, attributed to the data axis via
-    replica_groups."""
+    them) — the compiled-HLO layer sees them, attributed to the data
+    axis via replica_groups.  XLA's all-reduce combiner folds the 7
+    reductions the shard_map twin writes (6 leaves + the loss) into ONE
+    tuple all-reduce carrying the same bytes."""
     v = variants["dp"]
     assert jaxpr_collectives(v.fn, v.args) == []
     compiled = v.fn.lower(*v.args).compile()
-    assert _by_key(hlo_collectives(compiled, mesh=v.mesh)) == {
-        ("all_reduce", ("data",)): 7}
+    (entry,) = hlo_collectives(compiled, mesh=v.mesh)
+    assert (entry["kind"], entry["axes"], entry["count"]) == (
+        "all_reduce", ["data"], 1)
+    # the combined op still moves every gradient leaf + the loss
+    twin = variants["dp_shardmap"]
+    assert entry["bytes"] == total_bytes(
+        jaxpr_collectives(twin.fn, twin.args))
 
 
 def test_tp_hlo_axes_attribution(variants):
@@ -126,8 +131,8 @@ def test_tp_hlo_axes_attribution(variants):
     v = variants["tp"]
     compiled = v.fn.lower(*v.args).compile()
     sig = _by_key(hlo_collectives(compiled, mesh=v.mesh))
-    assert sig == {("all_reduce", ("model",)): 10,
-                   ("all_reduce", ("data",)): 17}
+    assert sig == {("all_reduce", ("model",)): 8,
+                   ("all_reduce", ("data",)): 1}
 
 
 def test_layout_hlo_signatures():
@@ -136,17 +141,18 @@ def test_layout_hlo_signatures():
     over the JOINT (data, fsdp) communicator (the batch shards over
     both, so the mean is one all-reduce spanning both axes); the
     tp-composed LM layouts split activation reductions onto the model
-    axis next to the batch-communicator gradient mean — byte-identical
-    structure to the hand-built tp variant's (17 data + 10 model) with
-    the batch communicator renamed to the layout's axes.  The
+    axis next to the batch-communicator gradient mean (combined into
+    one tuple all-reduce, as in dp) — the same structure as the
+    hand-built tp variant's (1 data + 8 model) with the batch
+    communicator renamed to the layout's axes.  The
     replica_groups matcher must untangle the multi-axis groups of the
     3-D mesh, including the joint (data, fsdp) combination."""
     cases = {
-        "layout_dp_fsdp": {("all_reduce", ("data", "fsdp")): 7},
-        "layout_fsdp_tp": {("all_reduce", ("fsdp",)): 17,
-                           ("all_reduce", ("model",)): 10},
-        "layout_dp_fsdp_tp": {("all_reduce", ("data", "fsdp")): 17,
-                              ("all_reduce", ("model",)): 10},
+        "layout_dp_fsdp": {("all_reduce", ("data", "fsdp")): 1},
+        "layout_fsdp_tp": {("all_reduce", ("fsdp",)): 1,
+                           ("all_reduce", ("model",)): 8},
+        "layout_dp_fsdp_tp": {("all_reduce", ("data", "fsdp")): 1,
+                              ("all_reduce", ("model",)): 8},
     }
     for name, want in cases.items():
         (v,) = build_variants([name])
@@ -160,14 +166,14 @@ def test_layout_hlo_signatures():
 def test_fsdp_hlo_signature(variants):
     """fsdp's compiled signature pinned as XLA emits it HERE: on this
     CPU build the tiny model's gather/scatter pairs fold into plain
-    all-reduces (sharding propagation re-replicates small params) —
-    the pinned count is the regression tripwire; a future XLA emitting
-    all-gather+reduce-scatter instead is a deliberate baseline
-    update."""
+    all-reduces (sharding propagation re-replicates small params),
+    combined into one — the pinned count is the regression tripwire; a
+    future XLA emitting all-gather+reduce-scatter instead is a
+    deliberate baseline update."""
     v = variants["fsdp"]
     compiled = v.fn.lower(*v.args).compile()
     assert _by_key(hlo_collectives(compiled, mesh=v.mesh)) == {
-        ("all_reduce", ("data",)): 7}
+        ("all_reduce", ("data",)): 1}
 
 
 # ---- counting semantics ---------------------------------------------------

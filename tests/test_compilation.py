@@ -39,16 +39,6 @@ def test_topology_fingerprint_stable_and_tag_sensitive():
     assert compilation.topology_fingerprint(mesh=data_mesh()) != a
 
 
-def test_topology_namespace_is_readable():
-    ns = compilation.topology_namespace()
-    # platform, device/process counts and jax version are all legible —
-    # the cache dir layout documents itself
-    assert ns.startswith("cpu-")
-    assert f"d{jax.device_count()}p{jax.process_count()}" in ns
-    assert jax.__version__ in ns
-    assert "/" not in ns and " " not in ns
-
-
 def test_abstract_signature_tracks_shapes_and_structure():
     x, y = jnp.ones((4, 4)), jnp.ones((8, 4))
     assert (compilation.abstract_signature((x,))
@@ -65,51 +55,67 @@ def test_abstract_signature_tracks_shapes_and_structure():
 
 
 @pytest.fixture
-def restore_cache_config():
+def restore_cache_config(monkeypatch):
+    """Cache tests start from "variable unset" and leave the process's
+    cache config as they found it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.delenv(compilation.CACHE_DIR_ENV, raising=False)
     prev = jax.config.jax_compilation_cache_dir
     yield
-    from fluxdistributed_tpu import compat
-
-    if prev:
-        compat.configure_compilation_cache(prev)
-    else:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        from jax._src import compilation_cache as _icc
-
-        _icc.reset_cache()  # drop the memoized cache-in-use decision
+    jax.config.update("jax_compilation_cache_dir", prev)
+    cc.reset_cache()  # drop the memoized cache-in-use decision
     compilation._cache_dir = None
+
+
+@pytest.mark.parametrize("env, explicit, want", [
+    # the variable wins over everything, verbatim
+    ("/x", None, "/x"),
+    ("/x", "/elsewhere", "/x"),
+    # unset: an explicit --compile-cache DIR
+    (None, "/elsewhere", "/elsewhere"),
+    ("", "/elsewhere", "/elsewhere"),
+    # neither: the ONE fixed in-checkout path
+    (None, None, os.path.join(REPO, ".jax_cache")),
+])
+def test_resolve_cache_dir_rule(monkeypatch, env, explicit, want):
+    if env is None:
+        monkeypatch.delenv(compilation.CACHE_DIR_ENV, raising=False)
+    else:
+        monkeypatch.setenv(compilation.CACHE_DIR_ENV, env)
+    assert compilation.resolve_cache_dir(explicit) == want
+    # fixed: asking twice (another process, another time) agrees
+    assert compilation.resolve_cache_dir(explicit) == want
+
+
+def test_default_cache_dir_is_gitignored():
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 def test_enable_persistent_cache(tmp_path, restore_cache_config):
     resolved = compilation.enable_persistent_cache(str(tmp_path / "cc"))
-    assert resolved is not None and os.path.isdir(resolved)
-    # namespaced per topology under the requested root
-    assert os.path.dirname(resolved) == str(tmp_path / "cc")
-    assert os.path.basename(resolved) == compilation.topology_namespace()
+    # exactly the directory asked for: no sub-directory appended
+    assert resolved == str(tmp_path / "cc") and os.path.isdir(resolved)
     assert jax.config.jax_compilation_cache_dir == resolved
     assert compilation.persistent_cache_dir() == resolved
     assert get_registry().value("fdtpu_compile_cache_enabled") == 1
-    # falsy dir = disabled, no side effects
-    assert compilation.enable_persistent_cache(None) is None
-    assert compilation.enable_persistent_cache("") is None
+    # thresholds: cache everything
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
 
 
-def test_configure_compilation_cache_shim_never_raises(tmp_path, monkeypatch,
-                                                       restore_cache_config):
-    """On a jax build without ANY cache knob the shim warns and reports
-    False — enablement must be a no-op, not a crash."""
-    from fluxdistributed_tpu import compat
-
-    assert compat.configure_compilation_cache(str(tmp_path)) is True
-    # simulate the knob-less build: every config update fails and the
-    # legacy set_cache_dir import path is absent
-    monkeypatch.setattr(compat, "_try_config_update", lambda *a: False)
-    import jax.experimental.compilation_cache.compilation_cache as legacy
-
-    monkeypatch.delattr(legacy, "set_cache_dir", raising=False)
-    with pytest.warns(RuntimeWarning, match="no persistent compilation cache"):
-        assert compat.configure_compilation_cache(str(tmp_path)) is False
-    assert compilation.enable_persistent_cache(str(tmp_path / "x")) is None
+def test_enable_persistent_cache_sets_no_dir_when_env_set(
+        tmp_path, restore_cache_config, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the program keeps its cache
+    exactly there and sets no directory in code: jax read the variable
+    at import, so the config value is left as it stands."""
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compilation.CACHE_DIR_ENV, str(tmp_path / "env"))
+    resolved = compilation.enable_persistent_cache(str(tmp_path / "flag"))
+    assert resolved == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not os.path.exists(tmp_path / "flag")
 
 
 # ------------------------------------------------------------------ AOT files
@@ -369,7 +375,7 @@ def test_prepare_training_cache_dir_enables_cache(tmp_path,
                                                   restore_cache_config):
     task = _prepare(cache_dir=str(tmp_path / "cc"))
     resolved = compilation.persistent_cache_dir()
-    assert resolved and resolved.startswith(str(tmp_path / "cc"))
+    assert resolved == str(tmp_path / "cc")
     assert jax.config.jax_compilation_cache_dir == resolved
     # the prepare-time compiles (model init) already populated it
     batch = _first_batch(task)

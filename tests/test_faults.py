@@ -403,15 +403,14 @@ def test_bench_retryable_classification():
     bench = _bench_mod()
     # the backend_init phase IS the unavailability being waited out
     assert bench.retryable_error("backend_init", "anything at all")
-    # unavailable/timeout signatures: retryable in any phase (a
-    # compile-WINDOW expiry surfaces as a timeout signature)
+    # unavailable/timeout signatures: retryable in any phase
     assert bench.retryable_error("compile", "measurement subprocess timed out")
     assert bench.retryable_error("measure", "UNAVAILABLE: socket closed")
     assert bench.retryable_error(
         "build", "remote_compile: read body: response body closed")
     assert bench.retryable_error("measure", "subprocess timed out after 60s")
-    # real failures: not retryable — the watcher must stop hammering,
-    # INCLUDING deterministic compile-phase code errors
+    # real failures: not retryable, INCLUDING deterministic
+    # compile-phase code errors
     assert not bench.retryable_error(
         "build", "TypeError: build_step() got an unexpected keyword")
     assert not bench.retryable_error(
@@ -423,16 +422,3 @@ def test_bench_retryable_classification():
     from fluxdistributed_tpu.faults import UNAVAILABLE_SIGNATURES
 
     assert bench._unavailable_sigs() is UNAVAILABLE_SIGNATURES
-
-
-def test_bench_resumable_ledger_io(tmp_path):
-    bench = _bench_mod()
-    path = str(tmp_path / "sub" / "ledger.json")
-    bench._write_json_atomic(path, {"state": "warmed", "attempts": [1]})
-    assert bench._read_json(path) == {"state": "warmed", "attempts": [1]}
-    assert bench._read_json(str(tmp_path / "missing.json")) is None
-    # corrupt file reads as None, never raises
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert bench._read_json(str(bad)) is None
-    assert not list(tmp_path.glob("**/*.tmp.*")), "atomic writes leave no tmp"

@@ -253,3 +253,14 @@ def test_imagenet_dataset_augmented_backends_agree(tmp_path, img):
     ds_eval = ImageNetDataset(str(root), table, nclasses=1, use_native=True, augment=False)
     c, _ = ds_eval.batch(np.random.default_rng(42), 4, indices=idx)
     assert np.abs(a - c).mean() > 0.05
+
+
+def test_failed_build_is_reported_loudly_not_swallowed(monkeypatch, tmp_path):
+    """A checkout builds the library from native/fd_native.cpp; when the
+    toolchain is missing the failure is a RuntimeWarning that carries
+    the compiler command — not a silent fall-back to PIL."""
+    monkeypatch.setenv("CXX", str(tmp_path / "no-such-compiler"))
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "build" / "lib.so"))
+    with pytest.warns(RuntimeWarning, match="failed to build.*no-such-compiler"):
+        assert native._build() is False
+    assert not os.path.exists(tmp_path / "build" / "lib.so")

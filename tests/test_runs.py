@@ -159,12 +159,21 @@ def test_ingest_preserves_fields_and_dedupes(tmp_path):
     assert len(runs_lib.load_runs(ledger)) == 2
 
 
-def test_committed_ledger_is_clean():
+def test_committed_ledger_is_clean(tmp_path):
     """The acceptance criterion's first half: ``--check`` on the
-    repo's own history must pass."""
-    runs = runs_lib.load_runs(
-        os.path.join(REPO, "benchmarks", "hw", "runs.jsonl"))
-    assert len(runs) >= 10  # the five dead bench + five multichip rounds
+    repo's own history must pass.  The history is the kept round
+    records at the root of the repo, ingested into a ledger here (no
+    mirror of them is committed; the default ledger file appears with
+    the first run on the chip that appends to it)."""
+    import glob
+
+    kept = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))
+                  + glob.glob(os.path.join(REPO, "MULTICHIP_r*.json")))
+    ledger = str(tmp_path / "runs.jsonl")
+    added, skipped = runs_lib.ingest_paths(ledger, kept)
+    assert (added, skipped) == (len(kept), 0) and added >= 5
+    runs = runs_lib.load_runs(ledger)
+    assert {r["kind"] for r in runs} == {"bench", "multichip"}
     assert runs_lib.check_regressions(runs)["failures"] == []
 
 

@@ -1,0 +1,173 @@
+"""The Pallas kernels of the chip path, put through the v5e compiler.
+
+The only file of the suite that describes a chip.  Interpret mode (what
+every other kernel test runs) cannot see what Mosaic refuses — a block
+that is not (8, 128)-tileable, too much VMEM — so each Pallas entry
+point is compiled here for a DESCRIBED ``v5e:2x2`` device at the widths
+``chip_smoke.py`` runs on the real one: GPT-2-small attention (12 heads
+x 64) at T = 1024 and 2048, decode batch 8 over a 1024-row cache, the
+paged pool ``[512, 16, 12, 64]``, Adam over a ResNet-50-sized flat
+buffer.  Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped, non-autouse fixture
+(never at import, in a ``skipif`` or in ``parametrize``): only the
+worker that is handed this file loads the TPU compiler, and it compiles
+in its own process with the persistent cache off.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fluxdistributed_tpu.ops import pallas_attention as pa
+from fluxdistributed_tpu.ops import pallas_decode as pd
+from fluxdistributed_tpu.parallel import zero1_fused as zf
+
+H, HKV, D = 12, 4, 64
+B_ATTN, B_DEC, ROWS = 4, 8, 1024
+POOL, PAGE = 512, 16
+ADAM_N = 25_557_032 + (-25_557_032) % 1024
+BF = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` → an argument placed on one described v5e
+    chip, with the persistent cache off around the module's compiles (a
+    described device's entries can be written but never read back)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    # the flash wrappers bake "interpreted or compiled" in at trace
+    # time: drop the compiled-mode traces made here
+    pa._flash_fwd_impl.clear_cache()
+    pa._flash_bwd_impl.clear_cache()
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The process's backend is the CPU, so the flash wrappers would
+    pick the interpreter; steer that here, in the test."""
+    monkeypatch.setattr(pa, "interpret_mode", lambda: False)
+
+
+def _compile(fn, *args) -> str:
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+def _grads(attn):
+    return jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("t", [1024, 2048])
+def test_flash_forward(chip, as_on_tpu, t):
+    q = chip((B_ATTN, t, H, D), BF)
+    _compile(lambda q, k, v: pa.flash_attention(q, k, v, True), q, q, q)
+
+
+@pytest.mark.parametrize("t", [1024, 2048])
+def test_flash_backward(chip, as_on_tpu, t):
+    """Forward + the dQ and dK/dV kernels (three custom calls)."""
+    q = chip((B_ATTN, t, H, D), BF)
+    text = _compile(
+        _grads(lambda q, k, v: pa.flash_attention(q, k, v, True)), q, q, q)
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_flash_backward_gqa(chip, as_on_tpu):
+    q = chip((B_ATTN, 1024, H, D), BF)
+    kv = chip((B_ATTN, 1024, HKV, D), BF)
+    _compile(_grads(lambda q, k, v: pa.flash_attention(q, k, v, True)),
+             q, kv, kv)
+
+
+def test_flash_backward_window_sinks(chip, as_on_tpu):
+    q = chip((B_ATTN, 2048, H, D), BF)
+    _compile(_grads(lambda q, k, v: pa.flash_attention(
+        q, k, v, True, 128, 128, 256, 4)), q, q, q)
+
+
+def test_flash_lse_backward(chip, as_on_tpu):
+    """The ring-attention building block: LSE out, and its cotangent
+    folded into the same backward kernels."""
+    q = chip((B_ATTN, 1024, H, D), BF)
+    _compile(jax.grad(
+        lambda q, k, v: sum(x.astype(jnp.float32).sum() for x in
+                            pa.flash_attention_lse(q, k, v, True)),
+        argnums=(0, 1, 2)), q, q, q)
+
+
+@pytest.mark.parametrize("hkv", [H, HKV], ids=["dense", "gqa"])
+def test_decode(chip, hkv):
+    q, idx = chip((B_DEC, 1, H, D), BF), chip((B_DEC,), jnp.int32)
+    c = chip((B_DEC, ROWS, hkv, D), BF)
+    _compile(lambda q, k, v, i: pd.flash_decode(q, k, v, i, impl="pallas"),
+             q, c, c, idx)
+
+
+def test_decode_ring_slot_pos(chip):
+    q, idx = chip((B_DEC, 1, H, D), BF), chip((B_DEC,), jnp.int32)
+    c, sp = chip((B_DEC, ROWS, H, D), BF), chip((B_DEC, ROWS), jnp.int32)
+    _compile(lambda q, k, v, i, sp: pd.flash_decode(
+        q, k, v, i, slot_pos=sp, window=ROWS - 4, sinks=4, impl="pallas"),
+        q, c, c, idx, sp)
+
+
+def test_decode_int8_scales(chip):
+    q, idx = chip((B_DEC, 1, H, D), BF), chip((B_DEC,), jnp.int32)
+    c = chip((B_DEC, ROWS, H, D), jnp.int8)
+    s = chip((B_DEC, ROWS, H), jnp.float32)
+    _compile(lambda q, k, v, i, ks, vs: pd.flash_decode(
+        q, k, v, i, k_scale=ks, v_scale=vs, impl="pallas"),
+        q, c, c, idx, s, s)
+
+
+@pytest.mark.parametrize("variant", ["plain", "int8", "ring"])
+def test_decode_paged(chip, variant):
+    q, idx = chip((B_DEC, 1, H, D), BF), chip((B_DEC,), jnp.int32)
+    pt = chip((B_DEC, ROWS // PAGE), jnp.int32)
+    pool = chip((POOL, PAGE, H, D), jnp.int8 if variant == "int8" else BF)
+    arrays, static = {}, {}
+    if variant == "int8":
+        scale = chip((POOL, PAGE, H), jnp.float32)
+        arrays = {"k_scale": scale, "v_scale": scale}
+    elif variant == "ring":
+        arrays = {"slot_pos": chip((B_DEC, ROWS), jnp.int32)}
+        static = dict(window=ROWS - 4, sinks=4)
+    _compile(lambda q, k, v, pt, i, arrays: pd.flash_decode_paged(
+        q, k, v, pt, i, impl="pallas", **arrays, **static),
+        q, pool, pool, pt, idx, arrays)
+
+
+def test_fused_adam(chip):
+    f = chip((ADAM_N,), jnp.float32)
+    _compile(lambda p, g, m, v: zf.fused_adam_update(
+        p, g, m, v, jnp.int32(3), impl="pallas"), f, f, f, f)
