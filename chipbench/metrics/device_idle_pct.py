@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran,
+averaged over the chips used."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
