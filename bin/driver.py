@@ -191,11 +191,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "the duration of the run (coordinator host only — "
                         "the serve/server.py stdlib-HTTP pattern)")
     p.add_argument("--trace-events", default=None, metavar="PATH",
-                   help="record nested step-phase spans (data_wait/h2d/"
-                        "dispatch/device/eval/checkpoint) and write "
-                        "Chrome/Perfetto trace-event JSON here at exit; "
-                        "implies per-step device sync so device time is "
-                        "honestly attributed")
+                   help="write the step timeline (every loader item's item/"
+                        "data_wait/dispatch/device/assemble/h2d spans, plus "
+                        "compile/eval/checkpoint; always recorded, in a "
+                        "bounded ring) as Chrome/Perfetto trace-event JSON "
+                        "here at exit; the loop runs as it does without")
     p.add_argument("--metrics-jsonl", default=None, metavar="PATH",
                    help="append registry snapshots (JSON lines) here at the "
                         "print cadence — offline run diffing without a "
@@ -744,22 +744,21 @@ def main(argv=None) -> int:
         # non-coordinators stay quiet unless --verbose
         logger = ConsoleLogger() if (multihost.is_coordinator() or args.verbose) else NullLogger()
 
-    # Unified observability: phase metrics + compile counters always on;
-    # spans/watchdog/endpoints per flags.  The metrics endpoint binds on
-    # the coordinator only (a fake cluster runs many processes per host —
-    # N processes racing for one port helps nobody).
+    # Unified observability: phase metrics, compile counters and the step
+    # timeline always on; watchdog/endpoints/files per flags.  The
+    # metrics endpoint binds on the coordinator only (a fake cluster runs
+    # many processes per host — N processes racing for one port helps
+    # nobody).
     from fluxdistributed_tpu.obs import (
-        Observation, SpanTracer, StepWatchdog, get_registry,
+        Observation, StepWatchdog, get_registry,
         start_metrics_server,
     )
 
     observation = Observation(
-        tracer=SpanTracer() if args.trace_events else None,
         watchdog=(StepWatchdog(factor=args.watchdog_factor,
                                escalate_after=args.watchdog_escalate)
                   if args.watchdog_factor else None),
         trace_path=args.trace_events,
-        device_sync=bool(args.trace_events),
         steady_after=args.steady_after,
         jsonl_path=args.metrics_jsonl,
         profile_path=args.profile_out,

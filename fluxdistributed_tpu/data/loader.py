@@ -159,14 +159,14 @@ class PrefetchLoader:
         self.retries = max(0, retries)
         self.sharding = NamedSharding(mesh, P(batch_entry(axis)))
         self._chunk_sharding = NamedSharding(mesh, P(None, batch_entry(axis)))
-        # observability: queue depth + h2d timing land in the process
-        # registry so /metrics can answer "is the input pipeline keeping
-        # up"; a tracer (set by train() when span tracing is on) adds
-        # h2d spans on the worker threads' own timeline rows
-        from ..obs import get_registry
+        # observability: queue depth + assemble/h2d timing land in the
+        # process registry so /metrics can answer "is the input pipeline
+        # keeping up"; the same brackets are spans of the loader item on
+        # the worker threads' own rows of the process tracer's timeline
+        from ..obs import get_registry, get_tracer
 
         reg = get_registry()
-        self.tracer = None
+        self._tracer = get_tracer()
         self._m_depth = reg.gauge(
             "fdtpu_data_prefetch_depth",
             "device-ready batches waiting in the prefetch queue "
@@ -289,17 +289,15 @@ class PrefetchLoader:
                     # via the fault plan) cost a short backoff, not the
                     # run; batch content is index-pure so a retry is
                     # bit-identical
-                    host = faults.with_retries(
-                        lambda: self._make_item(i),
-                        tries=self.retries + 1, backoff=0.05,
-                        site="loader")
+                    with self._tracer.span("assemble", item=i,
+                                           parent="item"):
+                        host = faults.with_retries(
+                            lambda: self._make_item(i),
+                            tries=self.retries + 1, backoff=0.05,
+                            site="loader")
                     t1 = time.perf_counter()
                     self._m_assemble.observe(t1 - t0)
-                    tracer = self.tracer
-                    if tracer is not None:
-                        with tracer.span("h2d", batch=i):
-                            dev = self._put(host)
-                    else:
+                    with self._tracer.span("h2d", item=i, parent="item"):
                         dev = self._put(host)
                     self._m_h2d.observe(time.perf_counter() - t1)
                     self._m_batches.inc()

@@ -5,7 +5,9 @@ One registry, four producers, three consumers:
 
 * :mod:`.metrics` — process-wide Counter/Gauge/Histogram registry with
   Prometheus text exposition and a JSONL snapshot sink;
-* :mod:`.spans` — contextvar-nested step-phase spans exporting
+* :mod:`.spans` — the step timeline: one process-wide, always-on,
+  bounded span tracer (every loader item's wait, dispatch and completion
+  under one id, also as ``jax.profiler`` annotations), exporting
   Chrome/Perfetto trace-event JSON;
 * :mod:`.jaxmon` — ``jax.monitoring`` listeners: compile counts/seconds
   and steady-state recompile flagging;
@@ -27,9 +29,10 @@ One registry, four producers, three consumers:
 
 :class:`Observation` bundles the per-run pieces for the trainer:
 ``train(task, observation=Observation.full(trace_path="run.trace.json"))``
-gets phase spans, a stall watchdog, per-step device sync timing and a
-trace file; the default (``None``) still feeds step counters, phase
-histograms and compile counts into the process registry for free.
+adds a stall watchdog and a trace file; the default (``None``) already
+feeds step counters, phase histograms and compile counts into the
+process registry and every loader item's spans into the process tracer
+(:func:`get_tracer`).
 """
 
 from __future__ import annotations
@@ -52,10 +55,18 @@ from .metrics import (
 from .profile import Profile, ProfileMismatch, collect_profile
 from .reqtrace import RequestTracer
 from .server import MetricsServer, start_metrics_server
-from .spans import SpanTracer, current_span, innermost_active
+from .spans import (
+    CompletionWatcher,
+    SpanTracer,
+    current_item,
+    current_span,
+    get_tracer,
+    innermost_active,
+)
 from .watchdog import StepWatchdog
 
 __all__ = [
+    "CompletionWatcher",
     "Counter",
     "FlightRecorder",
     "Gauge",
@@ -73,8 +84,10 @@ __all__ = [
     "bucket_percentile",
     "collect_profile",
     "comms",
+    "current_item",
     "current_span",
     "get_registry",
+    "get_tracer",
     "innermost_active",
     "jaxmon",
     "memstats",
@@ -91,15 +104,10 @@ class Observation:
     Attributes
     ----------
     registry: where counters/histograms live (default: process registry)
-    tracer: span tracer, or None for metrics-only (no timeline buffer)
     watchdog: stall watchdog, or None; ``train`` starts/stops it
-    trace_path: write the tracer's Chrome trace JSON here when training
-        ends (requires ``tracer``)
-    device_sync: ``block_until_ready`` each step's outputs inside a
-        ``device`` span.  This closes the host's dispatch run-ahead, so
-        the device phase is honestly attributed — worth it when you are
-        reading a breakdown, wrong as an always-on default (it
-        serializes host and device).
+    trace_path: write the process tracer's ring (:func:`get_tracer`) as
+        Chrome trace JSON here when training ends.  The spans are
+        recorded either way; this only exports them.
     steady_after: after this many loader items, declare
         :func:`jaxmon.mark_steady` — any later XLA compile is flagged as
         a steady-state recompile.  None (default) = never; eval or
@@ -107,10 +115,8 @@ class Observation:
     """
 
     registry: Registry = dataclasses.field(default_factory=get_registry)
-    tracer: Optional[SpanTracer] = None
     watchdog: Optional[StepWatchdog] = None
     trace_path: Optional[str] = None
-    device_sync: bool = False
     steady_after: Optional[int] = None
     # append a registry snapshot line here at the print cadence and at
     # exit (offline run diffing — no Prometheus server required)
@@ -128,8 +134,8 @@ class Observation:
 
     @classmethod
     def default(cls) -> "Observation":
-        """Metrics-only: counters + phase histograms in the process
-        registry; no span buffer, no watchdog thread, no device sync."""
+        """Counters and phase histograms in the process registry, spans
+        in the process tracer; no watchdog thread, no files."""
         return cls()
 
     @classmethod
@@ -143,15 +149,13 @@ class Observation:
         profile_path: Optional[str] = None,
         flight_path: Optional[str] = None,
     ) -> "Observation":
-        """Everything on: spans (the trainer feeds the phase histogram
-        from the same brackets), stall watchdog, per-step device sync."""
+        """The default plus a stall watchdog and the files asked for.
+        The loop observed is the loop every user runs."""
         registry = registry or get_registry()
         return cls(
             registry=registry,
-            tracer=SpanTracer(),
             watchdog=StepWatchdog(factor=watchdog_factor, registry=registry),
             trace_path=trace_path,
-            device_sync=True,
             steady_after=steady_after,
             jsonl_path=jsonl_path,
             profile_path=profile_path,

@@ -9,7 +9,9 @@ backend_compile_duration`` — the same hooks TensorBoard's profiler
 consumes); this module folds them into the metrics registry:
 
 * ``fdtpu_jax_compiles_total`` / ``fdtpu_jax_compile_seconds_total`` —
-  every backend compile, count and wall seconds;
+  every backend compile, count and wall seconds; each is also a
+  ``compile`` span ``[now - duration, now]`` in the process tracer
+  (:mod:`.spans`), carrying the loader item during which it fell;
 * ``fdtpu_jax_trace_seconds_total`` — jaxpr tracing time (host-side
   program construction, distinct from XLA compile time);
 * ``fdtpu_jax_steady_recompiles_total`` — compiles that happened AFTER
@@ -34,9 +36,11 @@ from __future__ import annotations
 import contextlib
 import sys
 import threading
+import time
 import weakref
 from typing import Callable, Optional
 
+from . import spans
 from .metrics import Registry, get_registry
 
 __all__ = [
@@ -94,6 +98,13 @@ def _listener(event: str, duration: float, **kwargs) -> None:
     if event == BACKEND_COMPILE_EVENT:
         _inc("fdtpu_jax_compiles_total")
         _inc("fdtpu_jax_compile_seconds_total", duration)
+        # on the step timeline too, with the loader item during which it
+        # fell: "which step recompiled" is one look
+        now = time.perf_counter()
+        item = spans.current_item()
+        spans.get_tracer().record(
+            "compile", now - duration, now,
+            **({} if item is None else {"item": item, "parent": "item"}))
         if _steady:
             _inc("fdtpu_jax_steady_recompiles_total")
             _warn(

@@ -1,46 +1,53 @@
-"""Nested step-phase spans with Chrome/Perfetto trace-event export.
+"""The step timeline: one process-wide, always-on, bounded span tracer.
 
 ``jax.profiler`` answers "what did the DEVICE do" at ~GB trace cost for
-a fixed window; this tracer answers "where did the HOST loop's time go"
-continuously and for pennies: the trainer brackets every phase of every
-step (data-wait / h2d / dispatch / device / eval / checkpoint) in a
-span, spans nest through a contextvar (so helper code can add spans
-without threading a handle), and the buffer exports as Chrome
-trace-event JSON — load it at ``chrome://tracing`` or ui.perfetto.dev
-next to a device trace.
+a fixed window; this tracer answers "where did the HOST loop's time go,
+and did the device starve meanwhile" continuously and for pennies.  The
+trainer opens an ``item`` span per loader item with ``data_wait`` /
+``dispatch`` / ``eval`` / ``checkpoint`` children, the prefetch workers
+record ``assemble`` and ``h2d``, a :class:`CompletionWatcher` closes a
+``device`` span when the item's step has really finished, and
+:mod:`.jaxmon` records every backend ``compile``.  All spans of one
+loader item carry its ``item`` id and the ``parent`` that caused them,
+so a timeline reader joins them without guessing.
 
-Overhead discipline:
+The same brackets also open ``jax.profiler`` annotations
+(``fdtpu/<name>`` with the ``item`` stat; the ``item`` span is a
+``StepTraceAnnotation``), so a profiler session shows them in its
+``/host:CPU`` plane beside the device planes and on their clock.  With
+no session recording an annotation costs well under a microsecond.
 
-* a **disabled** tracer hands out one shared no-op context manager —
-  the instrumented hot loop pays an attribute load and a truthiness
-  check, no allocation;
-* an **enabled** tracer appends one small dict per span to a bounded
-  ring (default 200k events ≈ a few hours of stepping) under a lock
-  only at span END; timestamps come from ``perf_counter`` (monotonic,
-  ns resolution).
-
-Spans can simultaneously feed a registry :class:`~.metrics.Histogram`
-labeled by phase, so the SAME brackets produce both the live
-``/metrics`` percentiles and the offline timeline.
+Cost discipline: a span appends one small tuple to a bounded ring under
+a lock only at span END; timestamps come from ``perf_counter``.  The
+ring (default 20,000 spans, more than 2,500 loader items) stays flat
+over a days-long run; ``export_chrome_trace`` writes it as Chrome
+trace-event JSON for ``chrome://tracing`` or ui.perfetto.dev.
 """
 
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import itertools
 import json
+import queue
 import threading
 import time
 from collections import deque
-from typing import Optional
+from typing import Callable, Optional
 
-from .metrics import Histogram
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-__all__ = ["SpanTracer", "current_span", "innermost_active", "phase_scope"]
+__all__ = [
+    "CompletionWatcher",
+    "SpanTracer",
+    "current_item",
+    "current_span",
+    "get_tracer",
+    "innermost_active",
+]
 
-# name of the innermost open span in this context ("" at top level);
-# contextvars give correct nesting across threads AND async contexts
+# the open spans of this context, innermost last; contextvars give
+# correct nesting across threads AND async contexts
 _stack: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "fdtpu_span_stack", default=()
 )
@@ -49,26 +56,25 @@ _stack: contextvars.ContextVar[tuple] = contextvars.ContextVar(
 def current_span() -> Optional[str]:
     """Innermost open span name in the calling context, or ``None``."""
     s = _stack.get()
-    return s[-1] if s else None
+    return s[-1].name if s else None
 
 
 # -- cross-thread active-span registry -------------------------------------
 # The contextvar above answers "where am I" for the CALLING context; the
 # stall watchdog needs "where is the LOOP" from its own daemon thread.
-# Every open span (and every tracer-less phase bracket via phase_scope)
-# also registers here: {thread_id: [(seq, name), ...]}, where seq is a
-# global open-order counter so "innermost" is well-defined across
-# threads.  One small lock + list op per span — phases tick a handful of
-# times per step, never per token.
+# Every open span also registers here: {thread_id: [(seq, span), ...]},
+# where seq is a global open-order counter so "innermost" is well-defined
+# across threads.  One small lock + list op per span — phases tick a
+# handful of times per step, never per token.
 _active_lock = threading.Lock()
 _active: dict = {}
 _active_seq = itertools.count(1)
 
 
-def _active_push(name: str) -> None:
+def _active_push(span: "_Span") -> None:
     tid = threading.get_ident()
     with _active_lock:
-        _active.setdefault(tid, []).append((next(_active_seq), name))
+        _active.setdefault(tid, []).append((next(_active_seq), span))
 
 
 def _active_pop() -> None:
@@ -81,93 +87,86 @@ def _active_pop() -> None:
             _active.pop(tid, None)
 
 
+def _newest_active(want=lambda span: True) -> Optional["_Span"]:
+    with _active_lock:
+        newest, found = 0, None
+        for stack in _active.values():
+            for seq, span in reversed(stack):
+                if want(span):
+                    if seq > newest:
+                        newest, found = seq, span
+                    break
+    return found
+
+
 def innermost_active() -> Optional[str]:
-    """Name of the most recently OPENED still-open span/phase across all
+    """Name of the most recently OPENED still-open span across all
     threads, or ``None`` — what the stall watchdog reports as "where the
     loop is wedged" (a stalled step is, by definition, inside whichever
     bracket opened last and never closed)."""
-    with _active_lock:
-        newest, name = 0, None
-        for stack in _active.values():
-            if stack and stack[-1][0] > newest:
-                newest, name = stack[-1]
-    return name
+    span = _newest_active()
+    return span.name if span is not None else None
 
 
-@contextlib.contextmanager
-def phase_scope(name: str):
-    """Register ``name`` as the active phase WITHOUT a tracer: the
-    metrics-only trainer path brackets its phases with this so the
-    watchdog can still name where a stall happened (no event buffer, no
-    histogram — just the active-span registry above)."""
-    _active_push(name)
-    try:
-        yield
-    finally:
-        _active_pop()
-
-
-class _NullSpan:
-    """The disabled path — one shared instance, __enter__/__exit__ only."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+def current_item() -> Optional[int]:
+    """The loader item the caller works for: the ``item`` of the
+    innermost open span of the calling context, else of the newest open
+    span anywhere that has one (a compile on a thread without spans
+    falls during the loop's current item)."""
+    s = _stack.get()
+    if s and s[-1].item is not None:
+        return s[-1].item
+    span = _newest_active(lambda sp: sp.item is not None)
+    return span.item if span is not None else None
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_token")
+    __slots__ = ("_tracer", "name", "args", "item", "_t0", "_token", "_note")
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self.item = args.get("item")
 
     def __enter__(self):
-        self._token = _stack.set(_stack.get() + (self.name,))
-        _active_push(self.name)
+        stack = _stack.get()
+        if stack:
+            # a child names the span that caused it and works for the
+            # same loader item unless it says otherwise
+            parent = stack[-1]
+            self.args.setdefault("parent", parent.name)
+            if self.item is None and parent.item is not None:
+                self.item = self.args["item"] = parent.item
+        self._token = _stack.set(stack + (self,))
+        _active_push(self)
+        if self.name == "item":
+            self._note = StepTraceAnnotation("fdtpu/item", step_num=self.item)
+        elif self.item is not None:
+            self._note = TraceAnnotation("fdtpu/" + self.name, item=self.item)
+        else:
+            self._note = TraceAnnotation("fdtpu/" + self.name)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._note.__exit__(*exc)
         _active_pop()
         _stack.reset(self._token)
-        self._tracer._record(self.name, self._t0, t1, self.args)
+        self._tracer.record(self.name, self._t0, t1, **self.args)
         return False
 
 
 class SpanTracer:
-    """Collects spans; exports Chrome trace-event JSON.
+    """Collects spans in a bounded ring; exports Chrome trace-event JSON.
 
-    Parameters
-    ----------
-    enabled: hand out real spans (False = shared no-op, near-zero cost)
-    max_events: ring capacity; oldest events drop first (a days-long run
-        must not grow host memory without bound)
-    histogram: optional labeled :class:`Histogram` — every completed
-        span also observes its seconds under ``{label: name}`` so the
-        same bracket feeds /metrics
-    label: the histogram's label name (default ``"phase"``)
+    ``max_events`` is the ring's capacity; the oldest spans drop first
+    (a days-long run must not grow host memory without bound).
     """
 
-    def __init__(
-        self,
-        enabled: bool = True,
-        max_events: int = 200_000,
-        histogram: Optional[Histogram] = None,
-        label: str = "phase",
-    ):
-        self.enabled = enabled
-        self.histogram = histogram
-        self.label = label
+    def __init__(self, max_events: int = 20_000):
         self._events: deque = deque(maxlen=max_events)
         self._lock = threading.Lock()
         # trace-event ts fields are µs relative to this origin; pairing
@@ -177,25 +176,15 @@ class SpanTracer:
         self.dropped = 0
 
     def span(self, name: str, **args):
-        """``with tracer.span("data_wait"):`` — bracket one phase."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args or None)
+        """``with tracer.span("data_wait"):`` — bracket one phase.  An
+        ``item=`` argument is the loader item the span works for;
+        nested spans inherit it and record their ``parent``."""
+        return _Span(self, name, args)
 
-    def _record(self, name, t0, t1, args) -> None:
-        if self.histogram is not None:
-            self.histogram.labels(**{self.label: name}).observe(t1 - t0)
-        ev = {
-            "name": name,
-            "ph": "X",  # complete event: begin ts + dur in one record
-            "ts": (t0 - self._origin) * 1e6,
-            "dur": (t1 - t0) * 1e6,
-            "pid": 0,
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-            "cat": "fdtpu",
-        }
-        if args:
-            ev["args"] = args
+    def record(self, name: str, t0: float, t1: float, **args) -> None:
+        """A span whose ends were taken elsewhere (``perf_counter``
+        seconds): a step's completion, a compile reported afterwards."""
+        ev = (name, t0, t1, threading.get_ident() & 0x7FFFFFFF, args)
         with self._lock:
             if len(self._events) == self._events.maxlen:
                 self.dropped += 1
@@ -211,10 +200,25 @@ class SpanTracer:
             self.dropped = 0
 
     def trace_events(self) -> list:
-        """The Chrome trace-event list (JSON-ready dicts, time-ordered
-        per thread by construction)."""
+        """The Chrome trace-event list (JSON-ready dicts, in the order
+        the spans ENDED)."""
         with self._lock:
-            return list(self._events)
+            events = list(self._events)
+        out = []
+        for name, t0, t1, tid, args in events:
+            ev = {
+                "name": name,
+                "ph": "X",  # complete event: begin ts + dur in one record
+                "ts": (t0 - self._origin) * 1e6,
+                "dur": (t1 - t0) * 1e6,
+                "pid": 0,
+                "tid": tid,
+                "cat": "fdtpu",
+            }
+            if args:
+                ev["args"] = args
+            out.append(ev)
+        return out
 
     def export_chrome_trace(self, path: str) -> int:
         """Write the buffer as a Chrome/Perfetto trace-event JSON file;
@@ -237,3 +241,68 @@ class SpanTracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return len(events)
+
+
+_TRACER = SpanTracer()
+
+
+def get_tracer() -> SpanTracer:
+    """The process-wide tracer — what the trainer loop, the prefetch
+    workers and the compile listener share, beside ``get_registry()``."""
+    return _TRACER
+
+
+class CompletionWatcher:
+    """Stamps when each loader item's step really finished, without
+    blocking the loop that dispatched it.
+
+    The loop hands over ``(item, value, dispatched)`` in order, where
+    ``value`` is the step's small output (its metrics, never the state)
+    and ``dispatched`` the ``perf_counter`` time its dispatch returned.
+    One daemon thread waits on each value in turn and records a
+    ``device`` span from the later of ``dispatched`` and the item
+    before's completion to this item's completion: the time in which the
+    device had been handed the item and not yet finished it.  An error that surfaces at completion
+    is recorded on the span and never raised into the loop.
+    """
+
+    def __init__(self, tracer: SpanTracer,
+                 on_done: Optional[Callable[[float], None]] = None):
+        self._tracer = tracer
+        self._on_done = on_done
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, name="fdtpu-completion-watcher", daemon=True)
+        self._thread.start()
+
+    def watch(self, item: int, value, dispatched: float) -> None:
+        self._queue.put((item, value, dispatched))
+
+    def _run(self) -> None:
+        import jax
+
+        last_done = 0.0
+        while True:
+            job = self._queue.get()
+            if job is None:
+                return
+            item, value, dispatched = job
+            args = {"item": item, "parent": "dispatch"}
+            try:
+                jax.block_until_ready(value)
+            except Exception as e:  # noqa: BLE001 - the loop meets it itself
+                args["error"] = f"{type(e).__name__}: {e}"[:500]
+            done = time.perf_counter()
+            del job, value
+            start = max(dispatched, last_done)
+            self._tracer.record("device", start, done, **args)
+            if self._on_done is not None:
+                self._on_done(done - start)
+            last_done = done
+
+    def close(self, timeout: float = 60.0) -> bool:
+        """Wait, at most ``timeout`` seconds, for the items handed over
+        to complete; whether they all did."""
+        self._queue.put(None)
+        self._thread.join(timeout)
+        return not self._thread.is_alive()

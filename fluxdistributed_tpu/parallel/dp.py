@@ -216,7 +216,10 @@ def make_train_step(
                 return loss_fn(p, mstate, batch, True, rng=rng)
             return loss_fn(p, mstate, batch, True)
 
-        return jax.value_and_grad(lossf, has_aux=True)(params)
+        # metadata only: names forward+backward in a profile (XProf, the
+        # trace reducer) without touching the program or its cache key
+        with jax.named_scope("fdtpu/grad"):
+            return jax.value_and_grad(lossf, has_aux=True)(params)
 
     def step(state: TrainState, batch):
         if accum_steps == 1:
@@ -243,9 +246,10 @@ def make_train_step(
             )
             grads = jax.tree.map(lambda g: g / accum_steps, gsum)
             loss = lsum / accum_steps
-        new_params, new_opt = optimizer.apply(
-            state.params, grads, state.opt_state, state.step
-        )
+        with jax.named_scope("fdtpu/update"):
+            new_params, new_opt = optimizer.apply(
+                state.params, grads, state.opt_state, state.step
+            )
         new_state = TrainState(
             params=new_params,
             opt_state=new_opt,

@@ -42,7 +42,7 @@ from .. import mesh as mesh_lib
 from .. import sharding as sharding_lib
 from .. import tree as tree_lib
 from ..data.loader import PrefetchLoader
-from ..obs import Observation, jaxmon
+from ..obs import CompletionWatcher, Observation, get_tracer, jaxmon
 from ..ops import logitcrossentropy
 from ..optim import Optimizer
 from ..parallel.dp import TrainState, flax_loss_fn, make_eval_step, make_train_step
@@ -1198,22 +1198,22 @@ def evaluate(
 
 class _PhaseClock:
     """Step-phase bracketing: every ``with phases("dispatch"):`` block
-    observes its wall seconds into the registry's per-phase histogram
-    and, when a tracer rides along, opens a span with the same name —
-    ONE set of brackets feeds both the live ``/metrics`` percentiles
-    and the offline Chrome/Perfetto timeline.  When the backend
-    reports HBM truth and a watchdog rides along, every phase exit
-    also samples ``device.memory_stats()`` into the watchdog's
-    OOM-margin gauge/alert (:meth:`~..obs.watchdog.StepWatchdog
-    .note_headroom`) — per-PHASE sampling, because the margin is
-    tightest inside eval/checkpoint phases a per-step sample would
-    straddle."""
+    opens a span of that name in the process tracer and observes its
+    wall seconds into the registry's per-phase histogram — ONE set of
+    brackets feeds the live ``/metrics`` percentiles, the step timeline
+    and, while a profiler session records, its ``/host:CPU`` plane.
+    ``begin_item`` opens the loader item's own span around them, so
+    every phase carries the item's id and names it as its parent.  When
+    the backend reports HBM truth and a watchdog rides along, every
+    phase exit also samples ``device.memory_stats()`` into the
+    watchdog's OOM-margin gauge/alert
+    (:meth:`~..obs.watchdog.StepWatchdog.note_headroom`) — per-PHASE
+    sampling, because the margin is tightest inside eval/checkpoint
+    phases a per-step sample would straddle."""
 
     def __init__(self, observation: Observation, hbm=None):
-        from ..obs.spans import phase_scope
-
-        self.tracer = observation.tracer
-        self._phase_scope = phase_scope
+        self.tracer = get_tracer()
+        self._item = None  # the open item span
         # headroom sampling only when BOTH truths exist: live memory
         # stats (hbm.available — CPU short-circuits to zero cost) and
         # a watchdog to route the alert through
@@ -1236,18 +1236,30 @@ class _PhaseClock:
         out, self.last = self.last, {}
         return out
 
+    def begin_item(self, item: int, opt_step: int) -> None:
+        """Close the item before and open this one: item spans tile the
+        loop's wall time, so what no phase covers is the loop's own."""
+        self.end_item()
+        self._item = self.tracer.span(
+            "item", item=item, opt_step=opt_step,
+            # a profiler session slows the loop it records: readers of
+            # the timeline stop at such an item
+            traced=jax.profiler.TraceAnnotation.is_enabled())
+        self._item.__enter__()
+
+    def end_item(self) -> None:
+        if self._item is not None:
+            # a session that began during the item touched it too
+            self._item.args["traced"] |= (
+                jax.profiler.TraceAnnotation.is_enabled())
+            self._item.__exit__(None, None, None)
+            self._item = None
+
     @contextlib.contextmanager
     def __call__(self, name: str, **args):
-        # a real span registers itself as the active phase; the
-        # metrics-only path uses the lightweight registration alone so
-        # the stall watchdog can still name WHERE the loop wedged
-        span = (
-            self.tracer.span(name, **args) if self.tracer is not None
-            else self._phase_scope(name)
-        )
         t0 = time.perf_counter()
         try:
-            with span:
+            with self.tracer.span(name, **args):
                 yield
         finally:
             # observe on the exception path too (the span does): an
@@ -1290,19 +1302,23 @@ def train(
     Beyond the reference (whose only timing hook is dead code, SURVEY §5):
     steps/sec + images/sec are logged at every ``print_every`` cadence,
     and ``profile_dir`` captures a ``jax.profiler`` device trace of steps
-    ``[profile_start, profile_start + profile_steps)`` for TensorBoard.
+    ``[profile_start, profile_start + profile_steps)`` for TensorBoard,
+    with the python tracer off (it halved a traced ResNet-50 run) and
+    the loop's ``fdtpu/<phase>`` annotations in the host plane.
 
     ``observation`` threads the unified observability layer
     (:mod:`fluxdistributed_tpu.obs`) through the loop.  The default
-    (``None`` → :meth:`Observation.default`) is metrics-only: step
-    counters, per-phase wall-time histograms, compile counts and the
-    OOM-skip counter land in the process registry (scrapeable via
-    ``bin/driver.py --metrics-port``) at sub-microsecond per-step cost.
-    :meth:`Observation.full` additionally buffers nested phase SPANS
-    (exported as Chrome/Perfetto trace JSON via ``trace_path``), runs a
-    stall watchdog against the rolling-median step time, and
-    ``block_until_ready``-syncs each step so device time is honestly
-    attributed to a ``device`` phase.
+    (``None`` → :meth:`Observation.default`) lands step counters,
+    per-phase wall-time histograms, compile counts and the OOM-skip
+    counter in the process registry (scrapeable via ``bin/driver.py
+    --metrics-port``) and every loader item's spans (``item`` with its
+    ``data_wait`` / ``dispatch`` / ``eval`` / ``checkpoint`` children,
+    the workers' ``assemble`` / ``h2d``, and a ``device`` span closed by
+    a watcher thread when the step's metrics are ready, so the loop is
+    never blocked) in the process tracer's bounded ring, at a few
+    microseconds per step.  :meth:`Observation.full` additionally
+    exports the ring as Chrome/Perfetto trace JSON (``trace_path``) and
+    runs a stall watchdog against the rolling-median step time.
 
     ``handle_signals=True`` arms checkpoint-on-preemption
     (:mod:`fluxdistributed_tpu.faults`): SIGTERM/SIGINT set a flag that
@@ -1404,10 +1420,10 @@ def train(
     spc = getattr(task, "steps_per_call", 1)
     if obs.watchdog is not None:
         obs.watchdog.start()
-    if obs.tracer is not None and isinstance(task.loader, PrefetchLoader):
-        # prefetch workers emit their h2d spans onto the same timeline
-        # (their own thread rows in the exported trace)
-        task.loader.tracer = obs.tracer
+    # closes each item's ``device`` span when its step has finished, from
+    # a thread of its own: the loop is never blocked to learn it
+    watcher = CompletionWatcher(
+        phases.tracer, phases.hist.labels(phase="device").observe)
 
     it = iter(task.loader)
     _end = object()
@@ -1545,6 +1561,7 @@ def train(
             # fault plan delivers the signal; the very next check sees
             # it) — and THE step-boundary preemption check: state here
             # is consistent, no donated buffers are in flight
+            phases.begin_item(j, done_steps)
             faults_lib.fire("step", index=j)
             if _preempted():
                 _checkpoint_and_exit()
@@ -1579,7 +1596,14 @@ def train(
                     sink.write(step=j * spc)
             if profile_dir is not None:
                 if j == profile_start:
-                    jax.profiler.start_trace(profile_dir)
+                    # the python tracer halved a traced ResNet-50 run
+                    # (PERF.md); the host tracer keeps the loop's own
+                    # fdtpu/<phase> annotations
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0
+                    opts.host_tracer_level = 1
+                    jax.profiler.start_trace(profile_dir,
+                                             profiler_options=opts)
                     profiling = True
                 elif profiling and j == profile_start + profile_steps:
                     tree_lib.synchronize(task.state.params)
@@ -1616,16 +1640,14 @@ def train(
                     if verbose:
                         logger.info(f"  step {j}: dispatching compiled SPMD step")
                     # dispatch: host-side time to enqueue the compiled step
-                    # (includes any XLA compile on first touch); with
-                    # device_sync the separate device phase then holds the
-                    # device execution time this step actually took
+                    # (any XLA compile on first touch, and any wait for the
+                    # device to take it); the watcher then closes the item's
+                    # device span when these metrics are ready
                     with phases("dispatch"):
                         new_state, metrics = task.step_fn(task.state, batch)
                         if guard_obj is None:
                             task.state = new_state
-                    if obs.device_sync:
-                        with phases("device"):
-                            jax.block_until_ready(metrics)
+                    watcher.watch(j, metrics, time.perf_counter())
                     if guard_obj is not None:
                         # verdict BEFORE commit: an anomalous step's output
                         # is discarded and the pre-step state lives on
@@ -1826,12 +1848,14 @@ def train(
             obs.watchdog.stop()
         if marked_steady:
             jaxmon.clear_steady()
-        if obs.tracer is not None and isinstance(task.loader, PrefetchLoader):
-            task.loader.tracer = None
-        if obs.tracer is not None and obs.trace_path:
+        phases.end_item()
+        if not watcher.close():
+            logger.info("the last steps did not complete within the "
+                        "watcher's time limit: their device spans are missing")
+        if obs.trace_path:
             # export even on an exception: the timeline UP TO a crash
             # is exactly what the postmortem needs
-            n = obs.tracer.export_chrome_trace(obs.trace_path)
+            n = phases.tracer.export_chrome_trace(obs.trace_path)
             logger.info(f"span trace ({n} events) written to {obs.trace_path}")
         if obs.profile_path:
             # the planner-facing artifact: static per-layer/step costs

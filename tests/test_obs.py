@@ -171,20 +171,38 @@ def test_span_nesting_and_chrome_export(tmp_path):
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
     assert outer["args"] == {"idx": 3}
+    # a child names the span that caused it
+    assert inner["args"] == {"parent": "step"}
 
 
-def test_span_disabled_is_noop_and_histogram_feed():
+def test_one_bracket_feeds_span_histogram_and_flight_split():
+    """What replaced ``SpanTracer(histogram=)`` and the tracer-less
+    bracket: the trainer's phase clock opens the span in the process
+    tracer and observes the phase histogram from the SAME bracket, on
+    the exception path too."""
+    from fluxdistributed_tpu.obs import get_tracer
+    from fluxdistributed_tpu.train.trainer import _PhaseClock
+
     r = Registry()
-    h = r.histogram("phase_seconds", "", labelnames=("phase",))
-    off = SpanTracer(enabled=False)
-    with off.span("x"):
-        assert current_span() is None  # no stack push on the noop path
-    assert len(off) == 0
-
-    on = SpanTracer(histogram=h)
-    with on.span("fit"):
-        pass
-    assert h.labels(phase="fit").count == 1
+    phases = _PhaseClock(Observation(registry=r))
+    assert phases.tracer is get_tracer()
+    get_tracer().clear()
+    phases.begin_item(7, opt_step=7)
+    with phases("fit"):
+        assert current_span() == "fit"
+    with pytest.raises(ZeroDivisionError):
+        with phases("fit"):
+            1 / 0
+    phases.end_item()
+    assert current_span() is None
+    h = r.get("fdtpu_train_phase_seconds")
+    assert h.labels(phase="fit").count == 2
+    assert set(phases.take()) == {"fit"} and phases.take() == {}
+    evs = get_tracer().trace_events()
+    assert [e["name"] for e in evs] == ["fit", "fit", "item"]
+    assert all(e["args"]["item"] == 7 for e in evs)
+    assert evs[0]["args"]["parent"] == "item"
+    assert evs[2]["args"]["traced"] is False
 
 
 def test_span_ring_bounds_memory():
@@ -343,7 +361,8 @@ def test_watchdog_stall_names_innermost_active_phase(capsys):
     the innermost active span/phase (registered cross-thread — the
     watchdog polls from its own thread) and last_where keeps it for
     callbacks."""
-    from fluxdistributed_tpu.obs.spans import innermost_active, phase_scope
+    from fluxdistributed_tpu.obs import get_tracer
+    from fluxdistributed_tpu.obs.spans import innermost_active
 
     r = Registry()
     w = StepWatchdog(factor=2.0, min_interval=0.01, warmup=2, registry=r)
@@ -353,7 +372,7 @@ def test_watchdog_stall_names_innermost_active_phase(capsys):
     entered, release = threading.Event(), threading.Event()
 
     def wedged_loop():  # the "hung dispatch" on the loop's own thread
-        with phase_scope("dispatch"):
+        with get_tracer().span("dispatch"):
             entered.set()
             release.wait(5)
 

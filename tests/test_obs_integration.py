@@ -107,7 +107,7 @@ def test_train_metrics_scrapeable_over_http(mesh):
     from fluxdistributed_tpu.obs import start_metrics_server
 
     train(_task(mesh, cycles=2), print_every=0, eval_every=0,
-          logger=NullLogger())  # default Observation: metrics-only
+          logger=NullLogger())  # the default Observation
     srv = start_metrics_server(host="127.0.0.1", port=0)
     try:
         url = f"http://127.0.0.1:{srv.port}/metrics"
