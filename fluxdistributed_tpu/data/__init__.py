@@ -40,11 +40,11 @@ def minibatch(dataset, n: int, rng=None, one_hot: bool = True):
     """
     import numpy as np
 
-    from ..ops import onehot
+    from .loader import host_onehot
 
     if rng is None:
         rng = np.random.default_rng()
     imgs, y = dataset.batch(rng, n)
     if one_hot:
-        y = np.asarray(onehot(y, dataset.nclasses))
+        y = host_onehot(y, dataset.nclasses)
     return imgs, y

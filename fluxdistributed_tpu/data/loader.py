@@ -27,9 +27,18 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import mesh as mesh_lib
-from ..ops import onehot
 
-__all__ = ["PrefetchLoader", "batch_to_dict", "model_input"]
+__all__ = ["PrefetchLoader", "batch_to_dict", "host_onehot", "model_input"]
+
+
+def host_onehot(labels, nclasses: int) -> np.ndarray:
+    """:func:`~fluxdistributed_tpu.ops.onehot` in numpy, for batches made
+    on the host: the same float32 0/1 array for any leading shape, an
+    all-zero row for a label outside ``[0, nclasses)``.  A loader thread
+    that called the jitted one would queue its tiny program on the chip
+    behind the running train step and block on the read-back."""
+    y = np.asarray(labels)
+    return (y[..., None] == np.arange(nclasses)).astype(np.float32)
 
 
 def batch_to_dict(out, nclasses=None, one_hot: bool = True) -> dict:
@@ -47,7 +56,7 @@ def batch_to_dict(out, nclasses=None, one_hot: bool = True) -> dict:
                 raise ValueError(
                     "one_hot labels need nclasses (dataset lacks .nclasses)"
                 )
-            y = np.asarray(onehot(y, nclasses))
+            y = host_onehot(y, nclasses)
         return {"image": np.asarray(imgs), "label": y}
     if isinstance(out, dict):
         return {k: np.asarray(v) for k, v in out.items()}
@@ -157,8 +166,12 @@ class PrefetchLoader:
             raise ValueError(f"start must be >= 0, got {start}")
         self.start = start
         self.retries = max(0, retries)
-        self.sharding = NamedSharding(mesh, P(batch_entry(axis)))
-        self._chunk_sharding = NamedSharding(mesh, P(None, batch_entry(axis)))
+        # the dim of a yielded item that holds the batch's rows, and the
+        # sharding that splits it: a stacked item's rows live on dim 1
+        self._batch_dim = int(chunk > 1)
+        rows = batch_entry(axis)
+        self.sharding = NamedSharding(
+            mesh, P(None, rows) if chunk > 1 else P(rows))
         # observability: queue depth + assemble/h2d timing land in the
         # process registry so /metrics can answer "is the input pipeline
         # keeping up"; the same brackets are spans of the loader item on
@@ -221,10 +234,9 @@ class PrefetchLoader:
         return apply_transform(self.transform, out)
 
     def _make_item(self, c: int):
-        """Host-side assembly of yielded item ``c``: one batch, or a
-        ``chunk``-stacked group of consecutive step batches."""
-        if self.chunk == 1:
-            return self._make_batch(c)
+        """Host-side assembly of yielded item ``c``, complete down to the
+        one-hot: one batch dict, or ``chunk`` consecutive step batches
+        stacked on a new leading dim."""
         nclasses = getattr(self.dataset, "nclasses", None)
         ds = [
             batch_to_dict(
@@ -232,21 +244,19 @@ class PrefetchLoader:
             )
             for j in range(self.chunk)
         ]
+        if self.chunk == 1:
+            return ds[0]
         return {k: np.stack([d[k] for d in ds]) for k in ds[0]}
 
-    def _put(self, out):
+    def _put(self, host: dict) -> dict:
+        """``device_put`` of a finished host item's numpy leaves — the
+        only thing a worker does to the device."""
         from ..parallel.multihost import global_batch_put
 
-        if self.chunk > 1:
-            # out is already a stacked dict; rows live on dim 1
-            return {
-                k: global_batch_put(v, self._chunk_sharding, batch_dim=1)
-                for k, v in out.items()
-            }
-        d = batch_to_dict(
-            out, getattr(self.dataset, "nclasses", None), self.one_hot
-        )
-        return {k: global_batch_put(v, self.sharding) for k, v in d.items()}
+        return {
+            k: global_batch_put(v, self.sharding, batch_dim=self._batch_dim)
+            for k, v in host.items()
+        }
 
     # -- iteration ----------------------------------------------------
     def __len__(self) -> int:

@@ -245,3 +245,133 @@ def test_byte_text_dataset_boundary(tmp_path):
     ds = ByteTextDataset(str(tail), seqlen=8)
     toks = ds.batch(np.random.default_rng(0), 256)
     assert (toks[:, -1] == ord("Z")).any(), "final corpus byte never sampled"
+
+
+# ---------------------------------------------------------------------------
+# The host one-hot, and a loader whose workers touch the device only to
+# device_put finished numpy arrays
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("labels_, nclasses", [
+    (np.array([0, 3, 9, 1], np.int32), 10),
+    (np.array([0, 3, 9, 1], np.int64), 10),
+    (np.arange(24, dtype=np.int32).reshape(2, 3, 4) % 7, 7),   # leading shape kept
+    (np.array([[1, 0], [2, 2]], np.int64), 3),
+    (np.array([2, 5, 4], np.int32), 5),          # a label equal to nclasses
+    (np.array([-1, 0, -7], np.int64), 4),        # negative labels
+    (np.array([0, 1, 0, -1], np.int32), 1),      # one class
+    (np.array([0, 999, 1000, 517, -1], np.int64), 1000),
+    (np.zeros((0,), np.int32), 6),               # an empty batch
+], ids=["int32", "int64", "3d", "2d", "eq_nclasses", "negative", "one_class",
+        "1000_classes", "empty"])
+def test_host_onehot_is_bit_equal_to_the_device_onehot(labels_, nclasses):
+    from fluxdistributed_tpu.data.loader import host_onehot
+    from fluxdistributed_tpu.ops import onehot
+
+    got = host_onehot(labels_, nclasses)
+    want = np.asarray(onehot(labels_, nclasses))
+    assert type(got) is np.ndarray and got.dtype == np.float32
+    assert got.shape == labels_.shape + (nclasses,) == want.shape
+    assert got.tobytes() == want.tobytes()
+    valid = (labels_ >= 0) & (labels_ < nclasses)
+    assert (got.sum(axis=-1) == valid).all()  # an all-zero row outside the range
+
+
+def test_batch_to_dict_and_minibatch_onehot_on_the_host():
+    from fluxdistributed_tpu.data.loader import batch_to_dict, host_onehot
+
+    ds = SyntheticDataset(nsamples=32, nclasses=11, shape=(4, 4, 3))
+    imgs, y = ds.batch(np.random.default_rng(5), 6)
+    d = batch_to_dict((imgs, y), ds.nclasses)
+    assert all(type(v) is np.ndarray for v in d.values())
+    assert d["label"].tobytes() == host_onehot(y, 11).tobytes()
+    assert batch_to_dict((imgs, y), one_hot=False)["label"].tobytes() == y.tobytes()
+    with pytest.raises(ValueError, match="nclasses"):
+        batch_to_dict((imgs, y))
+    mi, my = minibatch(ds, 6, np.random.default_rng(5))
+    assert type(my) is np.ndarray and my.tobytes() == d["label"].tobytes()
+    assert minibatch(ds, 6, np.random.default_rng(5), one_hot=False)[1].tobytes() == y.tobytes()
+
+
+@pytest.fixture(scope="module")
+def mesh8():
+    from fluxdistributed_tpu import mesh as mesh_lib
+
+    return mesh_lib.data_mesh(8)
+
+
+@pytest.mark.parametrize("chunk", [1, 2])
+def test_loader_yields_the_documented_batches(mesh8, chunk):
+    """Item ``c`` is ``device_put`` of ``batch_to_dict(dataset.batch(rng_i,
+    n))`` with ``rng_i = default_rng((seed, process, i))``, bit for bit —
+    stacked over steps ``c*chunk .. c*chunk+chunk-1`` when chunked."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fluxdistributed_tpu.data import PrefetchLoader
+    from fluxdistributed_tpu.data.loader import batch_to_dict
+
+    ds = SyntheticDataset(nsamples=96, nclasses=13, shape=(5, 5, 3), seed=2)
+    n, seed, cycles = 16, 9, 6
+    dl = PrefetchLoader(ds, mesh8, batch_size=n, cycles=cycles, seed=seed,
+                        chunk=chunk, buffersize=2)
+    items = list(dl)
+    assert len(items) == cycles // chunk
+
+    def step(i):
+        rng = np.random.default_rng((seed, jax.process_index(), i))
+        return batch_to_dict(ds.batch(rng, n), ds.nclasses)
+
+    spec = P("data") if chunk == 1 else P(None, "data")
+    for c, item in enumerate(items):
+        steps = [step(c * chunk + j) for j in range(chunk)]
+        assert sorted(item) == ["image", "label"]
+        for k, got in item.items():
+            want = steps[0][k] if chunk == 1 else np.stack([s[k] for s in steps])
+            assert isinstance(got, jax.Array)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.asarray(got).tobytes() == want.tobytes()
+            assert got.sharding.is_equivalent_to(
+                NamedSharding(mesh8, spec), got.ndim)
+
+
+@pytest.mark.parametrize("chunk", [1, 2])
+def test_loader_workers_stay_off_the_device(mesh8, monkeypatch, chunk):
+    """While the workers run nothing compiles (shapes no test has used, so
+    a jitted one-hot would have to), and what ``h2d`` is handed is a
+    finished host batch: numpy leaves, one-hot included."""
+    import threading
+
+    from fluxdistributed_tpu.data import PrefetchLoader
+    from fluxdistributed_tpu.obs import get_tracer, jaxmon
+
+    ds = SyntheticDataset(nsamples=80, nclasses=37 + chunk, shape=(7, 3, 3))
+    seen = []
+    real_put = PrefetchLoader._put
+
+    def put(self, host):
+        seen.append((threading.current_thread() is threading.main_thread(),
+                     {k: (type(v), v.dtype, v.shape) for k, v in host.items()}))
+        return real_put(self, host)
+
+    monkeypatch.setattr(PrefetchLoader, "_put", put)
+    jaxmon.install()
+    dl = PrefetchLoader(ds, mesh8, batch_size=24, cycles=8, chunk=chunk)
+    compiles = jaxmon.compile_count()
+    mark = len(get_tracer().trace_events())
+    items = list(dl)
+    assert jaxmon.compile_count() == compiles
+    lead = () if chunk == 1 else (chunk,)
+    assert len(seen) == len(items) == 8 // chunk
+    for on_main, leaves in seen:
+        assert not on_main
+        assert leaves == {
+            "image": (np.ndarray, np.dtype(np.float32), lead + (24, 7, 3, 3)),
+            "label": (np.ndarray, np.dtype(np.float32), lead + (24, 37 + chunk)),
+        }
+    # the timeline holds one assemble and one h2d span an item
+    new = get_tracer().trace_events()[mark:]
+    for name in ("assemble", "h2d"):
+        assert sorted(e["args"]["item"] for e in new if e["name"] == name) \
+            == list(range(8 // chunk))

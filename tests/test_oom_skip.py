@@ -188,6 +188,7 @@ def test_oom_multihost_raises(monkeypatch):
     # batch assembly single-process (it would otherwise try to stitch a
     # half-batch from each "process").
     monkeypatch.setattr(jax, "process_count", lambda: 2)
-    monkeypatch.setattr(multihost, "global_batch_put", jax.device_put)
+    monkeypatch.setattr(multihost, "global_batch_put",
+                        lambda x, sharding, batch_dim=0: jax.device_put(x, sharding))
     with pytest.raises(RuntimeError, match="multi-host"):
         train(task, print_every=0, eval_every=0, logger=NullLogger())
