@@ -841,6 +841,21 @@ class CausalSelfAttention(nn.Module):
         return nn.DenseGeneral(d, axis=(-2, -1), dtype=self.dtype, name="out")(out)
 
 
+def swiglu_mlp(y, mlp_dim: int, dtype, dropout: float = 0.0,
+               train: bool = True):
+    """Llama-style gated MLP in the scope of the compact module that
+    calls it (``gate``, ``up``, ``down`` become that module's children):
+    gate/up column matmuls fused by XLA, SiLU gating on the VPU,
+    biasless (explicit names keep the TP rules exact: gate/up
+    column-sharded, down row-sharded)."""
+    d = y.shape[-1]
+    gate = nn.Dense(mlp_dim, dtype=dtype, use_bias=False, name="gate")(y)
+    up = nn.Dense(mlp_dim, dtype=dtype, use_bias=False, name="up")(y)
+    y = nn.silu(gate) * up
+    y = nn.Dropout(dropout, deterministic=not train)(y)
+    return nn.Dense(d, dtype=dtype, use_bias=False, name="down")(y)
+
+
 class DecoderBlock(nn.Module):
     num_heads: int
     mlp_dim: int
@@ -881,16 +896,7 @@ class DecoderBlock(nn.Module):
         y = _norm_layer(self.norm, self.dtype, eps=self.norm_eps)(x)
         d = x.shape[-1]
         if self.mlp == "swiglu":
-            # Llama-style gated MLP: gate/up column matmuls fused by XLA,
-            # SiLU gating on the VPU, biasless (explicit names keep the
-            # TP rules exact: gate/up column-sharded, down row-sharded)
-            gate = nn.Dense(self.mlp_dim, dtype=self.dtype, use_bias=False,
-                            name="gate")(y)
-            up = nn.Dense(self.mlp_dim, dtype=self.dtype, use_bias=False,
-                          name="up")(y)
-            y = nn.silu(gate) * up
-            y = nn.Dropout(self.dropout, deterministic=not train)(y)
-            y = nn.Dense(d, dtype=self.dtype, use_bias=False, name="down")(y)
+            y = swiglu_mlp(y, self.mlp_dim, self.dtype, self.dropout, train)
         elif self.mlp == "gelu":
             y = nn.Dense(self.mlp_dim, dtype=self.dtype)(y)
             y = nn.gelu(y, approximate=True)
@@ -903,7 +909,13 @@ class DecoderBlock(nn.Module):
 
 
 class MoEDecoderBlock(nn.Module):
-    """DecoderBlock with the MLP replaced by a Switch/GShard MoE layer.
+    """DecoderBlock with the MLP replaced by a Switch/GShard MoE layer:
+    the capacity path of ``parallel/ep.py`` (softmax top-k, one-hot
+    dispatch, drops over capacity, GELU experts of ``mlp_dim``, a load
+    term in the loss).  It keeps its users (``TransformerLM(moe_every=)``,
+    ``spmd="ep"``).  A new model with many gated experts takes the other
+    path there (``sigmoid_route`` + ``held_experts_apply``: no capacity,
+    no drops, a grouped product), as ``models/glm4_moe_lite.py`` does.
 
     ``moe_fn`` comes from ``parallel.ep.moe_apply(expert_fn, mesh, ...)``
     with the matching ``expert_fn`` being this block's per-expert MLP
@@ -1165,33 +1177,52 @@ def next_token_loss(logits, tokens, mask=None):
     return nll.mean()
 
 
-def lm_loss_fn(model: TransformerLM) -> Callable:
-    """Adapt the LM to the framework loss signature
+def lm_loss_fn(model) -> Callable:
+    """Adapt an LM to the framework loss signature
     (``fn(params, model_state, batch, train, rng=None)``) so every
     compiled step maker — DP/FSDP/TP — accepts it unchanged.  The batch
-    is ``{"tokens": [B, T]}`` with optional ``{"mask": [B, T]}``."""
+    is ``{"tokens": [B, T]}`` with optional ``{"mask": [B, T]}``.
 
-    moe = getattr(model, "moe_every", 0) > 0
+    The loss is the next-token cross-entropy plus, in training, what the
+    model sows into ``"losses"``: the routers' load-balance terms
+    (``moe_aux``; their mean times ``moe_aux_weight``) and the
+    multi-token-prediction terms (``mtp_loss*``; their mean times
+    ``mtp_weight``).  A model whose routers balance by a selection bias
+    sows no load term.  The model's mutable collections (a router's bias
+    and load) are updated by a training step and handed on, as
+    ``flax_loss_fn`` hands on batch-norm statistics."""
 
     def fn(params, model_state, batch, train: bool, rng=None):
         rngs = {"dropout": rng} if (train and rng is not None) else None
-        if moe:
-            # "losses" holds the sown per-block MoE load-balance terms
-            logits, sown = model.apply(
-                {"params": params}, batch["tokens"], train=train, rngs=rngs,
-                mutable=["losses"],
-            )
-            aux_terms = jax.tree.leaves(sown.get("losses", {}))
-        else:
-            logits = model.apply(
-                {"params": params}, batch["tokens"], train=train, rngs=rngs
-            )
-            aux_terms = []
+        # "losses" is sown anew by every apply; an init leaves a stale
+        # copy of it among the model's collections, which is not state
+        carried = [k for k in model_state if k != "losses"]
+        logits, mutated = model.apply(
+            {"params": params, **{k: model_state[k] for k in carried}},
+            batch["tokens"], train=train, rngs=rngs,
+            mutable=["losses", *(carried if train else ())],
+        )
+        new_state = ({**model_state, **{k: mutated[k] for k in carried}}
+                     if train else model_state)
         loss = next_token_loss(logits, batch["tokens"], batch.get("mask"))
-        if aux_terms and train:
-            loss = loss + model.moe_aux_weight * sum(aux_terms) / len(aux_terms)
-        return loss, (model_state, logits)
+        if train:
+            aux, mtp = [], []
+            for path, term in jax.tree_util.tree_flatten_with_path(
+                    mutated.get("losses", {}))[0]:
+                name = str(getattr(path[-2], "key", path[-2]))
+                (mtp if name.startswith("mtp_loss") else aux).append(term)
+            if mtp and batch.get("mask") is not None:
+                raise NotImplementedError(
+                    "the multi-token-prediction term takes no mask yet")
+            if aux:
+                loss = loss + model.moe_aux_weight * sum(aux) / len(aux)
+            if mtp:
+                loss = loss + model.mtp_weight * sum(mtp) / len(mtp)
+        return loss, (new_state, logits)
 
+    if hasattr(model, "step_metrics"):
+        # what the step maker reports of the state a step leaves
+        fn.step_metrics = model.step_metrics
     return fn
 
 

@@ -264,12 +264,16 @@ class CompletionWatcher:
     before's completion to this item's completion: the time in which the
     device had been handed the item and not yet finished it.  An error that surfaces at completion
     is recorded on the span and never raised into the loop.
+    ``on_value`` gets each completed value in the same thread (the
+    trainer feeds a router's counters from the step's metrics there).
     """
 
     def __init__(self, tracer: SpanTracer,
-                 on_done: Optional[Callable[[float], None]] = None):
+                 on_done: Optional[Callable[[float], None]] = None,
+                 on_value: Optional[Callable[[object], None]] = None):
         self._tracer = tracer
         self._on_done = on_done
+        self._on_value = on_value
         self._queue: queue.SimpleQueue = queue.SimpleQueue()
         self._thread = threading.Thread(
             target=self._run, name="fdtpu-completion-watcher", daemon=True)
@@ -293,6 +297,12 @@ class CompletionWatcher:
             except Exception as e:  # noqa: BLE001 - the loop meets it itself
                 args["error"] = f"{type(e).__name__}: {e}"[:500]
             done = time.perf_counter()
+            if self._on_value is not None and "error" not in args:
+                # the value is ready: reading it here waits for nothing
+                try:
+                    self._on_value(value)
+                except Exception as e:  # noqa: BLE001 - never into the loop
+                    args["error"] = f"{type(e).__name__}: {e}"[:500]
             del job, value
             start = max(dispatched, last_done)
             self._tracer.record("device", start, done, **args)

@@ -55,6 +55,11 @@ __all__ = [
 # m/l scratch rows are replicated across the VPU lane width.
 _LANES = 128
 
+#: the three kernels' names in a compiled program and so in a device
+#: trace's ``XLA Ops`` (forward, dQ, dK/dV): fixed here, so that a trace
+#: reducer finds them wherever the call sits (under jvp, remat, a scan)
+KERNEL_NAMES = ("fdtpu_flash_fwd", "fdtpu_flash_dq", "fdtpu_flash_dkv")
+
 
 def interpret_mode() -> bool:
     """Whether Pallas kernels in this process run under the interpreter.
@@ -411,6 +416,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAMES[0],
     )(qf, kf, vf)
     return _unfold(out, b, h, tq), lse[:, 0, :tq]
 
@@ -488,6 +494,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_NAMES[1],
     )(qf, kf, vf, dof, lse_p, delta)
 
     dk, dv = pl.pallas_call(
@@ -508,6 +515,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAMES[2],
     )(qf, kf, vf, dof, lse_p, delta)
 
     return (

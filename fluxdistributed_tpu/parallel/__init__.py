@@ -24,9 +24,11 @@ from .zero1 import (
     zero1_state_shardings,
 )
 from .ep import (
+    held_experts_apply,
     moe_apply,
     router_dispatch,
     router_dispatch_expert_choice,
+    sigmoid_route,
     stack_expert_params,
 )
 from .pp import make_train_step_pp, pipeline_apply, stack_stage_params, switch_stage
@@ -93,6 +95,8 @@ __all__ = [
     "plan_from_model",
     "pp_plan",
     "moe_apply",
+    "sigmoid_route",
+    "held_experts_apply",
     "router_dispatch_expert_choice",
     "router_dispatch",
     "stack_expert_params",

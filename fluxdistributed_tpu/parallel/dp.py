@@ -188,6 +188,12 @@ def make_train_step(
     one dispatch instead of K, which matters when host dispatch latency
     is large or the host is slow relative to the step.
 
+    A ``loss_fn`` with a ``step_metrics`` attribute (a function of the
+    step's new model state to a dict of small arrays) gets those arrays
+    into ``metrics`` beside the loss: ``lm_loss_fn`` reports a router's
+    load that way, and ``train`` feeds its counters from it when the
+    step completes.
+
     ``guard=True`` adds ``metrics["guard"]`` — the
     :func:`guard_sentinel` ``[poisoned_loss, grad_norm]`` vector (per
     step; stacked ``[K, 2]`` under the device loop), computed in-graph
@@ -205,6 +211,10 @@ def make_train_step(
                           else P())
     state_sh = repl if state_shardings is None else state_shardings
     with_rng = _accepts_rng(loss_fn)
+    # what a loss function wants reported of the state its step leaves
+    # (``lm_loss_fn``: a router's load, for the trainer's counters); small
+    # arrays, computed in the step and never waited for by the loop
+    step_metrics = getattr(loss_fn, "step_metrics", None)
 
     def grad_of(params, mstate, batch, step_idx):
         def lossf(p):
@@ -257,6 +267,8 @@ def make_train_step(
             step=state.step + 1,
         )
         metrics = {"loss": loss}
+        if step_metrics is not None:
+            metrics.update(step_metrics(new_mstate))
         if guard:
             metrics["guard"] = guard_sentinel(loss, grads)
         return new_state, metrics

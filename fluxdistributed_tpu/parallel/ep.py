@@ -1,26 +1,41 @@
-"""Expert parallelism (MoE): top-1 (Switch) or top-k (GShard-style)
-routing with capacity, experts sharded over an ``expert`` mesh axis
-(``E // axis_size`` experts hosted per device, batched with ``vmap``).
+"""Expert layers (MoE).  Two paths live here; they share nothing but
+the name, and a new model takes the second.
 
-Net-new scope beyond the reference (SURVEY §2: "EP: NO"), built the
-TPU-classic way (Mesh-TF/Switch lineage): tokens are sharded over the
-same ``expert`` axis, routing/dispatch build ``(tokens, experts,
+**1. ``moe_apply``: capacity and one-hots, experts sharded over an
+``expert`` mesh axis** (the Mesh-TF/Switch lineage; net-new scope beyond
+the reference, SURVEY §2: "EP: NO").  Top-1 (Switch) or top-k
+(GShard-style) softmax routing; tokens are sharded over the same
+``expert`` axis, ``router_dispatch`` builds ``(tokens, experts,
 capacity)`` one-hots locally, and two ``all_to_all`` collectives move
-token activations to their expert's device and back — dense einsums and
-static shapes throughout, so XLA keeps everything on the MXU (no
-gather/scatter in the hot path).
+token activations to their expert's device and back: dense einsums and
+static shapes throughout.  ``TransformerLM``'s ``MoEDecoderBlock`` and
+``spmd="ep"`` use it.  It fits few experts and short batches: the one-hot
+grows with tokens x experts x capacity, and what overflows is dropped.
 
-Semantics:
 * ``top_k=1`` (Switch): one expert per token, output scaled by the
   router probability; ``top_k>1`` (GShard lineage): k experts per
   token, later choices queue after earlier ones in each expert's
   capacity, gates normalized to sum to 1;
 * per-shard expert capacity ``C = ceil(tokens_per_shard / E *
   capacity_factor * top_k)``; tokens over capacity are DROPPED (output
-  zero for that choice) — the documented switch behavior;
+  zero for that choice), the documented switch behavior;
 * auxiliary load-balance loss ``E * Σ_e f_e · p_e`` (first-choice
   fraction routed × mean router prob), returned for the caller to add
   to the task loss.
+
+**2. ``sigmoid_route`` and ``held_experts_apply``: no capacity, no
+drops, a grouped matrix product** (``models/glm4_moe_lite.py`` uses it;
+the path for a model with many experts).  The router scores every token
+over all experts in float32 and balances by a selection bias, not by a
+loss term.  The layer is told which experts it holds (``first``, and the
+leading size of its weights): the token-slots are sorted by expert, the
+held experts' slots come first, one ``jax.lax.ragged_dot`` a projection
+runs over the sorted rows (on the TPU XLA lowers it to a grouped-matmul
+kernel that visits the live row tiles only), and the weighted results
+are gathered back to their tokens.  The buffer has a row for every
+token-slot, so nothing can overflow; what the absent experts would add
+is left out.  On one chip the layer runs without an exchange; the
+exchange between chips that hold different experts is not built yet.
 """
 
 from __future__ import annotations
@@ -40,6 +55,8 @@ __all__ = [
     "router_dispatch",
     "router_dispatch_expert_choice",
     "stack_expert_params",
+    "sigmoid_route",
+    "held_experts_apply",
 ]
 
 # sourced from the device layer's single declaration (lint rule FDT105:
@@ -256,3 +273,111 @@ def moe_apply(
         return (y[:t] if pad else y), aux
 
     return fn
+
+
+# -- path 2: routing without drops, a grouped product over sorted rows ------
+
+def sigmoid_route(x, router_w, bias, *, top_k: int, scale: float = 1.0,
+                  normalize: bool = True):
+    """Score ``x`` (N, D) over all ``E`` experts and choose ``top_k``.
+
+    ``scores = sigmoid(x @ router_w)`` in float32 at full precision (on
+    a TPU a float32 product otherwise runs in bfloat16 passes, and a
+    score's rounding moves the choice).  The chosen experts are the
+    largest of ``scores + bias``; ``bias`` (E,) only steers the choice
+    and carries no gradient.  The weights are the chosen experts'
+    ``scores`` (without the bias), over their sum if ``normalize``,
+    times ``scale``.  Returns ``(chosen (N, k) int32, weights (N, k)
+    f32, load (E,) f32)``, ``load`` the count of token-slots an expert.
+    """
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    e = router_w.shape[-1]
+    # a compare-and-count over (slots, E): fused, never stored, and no
+    # scatter (which a TPU serialises)
+    load = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(e, dtype=chosen.dtype),
+                   axis=0, dtype=jnp.int32)
+    return chosen, w * scale, load.astype(jnp.float32)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_sorted(x, order, inverse, k):
+    """Row ``r`` of the result is token ``order[r] // k``'s row of ``x``
+    (N, D) -> (N*k, D).  ``order`` is a permutation of the N*k slots,
+    so the transpose is a gather too: slot ``s``'s cotangent sits at
+    row ``inverse[s]``, and a token's is the sum over its k slots."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _to_sorted_fwd(x, order, inverse, k):
+    return _to_sorted(x, order, inverse, k), inverse
+
+
+def _to_sorted_bwd(k, inverse, g):
+    per_slot = jnp.take(g, inverse, axis=0)
+    return (per_slot.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None)
+
+
+_to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _from_sorted(y, order, inverse, k):
+    """(N*k, D) sorted rows back to (N, k, D) by token and choice."""
+    return jnp.take(y, inverse, axis=0).reshape(-1, k, y.shape[-1])
+
+
+def _from_sorted_fwd(y, order, inverse, k):
+    return _from_sorted(y, order, inverse, k), order
+
+
+def _from_sorted_bwd(k, order, g):
+    return (jnp.take(g.reshape(-1, g.shape[-1]), order, axis=0), None, None)
+
+
+_from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
+
+
+def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, *,
+                       first: int = 0):
+    """What the experts held here add to each token: ``sum_i g_i E_i(x)``
+    over the chosen experts ``i`` in ``[first, first + held)``, ``E(x) =
+    (silu(x W_gate) * x W_up) W_down``.
+
+    ``x`` (N, D); ``chosen``, ``weights`` (N, k) from
+    :func:`sigmoid_route`; ``w_gate``, ``w_up`` (held, D, M) and
+    ``w_down`` (held, M, D).  The N*k token-slots are sorted by expert,
+    the held experts' first and the absent experts' behind them; the
+    three grouped products run over the sorted rows with the held
+    experts' group sizes, so rows of absent experts cost a gather and
+    no product; their rows are nought going in and coming out.  Nothing
+    is dropped: there is a row for every slot.
+    """
+    n, k, held = x.shape[0], chosen.shape[-1], w_gate.shape[0]
+    local = chosen.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(live, _to_sorted(x, order, inverse, k), 0)
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(
+            a, w.astype(a.dtype), sizes,
+            preferred_element_type=a.dtype)
+
+    # a row behind the last group is whatever the kernel left there
+    h = jnp.where(live, jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up), 0)
+    y = jnp.where(live, grouped(h, w_down), 0)
+    per_choice = _from_sorted(y, order, inverse, k)
+    return jnp.einsum("nkd,nk->nd", per_choice.astype(jnp.float32),
+                      weights.astype(jnp.float32)).astype(x.dtype)

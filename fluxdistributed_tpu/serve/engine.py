@@ -158,6 +158,10 @@ class LMEngine:
         attention_impl: str = "xla",
         kv_dtype: str | None = None,
     ):
+        if getattr(model, "no_decode", None):
+            # a model without a decode path says what it lacks: fail with
+            # that, before any clone or cast
+            raise NotImplementedError(model.no_decode)
         if model.moe_every:
             raise ValueError(
                 "the serving engine supports dense models only (MoE decode "

@@ -1196,6 +1196,49 @@ def evaluate(
     return out
 
 
+class _RouterCounters:
+    """Feeds the registry from what a step with routers reports of
+    itself (``metrics["moe_load"]`` and its siblings, which
+    ``lm_loss_fn`` hands the step maker for a model that has a
+    ``step_metrics``): called by the completion watcher with metrics
+    that are ready, so the loop waits for nothing.  A step without a
+    router registers nothing."""
+
+    def __init__(self, registry):
+        self._reg = registry
+        self._made = None
+
+    def __call__(self, metrics) -> None:
+        if not isinstance(metrics, dict) or "moe_load" not in metrics:
+            return
+        if self._made is None:
+            reg = self._reg
+            self._made = (
+                reg.counter("fdtpu_moe_slots_total",
+                            "token-slots routed to the experts held here "
+                            "and to the absent ones", ("where",)),
+                reg.counter("fdtpu_moe_dropped_total",
+                            "token-slots of held experts that found no row"),
+                reg.histogram(
+                    "fdtpu_moe_load_max_over_mean",
+                    "a step's largest expert load over its mean load",
+                    ("layer",), buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0,
+                                         16.0, 64.0)))
+        slots, dropped, balance = self._made
+        load = np.asarray(metrics["moe_load"], np.float64)
+        load = load.reshape((-1,) + load.shape[-2:])  # steps_per_call > 1
+        held, absent = np.asarray(
+            metrics["moe_slots"], np.float64).reshape(-1, 2).sum(axis=0)
+        slots.labels(where="held").inc(float(held))
+        slots.labels(where="absent").inc(float(absent))
+        dropped.inc(float(np.sum(np.asarray(metrics["moe_dropped"]))))
+        for step in load:
+            for layer, row in enumerate(step):
+                mean = row.mean()
+                if mean > 0:
+                    balance.labels(layer=layer).observe(float(row.max() / mean))
+
+
 class _PhaseClock:
     """Step-phase bracketing: every ``with phases("dispatch"):`` block
     opens a span of that name in the process tracer and observes its
@@ -1423,7 +1466,8 @@ def train(
     # closes each item's ``device`` span when its step has finished, from
     # a thread of its own: the loop is never blocked to learn it
     watcher = CompletionWatcher(
-        phases.tracer, phases.hist.labels(phase="device").observe)
+        phases.tracer, phases.hist.labels(phase="device").observe,
+        on_value=_RouterCounters(reg))
 
     it = iter(task.loader)
     _end = object()

@@ -125,6 +125,38 @@ def test_flash_lse_backward(chip, as_on_tpu):
         argnums=(0, 1, 2)), q, q, q)
 
 
+def test_flash_backward_latent_attention_widths(chip, as_on_tpu):
+    """The `glm47_flash` cell's attention: 20 heads of 256 over 4,096
+    positions in 1,024 x 1,024 blocks, under the names the trace reducer
+    reads (`chipbench/metrics/attn_roofline_pct.py`)."""
+    q = chip((1, 4096, 20, 256), BF)
+    text = _compile(_grads(lambda q, k, v: pa.flash_attention(
+        q, k, v, True, 1024, 1024)), q, q, q)
+    for name in pa.KERNEL_NAMES:
+        assert f"%{name}" in text, name
+
+
+def test_held_experts_grouped_products(chip):
+    """The same cell's expert layer: 16,384 tokens, 4 of 64 experts a
+    token, 8 held; XLA lowers `ragged_dot` to its grouped-matmul kernel,
+    forward and both gradients, under the name the reducer reads."""
+    from fluxdistributed_tpu.parallel import ep
+
+    x = chip((16384, 2048), BF)
+    router = chip((2048, 64), jnp.float32)
+    w_in, w_out = chip((8, 2048, 1536), jnp.float32), chip((8, 1536, 2048), jnp.float32)
+
+    def layer(x, router, w_gate, w_up, w_down):
+        chosen, weights, _ = ep.sigmoid_route(
+            x, router, jnp.zeros((64,)), top_k=4, scale=1.8)
+        return ep.held_experts_apply(x, chosen, weights, w_gate, w_up, w_down)
+
+    text = _compile(jax.grad(
+        lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)),
+        x, router, w_in, w_in, w_out)
+    assert text.count("%ragged-dot-none") >= 9
+
+
 @pytest.mark.parametrize("hkv", [H, HKV], ids=["dense", "gqa"])
 def test_decode(chip, hkv):
     q, idx = chip((B_DEC, 1, H, D), BF), chip((B_DEC,), jnp.int32)
