@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.attention import dot_product_attention
-from ..parallel.ep import held_experts_apply, sigmoid_route
+from ..parallel.ep import compact_rows, held_experts_apply, sigmoid_route
 from .common import maybe_remat
 from .transformer_lm import rope, swiglu_mlp
 
@@ -216,7 +216,7 @@ class ExpertMLP(nn.Module):
                 jnp.mean(count) - count)
         with jax.named_scope("fdtpu/moe_experts"):
             y = held_experts_apply(toks.astype(self.dtype), chosen, weights,
-                                   w_gate, w_up, w_down, first=first)
+                                   w_gate, w_up, w_down, e, first=first)
         return y.reshape(x.shape)
 
 
@@ -287,12 +287,16 @@ class Glm4MoeLite(nn.Module):
 
     def step_metrics(self, model_state) -> dict:
         """Of the state a training step leaves: each router's load
-        ``moe_load`` [routers, experts], and over all routers the
+        ``moe_load`` [routers, experts]; over all routers the
         token-slots of the experts held here and of the absent ones
         (``moe_slots``) and those that found no row (``moe_dropped``:
-        ``held_experts_apply`` has a row for every slot, as many as the
-        loads sum to, so nought until a buffer is bounded).  Nothing for
-        a model without a router."""
+        nought, since a step that overflows ``held_experts_apply``'s
+        bounded buffer takes the one with a row for every slot); and
+        how many routers' layers took the bounded buffer and how many
+        the whole one (``moe_compact``: the layer's own predicate over
+        the same load; a layer whose bound is all its slots has no
+        branch and counts as whole).  Nothing for a model without a
+        router."""
         routers = model_state.get(ROUTER_COLLECTION)
         if not routers:
             return {}
@@ -300,11 +304,15 @@ class Glm4MoeLite(nn.Module):
                           jax.tree_util.tree_flatten_with_path(routers)[0]
                           if path[-1].key == "load"])
         first, held = self.cfg.experts_held or (0, self.cfg.n_routed_experts)
-        here = jnp.sum(load[:, first:first + held])
-        rows = jnp.sum(load)
+        here = jnp.sum(load[:, first:first + held], axis=-1)
+        slots = jnp.sum(load, axis=-1)
+        rows = compact_rows(slots.astype(jnp.int32), held,
+                            self.cfg.n_routed_experts)
+        compact = jnp.sum((rows < slots) & (here <= rows), dtype=jnp.float32)
         return {"moe_load": load,
-                "moe_slots": jnp.stack([here, rows - here]),
-                "moe_dropped": jnp.maximum(here - rows, 0.0)}
+                "moe_slots": jnp.stack([jnp.sum(here), jnp.sum(slots - here)]),
+                "moe_dropped": jnp.zeros((), jnp.float32),
+                "moe_compact": jnp.stack([compact, len(load) - compact])}
 
     def __post_init__(self):
         if self.decode:

@@ -32,10 +32,17 @@ leading size of its weights): the token-slots are sorted by expert, the
 held experts' slots come first, one ``jax.lax.ragged_dot`` a projection
 runs over the sorted rows (on the TPU XLA lowers it to a grouped-matmul
 kernel that visits the live row tiles only), and the weighted results
-are gathered back to their tokens.  The buffer has a row for every
-token-slot, so nothing can overflow; what the absent experts would add
-is left out.  On one chip the layer runs without an exchange; the
-exchange between chips that hold different experts is not built yet.
+are gathered back to their tokens.  The buffer is as long as the rows
+this chip can expect to hold (``compact_rows``: twice the held experts'
+even share of the token-slots), as a chip's buffer is behind an
+exchange; a step whose held slots overflow it takes a buffer with a row
+for every token-slot instead (a ``lax.cond`` on the step's own load),
+so nothing is dropped, and where the bound is every slot (most experts
+held) that is the only path.  What the absent experts would add is left
+out.  The trainer counts the path a layer took in
+``fdtpu_moe_compact_total{path}``.  On one chip the layer runs without
+an exchange; the exchange between chips that hold different experts is
+not built yet.
 """
 
 from __future__ import annotations
@@ -51,6 +58,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 Pytree = Any
 
 __all__ = [
+    "compact_rows",
     "moe_apply",
     "router_dispatch",
     "router_dispatch_expert_choice",
@@ -306,69 +314,94 @@ def sigmoid_route(x, router_w, bias, *, top_k: int, scale: float = 1.0,
     return chosen, w * scale, load.astype(jnp.float32)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_sorted(x, order, inverse, k):
-    """Row ``r`` of the result is token ``order[r] // k``'s row of ``x``
-    (N, D) -> (N*k, D).  ``order`` is a permutation of the N*k slots,
-    so the transpose is a gather too: slot ``s``'s cotangent sits at
-    row ``inverse[s]``, and a token's is the sum over its k slots."""
-    return jnp.take(x, order // k, axis=0)
+#: the compact buffer's rows over the held slots expected of an even
+#: router.  Fixed by chip runs (PERF.md §6, PR 28): training one chip's
+#: share sends its experts from the even share to 1.5-2 times it within
+#: 60 steps (single steps 2.5).  At 1.5 a sixth of the layers overflowed;
+#: 3 would hold them all and every step pay half as many rows again; at 2
+#: some 95% fit, and a step over it takes the whole buffer.
+COMPACT_OVER_EXPECTED = 2
+_COMPACT_TILE = 512  # the bound is a whole number of row tiles
 
 
-def _to_sorted_fwd(x, order, inverse, k):
-    return _to_sorted(x, order, inverse, k), inverse
+def compact_rows(slots, held: int, experts: int):
+    """Rows of the sorted buffer for ``slots`` token-slots routed over
+    ``experts`` of which ``held`` live here: ``COMPACT_OVER_EXPECTED``
+    times the expected share, up to the next tile, and never more than
+    the slots.  Of shapes alone, so the trace decides; ``slots`` may
+    also be an array of whole numbers (a step's loads, for the counter
+    of the path taken)."""
+    want = COMPACT_OVER_EXPECTED * slots * held
+    bound = -(-want // (experts * _COMPACT_TILE)) * _COMPACT_TILE
+    return min(slots, bound) if isinstance(slots, int) else jnp.minimum(slots, bound)
 
 
-def _to_sorted_bwd(k, inverse, g):
-    per_slot = jnp.take(g, inverse, axis=0)
-    return (per_slot.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None)
+def _sum_rows(rows, at, scale=None):
+    """``out[n] = sum_j scale[n, j] * rows[at[n, j]]`` in float32, the
+    choices ``j`` in order; an ``at`` behind the last row adds nought.
+    One gather of N rows a choice: no (N*k, D) array is made."""
+    total = 0.0
+    for j in range(at.shape[-1]):
+        row = jnp.take(rows, at[:, j], axis=0, mode="fill",
+                       fill_value=0).astype(jnp.float32)
+        total = total + (row if scale is None else row * scale[:, j:j + 1])
+    return total
+
+
+@jax.custom_vjp
+def _to_sorted(x, order, inverse):
+    """Row ``r`` of the result is the row of ``x`` (N, D) of the token
+    that slot ``order[r]`` belongs to, for the R rows of the buffer
+    (``order`` holds the first R slots of the sorted order, ``inverse``
+    (N, k) every slot's row).  The transpose is a gather too: a token's
+    cotangent is the sum over its slots' rows, nought for a slot whose
+    row is behind the buffer."""
+    return jnp.take(x, order // inverse.shape[-1], axis=0)
+
+
+def _to_sorted_fwd(x, order, inverse):
+    return _to_sorted(x, order, inverse), inverse
+
+
+def _to_sorted_bwd(inverse, g):
+    return (_sum_rows(g, inverse).astype(g.dtype), None, None)
 
 
 _to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _from_sorted(y, order, inverse, k):
-    """(N*k, D) sorted rows back to (N, k, D) by token and choice."""
-    return jnp.take(y, inverse, axis=0).reshape(-1, k, y.shape[-1])
+@jax.custom_vjp
+def _from_sorted(y, weights, order, inverse):
+    """Each token's weighted sum over its slots' rows of ``y`` (R, D):
+    ``sum_j weights[n, j] * y[inverse[n, j]]`` in float32, a slot behind
+    the buffer adding nought.  The transpose stays among the R rows."""
+    return _sum_rows(y, inverse, weights.astype(jnp.float32)).astype(y.dtype)
 
 
-def _from_sorted_fwd(y, order, inverse, k):
-    return _from_sorted(y, order, inverse, k), order
+def _from_sorted_fwd(y, weights, order, inverse):
+    return _from_sorted(y, weights, order, inverse), (y, weights, order, inverse)
 
 
-def _from_sorted_bwd(k, order, g):
-    return (jnp.take(g.reshape(-1, g.shape[-1]), order, axis=0), None, None)
+def _from_sorted_bwd(res, g):
+    y, weights, order, inverse = res
+    g_rows = jnp.take(g, order // inverse.shape[-1], axis=0).astype(jnp.float32)
+    w_rows = jnp.take(weights.reshape(-1), order).astype(jnp.float32)
+    d_rows = jnp.sum(y.astype(jnp.float32) * g_rows, axis=-1)
+    d_weights = jnp.take(d_rows, inverse, mode="fill", fill_value=0)
+    return ((g_rows * w_rows[:, None]).astype(y.dtype),
+            d_weights.astype(weights.dtype), None, None)
 
 
 _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
 
 
-def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, *,
-                       first: int = 0):
-    """What the experts held here add to each token: ``sum_i g_i E_i(x)``
-    over the chosen experts ``i`` in ``[first, first + held)``, ``E(x) =
-    (silu(x W_gate) * x W_up) W_down``.
-
-    ``x`` (N, D); ``chosen``, ``weights`` (N, k) from
-    :func:`sigmoid_route`; ``w_gate``, ``w_up`` (held, D, M) and
-    ``w_down`` (held, M, D).  The N*k token-slots are sorted by expert,
-    the held experts' first and the absent experts' behind them; the
-    three grouped products run over the sorted rows with the held
-    experts' group sizes, so rows of absent experts cost a gather and
-    no product; their rows are nought going in and coming out.  Nothing
-    is dropped: there is a row for every slot.
-    """
-    n, k, held = x.shape[0], chosen.shape[-1], w_gate.shape[0]
-    local = chosen.reshape(-1) - first
-    here = (local >= 0) & (local < held)
-    key = jnp.where(here, local, held).astype(jnp.int32)
-    order = jnp.argsort(key, stable=True)
-    inverse = jnp.argsort(order)
-    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
-                    axis=0, dtype=jnp.int32)
-    live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
-    xs = jnp.where(live, _to_sorted(x, order, inverse, k), 0)
+def _sorted_experts(rows, x, weights, w_gate, w_up, w_down, order, inverse,
+                    sizes):
+    """The layer over the first ``rows`` rows of the sorted order, which
+    must hold every held slot (``sum(sizes) <= rows``)."""
+    order = order[:rows]
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(live, _to_sorted(x, order, inverse), 0)
 
     def grouped(a, w):
         return jax.lax.ragged_dot(
@@ -378,6 +411,71 @@ def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, *,
     # a row behind the last group is whatever the kernel left there
     h = jnp.where(live, jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up), 0)
     y = jnp.where(live, grouped(h, w_down), 0)
-    per_choice = _from_sorted(y, order, inverse, k)
-    return jnp.einsum("nkd,nk->nd", per_choice.astype(jnp.float32),
-                      weights.astype(jnp.float32)).astype(x.dtype)
+    return _from_sorted(y, weights, order, inverse)
+
+
+def _fits(rows, slots, sizes, path):
+    """``path(rows)`` in a step whose held slots fit ``rows``, else
+    ``path(slots)``, a row for every slot: nothing is dropped."""
+    return jax.lax.cond(jnp.sum(sizes) <= rows, lambda: path(rows),
+                        lambda: path(slots))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_experts(rows, x, weights, w_gate, w_up, w_down, order, inverse,
+                     sizes):
+    """:func:`_sorted_experts` over ``rows`` rows where the step fits,
+    over all the slots where not.  A ``cond``'s own transpose keeps the
+    union of both branches' residuals and writes noughts for the branch
+    not taken, the whole buffer's in every step; so what crosses from
+    forward to backward is the arguments alone, the same in both
+    branches, and the backward chooses again and recomputes its branch's
+    products (under ``jax.checkpoint`` the forward's then fall away)."""
+    args = (x, weights, w_gate, w_up, w_down)
+    return _fits(rows, len(order), sizes, lambda r: _sorted_experts(
+        r, *args, order, inverse, sizes))
+
+
+def _bounded_fwd(rows, *args):
+    return _bounded_experts(rows, *args), args
+
+
+def _bounded_bwd(rows, args, g):
+    *diff, order, inverse, sizes = args
+    grads = _fits(rows, len(order), sizes, lambda r: jax.vjp(
+        lambda *a: _sorted_experts(r, *a, order, inverse, sizes), *diff)[1](g))
+    return (*grads, None, None, None)
+
+
+_bounded_experts.defvjp(_bounded_fwd, _bounded_bwd)
+
+
+def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, experts, *,
+                       first: int = 0):
+    """What the experts held here add to each token: ``sum_i g_i E_i(x)``
+    over the chosen experts ``i`` in ``[first, first + held)``, ``E(x) =
+    (silu(x W_gate) * x W_up) W_down``.
+
+    ``x`` (N, D); ``chosen``, ``weights`` (N, k) from
+    :func:`sigmoid_route` over ``experts`` experts; ``w_gate``, ``w_up``
+    (held, D, M) and ``w_down`` (held, M, D).  The N*k token-slots are
+    sorted by expert, the held experts' first and the absent experts'
+    behind them; the three grouped products run over the first
+    :func:`compact_rows` rows of that order with the held experts' group
+    sizes, and a slot behind them adds nought to its token without a row
+    being touched for it.  Nothing is dropped: a step whose held slots
+    overflow the bound takes a buffer with a row for every slot, and
+    where the bound is all the slots (most experts held) that buffer is
+    the only path and there is no branch.
+    """
+    n, k, held = x.shape[0], chosen.shape[-1], w_gate.shape[0]
+    local = chosen.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    inverse = jnp.argsort(order).reshape(n, k)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    rows = compact_rows(n * k, held, experts)
+    path = _sorted_experts if rows == n * k else _bounded_experts
+    return path(rows, x, weights, w_gate, w_up, w_down, order, inverse, sizes)

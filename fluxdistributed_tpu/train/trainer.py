@@ -1219,12 +1219,16 @@ class _RouterCounters:
                             "and to the absent ones", ("where",)),
                 reg.counter("fdtpu_moe_dropped_total",
                             "token-slots of held experts that found no row"),
+                reg.counter("fdtpu_moe_compact_total",
+                            "expert layers of a step that ran over the "
+                            "bounded buffer and over the whole one",
+                            ("path",)),
                 reg.histogram(
                     "fdtpu_moe_load_max_over_mean",
                     "a step's largest expert load over its mean load",
                     ("layer",), buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0,
                                          16.0, 64.0)))
-        slots, dropped, balance = self._made
+        slots, dropped, paths, balance = self._made
         load = np.asarray(metrics["moe_load"], np.float64)
         load = load.reshape((-1,) + load.shape[-2:])  # steps_per_call > 1
         held, absent = np.asarray(
@@ -1232,6 +1236,10 @@ class _RouterCounters:
         slots.labels(where="held").inc(float(held))
         slots.labels(where="absent").inc(float(absent))
         dropped.inc(float(np.sum(np.asarray(metrics["moe_dropped"]))))
+        compact, full = np.asarray(
+            metrics["moe_compact"], np.float64).reshape(-1, 2).sum(axis=0)
+        paths.labels(path="compact").inc(float(compact))
+        paths.labels(path="full").inc(float(full))
         for step in load:
             for layer, row in enumerate(step):
                 mean = row.mean()

@@ -121,18 +121,28 @@ def test_the_flash_path_wants_one_head_size():
 
 # -- the expert layer ---------------------------------------------------------
 
-def skewed_layer(held):
+def skewed_layer(held, tokens=128, skew=True):
     """A layer's weights and tokens with the router skewed towards
-    expert ``held[0] + 1``: its score is the largest for every token."""
+    expert ``held[0] + 1``: its score is the largest for every token
+    (``skew=False``: the seeded router as it is, an even load)."""
     cfg = tiny_cfg(experts_held=list(held), num_nextn_predict_layers=0)
     params, state = REF.make_params(cfg, jax.random.PRNGKey(7))
     p = copy.deepcopy(params["layer1"])
-    x = jax.random.normal(jax.random.PRNGKey(8), (128, 32), jnp.float32)
-    # a column along the tokens' mean direction, and tokens with a mean
-    mean = jnp.ones((32,)) / jnp.sqrt(32.0)
-    x = x + 4.0 * mean
-    p["moe"]["router"] = p["moe"]["router"].at[:, held[0] + 1].set(2.0 * mean)
+    x = jax.random.normal(jax.random.PRNGKey(8), (tokens, 32), jnp.float32)
+    if skew:
+        # a column along the tokens' mean direction, and tokens with a mean
+        mean = jnp.ones((32,)) / jnp.sqrt(32.0)
+        x = x + 4.0 * mean
+        p["moe"]["router"] = p["moe"]["router"].at[:, held[0] + 1].set(
+            2.0 * mean)
     return cfg, p, state["router"]["layer1"], x
+
+
+def path_counted(cfg, router_state):
+    """[compact, full] as the model's step metrics count one layer."""
+    model = models.glm4_moe_lite(**cfg["model"]["kwargs"])
+    return [int(n) for n in model.step_metrics(
+        {"router": {"layer1": router_state}})["moe_compact"]]
 
 
 def program_layer(cfg, p, state, x):
@@ -149,19 +159,31 @@ def program_layer(cfg, p, state, x):
     return y, shared, new["router"]
 
 
-@pytest.mark.parametrize("held", [(0, 8), (0, 64), (24, 8)])
-def test_expert_layer_matches_the_reference_and_drops_nothing(held):
-    cfg, p, state, x = skewed_layer(held)
+# 128 tokens: the bound is all 512 slots, one path and no branch.  1,024
+# tokens: 4,096 slots, a bound of 1,024 rows; the even router holds some
+# 512 slots and fits, the skewed one over 1,300 and takes the whole buffer
+@pytest.mark.parametrize("held,tokens,skew,path", [
+    ((0, 8), 128, True, [0, 1]), ((0, 64), 128, True, [0, 1]),
+    ((24, 8), 128, True, [0, 1]), ((0, 8), 1024, True, [0, 1]),
+    ((0, 8), 1024, False, [1, 0]), ((24, 8), 1024, False, [1, 0])])
+def test_expert_layer_matches_the_reference_and_drops_nothing(
+        held, tokens, skew, path):
+    cfg, p, state, x = skewed_layer(held, tokens, skew)
     want, ref_state = jax.jit(
         lambda p, x: REF.expert_mlp(cfg, PREC, p, state, x))(p, x)
     y, shared, new = jax.jit(
         lambda p, x: program_layer(cfg, p, state, x))(p, x)
     close(y + shared, want)
-    # every token chose the favoured expert: over half of them on one
-    # expert, and each is in the result (the reference has no capacity)
     load = np.asarray(new["load"])
-    assert load[held[0] + 1] == len(x) and load.sum() == 4 * len(x)
+    assert load.sum() == 4 * len(x)
+    if skew:
+        # every token chose the favoured expert: over half of them on one
+        # expert, and each is in the result (the reference has no capacity)
+        assert load[held[0] + 1] == len(x)
     np.testing.assert_array_equal(load, np.asarray(ref_state["moe"]["load"]))
+    fits = load[held[0]:held[0] + held[1]].sum() <= ep.compact_rows(
+        4 * tokens, held[1], 64) < 4 * tokens
+    assert path_counted(cfg, new) == path == [int(fits), int(not fits)]
     # gradients of the routed part, the router's among them
     w = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
 
@@ -174,6 +196,67 @@ def test_expert_layer_matches_the_reference_and_drops_nothing(held):
 
     trees_close(jax.jit(jax.grad(prog, argnums=(0, 1)))(p, x),
                 jax.jit(jax.grad(ref, argnums=(0, 1)))(p, x))
+
+
+def routed_grads(args, first):
+    """The routed part and its five gradients, through a fresh trace."""
+    def out(x, weights, w_gate, w_up, w_down, chosen):
+        return ep.held_experts_apply(x, chosen, weights, w_gate, w_up, w_down,
+                                     64, first=first)
+
+    w = jax.random.normal(jax.random.PRNGKey(10), args[0].shape, jnp.float32)
+    text = str(jax.make_jaxpr(out)(*args))
+    return jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(out(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args), text
+
+
+@pytest.mark.parametrize("first", [0, 24])
+def test_the_bounded_buffer_and_the_whole_one_agree(first, monkeypatch):
+    """The same step over 1,024 rows and over all 4,096: output and the
+    five gradients to float32 rounding, and both the reference's."""
+    cfg, p, state, x = skewed_layer((first, 8), 1024, skew=False)
+    chosen, weights, load = ep.sigmoid_route(
+        x, p["moe"]["router"], state["moe"]["bias"], top_k=4,
+        scale=cfg["routed_scaling_factor"])
+    assert float(load[first:first + 8].sum()) <= 1024
+    part = {k: p["moe"][k] for k in ("w_gate", "w_up", "w_down")}
+    args = (x, weights, *part.values(), chosen)
+    bounded, text = routed_grads(args, first)
+    assert " cond[" in text
+    # a bound of eight times the expected share is every slot
+    monkeypatch.setattr(ep, "COMPACT_OVER_EXPECTED", 8)
+    whole, text = routed_grads(args, first)
+    assert " cond[" not in text
+    trees_close(bounded, whole)
+
+    def ref(x, weights, w_gate, w_up, w_down):
+        part = {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+        return REF.routed_part(cfg, PREC, part, x, chosen, weights)
+
+    w = jax.random.normal(jax.random.PRNGKey(10), x.shape, jnp.float32)
+    want = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args[:5])
+    trees_close(bounded, want)
+
+
+@pytest.mark.parametrize("slots,held,experts,rows", [
+    (65536, 8, 64, 16384),    # the cell: twice the 8,192 expected
+    (65536, 64, 64, 65536),   # every expert held
+    (65536, 40, 64, 65536),   # over half of them
+    (512, 4, 8, 512),         # the tiny sizes
+    (4096, 8, 64, 1024), (3840, 8, 64, 1024)])  # up to a tile of 512
+def test_compact_rows_by_hand_and_no_branch_where_it_is_every_slot(
+        slots, held, experts, rows):
+    assert ep.compact_rows(slots, held, experts) == rows
+    # the same of an array of loads, as the step's metrics call it
+    assert int(ep.compact_rows(jnp.int32(slots), held, experts)) == rows
+    n, k = slots // 64, 4  # the layer at a sixteenth of the slots, 4 a token
+    bound = ep.compact_rows(n * k, held, experts)
+    text = str(jax.make_jaxpr(
+        lambda x, c, w, a, b: ep.held_experts_apply(x, c, w, a, a, b, experts))(
+            jnp.zeros((n, 8)), jnp.zeros((n, k), jnp.int32), jnp.zeros((n, k)),
+            jnp.zeros((held, 8, 4)), jnp.zeros((held, 4, 8))))
+    assert (" cond[" in text) == (bound < n * k)
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
@@ -191,11 +274,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                 for k in ("w_gate", "w_up", "w_down")}
         total = total + ep.held_experts_apply(
             x, chosen, weights, part["w_gate"], part["w_up"], part["w_down"],
-            first=first)
+            64, first=first)
         # the same share through the reference
         share = dict(cfg, experts_held=[first, 8])
         close(ep.held_experts_apply(x, chosen, weights, *part.values(),
-                                    first=first),
+                                    64, first=first),
               REF.routed_part(share, PREC, part, x, chosen, weights))
     close(total, want)
     assert float(load.sum()) == 4 * len(x)
@@ -281,7 +364,15 @@ def test_eval_leaves_the_routers_alone_and_adds_no_multi_token_term():
     close(loss, models.next_token_loss(logits, tokens))
 
 
-def test_step_metrics_carry_the_load_through_the_step_to_the_counters():
+@pytest.mark.parametrize("sizes,rows,paths", [
+    # 128 and 120 slots: the bound is every slot, no branch, counted whole
+    (dict(router_experts=8, experts_held=[2, 4], num_experts_per_tok=2), 4,
+     [0, 2]),
+    # 4,096 and 3,840 slots of which an eighth are held: 1,024 rows hold them
+    (dict(router_experts=64, experts_held=[8, 8], num_experts_per_tok=4), 64,
+     [2, 0])])
+def test_step_metrics_carry_the_load_through_the_step_to_the_counters(
+        sizes, rows, paths):
     """The step's metrics hold what the model reports of its routers,
     and the trainer's watcher callback feeds the registry from them; a
     model without a router reports and registers nothing."""
@@ -290,22 +381,26 @@ def test_step_metrics_carry_the_load_through_the_step_to_the_counters():
     from fluxdistributed_tpu.parallel.dp import TrainState, make_train_step
     from fluxdistributed_tpu.train.trainer import _RouterCounters
 
-    cfg = tiny_cfg(router_experts=8, experts_held=[2, 4], num_experts_per_tok=2)
+    cfg = tiny_cfg(**sizes)
     model = models.glm4_moe_lite(**cfg["model"]["kwargs"])
     params, state = REF.make_params(cfg, jax.random.PRNGKey(15))
     opt = fd.optim.adamw(lr=1e-3)
     mesh = fd.data_mesh(devs=jax.devices()[:1])
     step = make_train_step(models.lm_loss_fn(model), opt, mesh, donate=False)
-    tokens = jnp.asarray(np.random.default_rng(16).integers(0, 64, (4, 16)),
+    tokens = jnp.asarray(np.random.default_rng(16).integers(0, 64, (rows, 16)),
                          jnp.int32)
     new, metrics = step(TrainState.create(params, opt, model_state=state),
                         {"tokens": tokens})
     load = np.asarray(metrics["moe_load"])
-    assert load.shape == (2, 8)  # layer1 and the multi-token module's block
-    assert load[0].sum() == 4 * 16 * 2 and load[1].sum() == 4 * 15 * 2
+    k, (first, count) = sizes["num_experts_per_tok"], sizes["experts_held"]
+    # layer1 and the multi-token module's block
+    assert load.shape == (2, sizes["router_experts"])
+    assert load[0].sum() == rows * 16 * k and load[1].sum() == rows * 15 * k
     held, absent = np.asarray(metrics["moe_slots"])
-    assert held == load[:, 2:6].sum() and held + absent == load.sum()
+    assert held == load[:, first:first + count].sum()
+    assert held + absent == load.sum()
     assert float(metrics["moe_dropped"]) == 0.0
+    assert [int(n) for n in metrics["moe_compact"]] == paths
     np.testing.assert_array_equal(
         load[0], np.asarray(new.model_state["router"]["layer1"]["moe"]["load"]))
 
@@ -320,6 +415,9 @@ def test_step_metrics_carry_the_load_through_the_step_to_the_counters():
     balance = reg.get("fdtpu_moe_load_max_over_mean")
     assert balance.cell_count("0") == balance.cell_count("1") == 1
     assert balance.cell_sum("0") == pytest.approx(load[0].max() / load[0].mean())
+    feed(metrics)  # a second step: its layers are counted on top
+    assert [reg.value("fdtpu_moe_compact_total", path) / 2
+            for path in ("compact", "full")] == paths
 
     dense = models.lm_tiny(vocab=64)
     assert not hasattr(models.lm_loss_fn(dense), "step_metrics")

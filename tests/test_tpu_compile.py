@@ -139,7 +139,13 @@ def test_flash_backward_latent_attention_widths(chip, as_on_tpu):
 def test_held_experts_grouped_products(chip):
     """The same cell's expert layer: 16,384 tokens, 4 of 64 experts a
     token, 8 held; XLA lowers `ragged_dot` to its grouped-matmul kernel,
-    forward and both gradients, under the name the reducer reads."""
+    forward and both gradients, under the name the reducer reads.  The
+    backward is a conditional: one branch over the 16,384 rows the chip
+    can expect to hold, one over all 65,536 slots for a step that
+    overflows them, and the first makes no product as long as the
+    slots."""
+    import re
+
     from fluxdistributed_tpu.parallel import ep
 
     x = chip((16384, 2048), BF)
@@ -149,12 +155,20 @@ def test_held_experts_grouped_products(chip):
     def layer(x, router, w_gate, w_up, w_down):
         chosen, weights, _ = ep.sigmoid_route(
             x, router, jnp.zeros((64,)), top_k=4, scale=1.8)
-        return ep.held_experts_apply(x, chosen, weights, w_gate, w_up, w_down)
+        return ep.held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, 64)
 
     text = _compile(jax.grad(
         lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)),
         x, router, w_in, w_in, w_out)
-    assert text.count("%ragged-dot-none") >= 9
+    (names,) = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+    whole, bounded = (  # branch 0 is the predicate's False
+        text[text.index(f"\n{name.strip()} ("):].split("\n}\n", 1)[0]
+        for name in names.split(","))
+    for branch in (whole, bounded):
+        # 3 forward again and 6 gradients, each defined and then used
+        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", branch)) == 9
+    assert "[65536,1536]" in whole and "[16384,1536]" not in whole
+    assert "[16384,1536]" in bounded and "[65536," not in bounded
 
 
 @pytest.mark.parametrize("hkv", [H, HKV], ids=["dense", "gqa"])
