@@ -72,7 +72,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="after training, aggregate loss/top-k over the FULL "
                         "--val-dataset with train.evaluate")
     p.add_argument("--spmd", default="jit",
-                   choices=["jit", "dp", "shard_map", "fsdp", "tp", "fsdp_tp",
+                   choices=["jit", "dp", "shard_map",
                             "pp", "pp_1f1b", "ep", "sp"])
     p.add_argument("--layout", default=None, metavar="NAME|auto",
                    help="declarative dp x fsdp x tp layout "
@@ -104,9 +104,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="optimizer steps per dispatch (device loop; spmd=jit). "
                         "Amortizes host dispatch latency")
     p.add_argument("--tp", type=int, default=None,
-                   help="model-axis size for --spmd tp / fsdp_tp (mesh "
-                        "becomes {data: N/tp, model: tp}; required for "
-                        "fsdp_tp, defaults to all devices for tp)")
+                   help="model-axis size for --layout tp / fsdp_tp (the "
+                        "layout becomes dp=N/tp x tp, resp. fsdp=N/tp x "
+                        "tp; default: the preset's own split)")
     p.add_argument("--pipe", type=int, default=None,
                    help="pipe-axis size for --spmd pp / pp_1f1b (mesh "
                         "becomes {data: N/pipe, pipe: pipe}; defaults to "
@@ -408,18 +408,45 @@ def main(argv=None) -> int:
         )
     if args.final_eval and args.val_dataset is None:
         raise SystemExit("--final-eval needs --val-dataset")
-    def data_x_mesh(axis: str, flag: str, requested, min_k: int = 2):
-        """The shared {data: N/k, <axis>: k} mesh recipe behind --tp /
-        --pipe / --expert-parallel / --seq-parallel: resolve the default
+    def data_x_mesh(axis: str, flag: str, requested):
+        """The shared {data: N/k, <axis>: k} mesh recipe behind --pipe /
+        --expert-parallel / --seq-parallel: resolve the default
         (all devices), validate divisibility, build the mesh."""
         from fluxdistributed_tpu.mesh import make_mesh
 
         ndev = jax.device_count()
         k = requested if requested is not None else ndev
-        if k < min_k or ndev % k:
+        if k < 2 or ndev % k:
             raise SystemExit(
-                f"{flag} {k} must be >={min_k} and divide {ndev} devices")
+                f"{flag} {k} must be >=2 and divide {ndev} devices")
         return make_mesh({"data": ndev // k, axis: k}), k
+
+    # a named layout resolves before the model is built (its model-axis
+    # size bounds --kv-heads); 'auto' is picked once the model exists
+    chosen = None
+    if args.tp is not None and args.layout not in ("tp", "fsdp_tp"):
+        raise SystemExit("--tp only applies with --layout tp or fsdp_tp")
+    if args.layout not in (None, "auto"):
+        from fluxdistributed_tpu.parallel import layout as layout_lib
+
+        ndev = jax.device_count()
+        if args.tp is None:
+            try:
+                chosen = layout_lib.resolve_layout(args.layout)
+            except layout_lib.LayoutError as e:
+                raise SystemExit(f"--layout {args.layout}: {e}")
+        elif args.tp < 1 or ndev % args.tp:
+            raise SystemExit(
+                f"--tp {args.tp} must be >=1 and divide {ndev} devices")
+        elif args.layout == "tp":
+            chosen = layout_lib.Layout("tp", dp=ndev // args.tp, tp=args.tp)
+        elif args.tp == ndev:
+            raise SystemExit(
+                "--layout fsdp_tp needs --tp < device count: with no fsdp "
+                "extent there is nothing for FSDP to shard over")
+        else:
+            chosen = layout_lib.Layout(
+                "fsdp_tp", fsdp=ndev // args.tp, tp=args.tp)
 
     # Sequence/context parallelism: the model's attn_fn closes over the
     # mesh, so the seq mesh is built BEFORE the model for this mode
@@ -497,18 +524,13 @@ def main(argv=None) -> int:
             raise SystemExit(
                 f"--kv-heads {args.kv_heads} must be > 0 and divide the "
                 f"model's num_heads ({nheads} for {args.model})")
-        if args.spmd in ("tp", "fsdp_tp") and not (
-                args.spmd == "fsdp_tp" and args.tp is None):
-            # lm_tp_rules head-shards the kv projection: the model axis
-            # must divide the KV head count or sharding fails cryptically.
-            # (fsdp_tp without --tp is itself invalid — the dedicated
-            # check below reports THAT, not a misleading kv-heads error.)
-            model_k = args.tp if args.tp is not None else jax.device_count()
-            if args.kv_heads % model_k:
-                raise SystemExit(
-                    f"--kv-heads {args.kv_heads} must be a multiple of the "
-                    f"TP model-axis size ({model_k}) so the grouped kv "
-                    f"projection can be head-sharded")
+        if chosen is not None and args.kv_heads % chosen.tp:
+            # the lm_tp table head-shards the kv projection: the model
+            # axis must divide the KV head count
+            raise SystemExit(
+                f"--kv-heads {args.kv_heads} must be a multiple of the "
+                f"layout's model-axis size ({chosen.tp}) so the grouped "
+                f"kv projection can be head-sharded")
         attn_kwargs["num_kv_heads"] = args.kv_heads
     if args.norm != "layernorm" or args.mlp != "gelu":
         if not is_lm:
@@ -565,8 +587,6 @@ def main(argv=None) -> int:
     opt_factory = getattr(optim, args.opt)
     opt = opt_factory(lr)
 
-    if args.tp is not None and args.spmd not in ("tp", "fsdp_tp"):
-        raise SystemExit("--tp only applies with --spmd tp or fsdp_tp")
     if args.pipe is not None and args.spmd not in ("pp", "pp_1f1b"):
         raise SystemExit("--pipe only applies with --spmd pp or pp_1f1b")
     if args.microbatches is not None and args.spmd not in ("pp", "pp_1f1b"):
@@ -587,8 +607,7 @@ def main(argv=None) -> int:
     if args.seq_parallel is not None and args.spmd != "sp":
         raise SystemExit("--seq-parallel only applies with --spmd sp")
     if args.zero1 and args.spmd not in ("jit", "dp", "shard_map"):
-        raise SystemExit("--zero1 only applies with --spmd jit/dp/shard_map "
-                         "(fsdp already shards the optimizer state)")
+        raise SystemExit("--zero1 only applies with --spmd jit/dp/shard_map")
     if args.layout is not None:
         if args.spmd not in ("jit", "dp"):
             raise SystemExit("--layout builds the rule-derived 3-D step "
@@ -603,15 +622,7 @@ def main(argv=None) -> int:
                          "--layout auto")
     if args.sp_strategy != "ring" and args.spmd != "sp":
         raise SystemExit("--sp-strategy only applies with --spmd sp")
-    if args.spmd in ("tp", "fsdp_tp"):
-        if args.spmd == "fsdp_tp" and (
-                args.tp is None or args.tp >= jax.device_count()):
-            raise SystemExit(
-                "--spmd fsdp_tp needs --tp < device count: with no data-axis "
-                "extent there is nothing for FSDP to shard over"
-            )
-        mesh, _ = data_x_mesh("model", "--tp", args.tp, min_k=1)
-    elif args.spmd in ("pp", "pp_1f1b"):
+    if args.spmd in ("pp", "pp_1f1b"):
         mesh, _ = data_x_mesh("pipe", "--pipe", args.pipe)
         lm_extra["num_microbatches"] = args.microbatches
         lm_extra["pipeline_interleave"] = args.pp_interleave
@@ -658,11 +669,6 @@ def main(argv=None) -> int:
                 print(pick_report.describe())
             if args.layout_report:
                 pick_report.save(args.layout_report)
-        else:
-            try:
-                chosen = layout_lib.resolve_layout(args.layout)
-            except layout_lib.LayoutError as e:
-                raise SystemExit(f"--layout {args.layout}: {e}")
         mesh = chosen.build_mesh()
         lm_extra["layout"] = chosen
     else:

@@ -147,24 +147,24 @@ def _build_zero1_shardmap() -> List[StepVariant]:
 
 
 def _build_fsdp() -> List[StepVariant]:
-    from .. import mesh as mesh_lib
-    from ..parallel import fsdp
+    """ZeRO-3 over all 8 devices: the one-rule fsdp table."""
+    from ..parallel import layout as layout_mod
 
     model, ds = _image_setup()
-    return _prepared("fsdp", model, ds, mesh_lib.data_mesh(8), fsdp,
-                     execute=True, spmd="fsdp")
+    lay = layout_mod.resolve_layout("fsdp", 8)
+    return _prepared("fsdp", model, ds, lay.build_mesh(), layout_mod,
+                     execute=True, layout=lay)
 
 
 def _build_tp() -> List[StepVariant]:
-    from .. import mesh as mesh_lib
+    """Megatron tensor parallelism, dp=2 x tp=4: the lm_tp table."""
     from ..models.transformer_lm import lm_loss_fn
-    from ..parallel import tp
+    from ..parallel import layout as layout_mod
 
-    mesh = mesh_lib.make_mesh(
-        {mesh_lib.DATA_AXIS: 2, mesh_lib.MODEL_AXIS: 4})
     model, ds = _lm_setup(depth=1, heads=4)
-    return _prepared("tp", model, ds, mesh, tp, spmd="tp",
-                     loss_fn=lm_loss_fn(model), topk=())
+    lay = layout_mod.Layout("tp", dp=2, tp=4)
+    return _prepared("tp", model, ds, lay.build_mesh(), layout_mod,
+                     layout=lay, loss_fn=lm_loss_fn(model), topk=())
 
 
 def _build_pp_1f1b() -> List[StepVariant]:
@@ -220,8 +220,7 @@ def _build_layout_dp_fsdp() -> List[StepVariant]:
     """The rule-derived 2-D layout (dp=2 x fsdp=4) on the image model:
     the EMPTY rule table + the ShardLargest fsdp overlay shards a conv
     stack with no per-model spec code — swept so the 3-D mesh step
-    keeps donation/axis/retrace hygiene like the hand-built fsdp
-    variant it generalizes."""
+    keeps donation/axis/retrace hygiene."""
     from .. import mesh as mesh_lib  # noqa: F401 — axis constants source
     from ..parallel import layout as layout_mod
 
@@ -234,8 +233,7 @@ def _build_layout_dp_fsdp() -> List[StepVariant]:
 def _build_layout_fsdp_tp() -> List[StepVariant]:
     """fsdp=4 x tp=2 on the LM: the committed lm_tp rule table decides
     the Megatron dims, the overlay ZeRO-shards the leftovers — the 2-D
-    large-model recipe, derived from data instead of
-    hybrid_fsdp_tp_specs' special case."""
+    large-model recipe."""
     from ..models.transformer_lm import lm_loss_fn
     from ..parallel import layout as layout_mod
 

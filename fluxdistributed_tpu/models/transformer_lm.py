@@ -1683,7 +1683,7 @@ def lm_moe_specs(params, axis: str = EXPERT_AXIS):
     """PartitionSpec tree for an MoE LM's params: expert-stacked leaves
     (``w1/b1/w2/b2`` inside MoE blocks, leading dim E) sharded over
     ``axis``; routers and every dense leaf replicated.  Feed through
-    ``parallel.tp.state_specs`` + ``sharding.make_shardings`` to get the
+    ``parallel.rules.train_state_specs`` + ``sharding.make_shardings`` to get the
     ``state_shardings=`` for ``make_train_step``."""
     from jax.sharding import PartitionSpec as P
 

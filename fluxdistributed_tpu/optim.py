@@ -325,7 +325,7 @@ def with_ema(optimizer: Optimizer, decay: float = 0.9999) -> Optimizer:
     steps don't average against the random init.
 
     State layout honors the opt-state contract the TP/PP sharding
-    machinery assumes (tp.state_specs/broadcast_prefix: "mirror the
+    machinery assumes (rules.train_state_specs/broadcast_prefix: "mirror the
     param tree, extra structure nested PER PARAM"): each param leaf maps
     to ``{"inner": <wrapped state leaf>, "ema": <shadow leaf>}``.  The
     shadow is a real copy (never an alias of the live param buffer, so

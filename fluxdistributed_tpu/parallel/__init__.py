@@ -7,8 +7,6 @@ from .context import (
     ulysses_attention,
 )
 from .dp import TrainState, make_train_step, make_eval_step, make_train_step_shardmap
-from . import fsdp
-from .fsdp import fsdp_specs, hybrid_fsdp_tp_specs, make_train_step_fsdp, make_eval_step_fsdp
 from . import zero1
 from . import zero1_fused
 from .zero1_fused import (
@@ -35,7 +33,6 @@ from .pp import make_train_step_pp, pipeline_apply, stack_stage_params, switch_s
 from .pp_1f1b import build_schedule, make_train_step_1f1b, pipeline_grads_1f1b
 from . import pp_plan
 from .pp_plan import PipelinePlan, plan_from_model, plan_from_profile, plan_stages
-from .tp import lm_tp_rules, make_train_step_tp, param_specs, shard_state, vit_tp_rules
 from . import rules
 from .rules import (
     RULE_TABLES,
@@ -58,11 +55,6 @@ __all__ = [
     "make_train_step",
     "make_eval_step",
     "make_train_step_shardmap",
-    "fsdp",
-    "fsdp_specs",
-    "hybrid_fsdp_tp_specs",
-    "make_train_step_fsdp",
-    "make_eval_step_fsdp",
     "zero1",
     "zero1_fused",
     "fused_adam_update",
@@ -77,11 +69,6 @@ __all__ = [
     "make_ring_attention",
     "ulysses_attention",
     "make_ulysses_attention",
-    "make_train_step_tp",
-    "param_specs",
-    "shard_state",
-    "vit_tp_rules",
-    "lm_tp_rules",
     "pipeline_apply",
     "make_train_step_pp",
     "build_schedule",

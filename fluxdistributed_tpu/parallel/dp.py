@@ -162,8 +162,9 @@ def make_train_step(
 
     ``state_shardings`` (a ``TrainState`` of ``NamedSharding`` leaves)
     overrides the replicated default for the train state — this is how
-    ``fsdp.make_train_step_fsdp`` turns the same step into ZeRO-style
-    fully-sharded data parallelism without duplicating the step logic:
+    ``prepare_training(layout=...)`` turns the same step into ZeRO-style
+    fully-sharded (and tensor-parallel) training without duplicating the
+    step logic:
     XLA inserts the all-gathers (params on use) and reduce-scatters
     (grads at the sharded update) implied by the annotations.
 

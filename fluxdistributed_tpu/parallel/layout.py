@@ -15,8 +15,10 @@ tensor-parallel dims, :func:`.rules.with_fsdp` overlays the ZeRO
 sharding on every large leaf's leftover dim, and the derived spec tree
 drives the UNCHANGED dp train step (``dp.make_train_step`` with
 ``state_shardings`` + a ``("data", "fsdp")`` batch) — GSPMD composes
-the collectives exactly as it already does for the hand-built fsdp/tp
-variants (arXiv:1810.09868's full-program partitioning).
+the collectives from the annotations (arXiv:1810.09868's full-program
+partitioning): a per-layer all-gather and a gradient reduce-scatter
+over ``fsdp`` (the ZeRO-3 schedule), two all-reduces a block over
+``model``.
 
 :func:`pick` is the auto-layout picker ROADMAP item 3 promised: it
 prices every candidate layout by compiling the REAL train step
@@ -47,6 +49,7 @@ __all__ = [
     "resolve_layout",
     "layout_candidates",
     "state_specs_for",
+    "shard_state",
     "price_layouts",
     "pick",
 ]
@@ -193,7 +196,8 @@ def state_specs_for(model, state, layout: Layout, mesh,
             f"layout {layout.name!r} has a model axis (tp={layout.tp}) "
             f"but {type(model).__name__} has no tensor-parallel rule "
             "table — every leaf would replicate over it.  Use a dp/"
-            "fsdp layout, or register a table in parallel/rules.py")
+            "fsdp layout, or give the family a table in "
+            "parallel/rules.py and an entry in rules.rules_for_model")
     p_specs = rules.match_partition_rules(
         table, state.params, mesh=mesh, **kw)
     if layout.fsdp > 1:
@@ -203,6 +207,26 @@ def state_specs_for(model, state, layout: Layout, mesh,
     rules.validate_specs(spec_state, state, mesh,
                          where=f"layout:{layout.name}")
     return spec_state
+
+
+def shard_state(model, state, layout: Layout, mesh,
+                min_size: Optional[int] = None):
+    """Place ``state`` per :func:`state_specs_for`; returns the placed
+    state and its ``NamedSharding`` tree (the ``state_shardings=`` of
+    ``dp.make_train_step``).  Leaves are copied first
+    (``sharding.unaliased``) so donating the placed state cannot delete
+    the caller's source arrays."""
+    import jax
+
+    from ..sharding import make_shardings, unaliased
+
+    sh = make_shardings(
+        state_specs_for(model, state, layout, mesh, min_size=min_size), mesh)
+
+    def put(x, s):
+        return None if x is None else jax.device_put(unaliased(x), s)
+
+    return jax.tree.map(put, state, sh, is_leaf=lambda x: x is None), sh
 
 
 # -- the picker -------------------------------------------------------------

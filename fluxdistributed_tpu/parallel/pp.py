@@ -273,7 +273,7 @@ def make_train_step_pp(
     collective at all, the pipeline's communication is activations only.
     """
     from ..sharding import make_shardings
-    from .tp import state_specs
+    from .rules import train_state_specs
 
     fwd = pipeline_apply(
         stage_fn, mesh, axis=axis, num_microbatches=num_microbatches, remat=remat
@@ -282,7 +282,7 @@ def make_train_step_pp(
 
     def state_shardings(state: TrainState) -> TrainState:
         p_specs = jax.tree.map(lambda _: P(axis), state.params)
-        return make_shardings(state_specs(state, p_specs), mesh)
+        return make_shardings(train_state_specs(state, p_specs), mesh)
 
     def step(state: TrainState, batch):
         def lossf(params):

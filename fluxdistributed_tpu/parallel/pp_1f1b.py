@@ -79,14 +79,14 @@ def split_state_shardings(mesh: Mesh, axis: str = PIPE_AXIS) -> Callable:
     of truth for both pipeline schedules (``lm_pp``/``lm_pp_1f1b`` reuse
     it, and ``make_train_step_1f1b`` compiles with it)."""
     from ..sharding import make_shardings
-    from .tp import state_specs
+    from .rules import train_state_specs
 
     def state_shardings(state: TrainState) -> TrainState:
         p_specs = {
             "outer": jax.tree.map(lambda _: P(), state.params["outer"]),
             "stages": jax.tree.map(lambda _: P(axis), state.params["stages"]),
         }
-        return make_shardings(state_specs(state, p_specs), mesh)
+        return make_shardings(train_state_specs(state, p_specs), mesh)
 
     return state_shardings
 

@@ -1,21 +1,21 @@
 """Declarative sharding rules: a ~10-line regex-on-path rule table turns
 into a full PartitionSpec tree for ANY model.
 
-Every parallelism variant used to hand-build its PartitionSpecs per
-model (``tp.lm_tp_rules`` / ``tp.vit_tp_rules`` as Python callables,
-``fsdp.fsdp_specs`` as a shape walk), so each new model or mesh shape
-cost bespoke spec code and nothing composed — ROADMAP item 3's wall.
-This module replaces that with DATA:
+This module is the one place that decides which leaf is split over
+which mesh axis; :mod:`.layout` supplies the dp x fsdp x tp grid the
+axes live on, and ``prepare_training(layout=...)`` compiles the
+unchanged dp step with the derived shardings.  Placement is DATA:
 
 * :func:`match_partition_rules` — EasyLM-style (SNIPPETS.md [3]): walk
   the param tree, '/'-join each leaf path, take the FIRST rule whose
   regex ``re.search``-matches, and use its value as the leaf's
   PartitionSpec.  Scalars and single-element leaves always replicate.
 * :class:`ShardLargest` — a shape-driven rule value (the paranum-style
-  size threshold, SNIPPETS.md [2], generalized by ``fsdp.fsdp_leaf_
-  spec``): shard the leaf's largest still-unsharded divisible dim over
-  one mesh axis.  This is how ZeRO-style parameter/optimizer sharding
-  (arXiv:2004.13336 extended to ZeRO-3 placement) becomes ONE rule —
+  size threshold, SNIPPETS.md [2], generalized by
+  :func:`fsdp_leaf_spec`): shard the leaf's largest still-unsharded
+  divisible dim over one mesh axis.  This is how ZeRO-style
+  parameter/optimizer sharding (arXiv:2004.13336 extended to ZeRO-3
+  placement) becomes ONE rule —
   ``(".*", ShardLargest(mesh.FSDP_AXIS))`` — instead of a per-model
   walk, and how it composes with tensor-parallel rules: a
   :func:`with_fsdp` overlay applies it on top of an existing spec
@@ -32,10 +32,11 @@ This module replaces that with DATA:
   divisibility) against real leaf shapes BEFORE any memory is
   committed.
 
-The hand-built variants are reproducible as committed tables
-(:data:`RULE_TABLES`) whose derived trees match the legacy builders
-leaf-for-leaf — parity-pinned by tests/test_rules.py so the old AOT
-keys and the memory baseline survive this refactor.
+The committed tables (:data:`RULE_TABLES`) are the Megatron recipes for
+the transformer LM and the ViT, the one-rule ZeRO-3 table and the empty
+dp table; tests/test_rules.py pins the spec tree each gives, leaf for
+leaf, so a table edit that moves a leaf is seen.  A new model family
+gets a table here and one entry in :func:`rules_for_model`.
 """
 
 from __future__ import annotations
@@ -45,9 +46,11 @@ import re
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import numpy as np
+import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import mesh as mesh_lib
+from .dp import TrainState
 
 Pytree = Any
 
@@ -58,11 +61,13 @@ __all__ = [
     "RuleTable",
     "RULE_TABLES",
     "FALLBACK_MIN_SIZE",
+    "fsdp_leaf_spec",
     "match_partition_rules",
     "with_fsdp",
     "rule_report",
     "validate_rules",
     "validate_specs",
+    "broadcast_prefix",
     "train_state_specs",
     "dp_rules",
     "fsdp_rules",
@@ -72,18 +77,60 @@ __all__ = [
     "registered_rule_tables",
 ]
 
-#: an UNMATCHED leaf at or above this many elements falling to
-#: replication is reported (and rejected under ``strict=True``) — the
-#: same scale as ``fsdp.MIN_SHARD_ELEMS``: below it, replication is the
-#: right answer, not a trap
+#: leaves smaller than this stay replicated: sharding a 64-float
+#: BatchNorm bias saves nothing and costs a latency-bound collective
+#: per use.  The same scale decides the report: an UNMATCHED leaf at
+#: or above it falling to replication is reported (and rejected under
+#: ``strict=True``) — below it, replication is the right answer, not
+#: a trap
 FALLBACK_MIN_SIZE = 2 ** 11
+
+
+def fsdp_leaf_spec(
+    shape, axis: str = mesh_lib.DATA_AXIS, nshards: int = 1,
+    min_size: int = FALLBACK_MIN_SIZE, base: P | None = None,
+) -> P:
+    """PartitionSpec for one leaf, chosen from its shape alone.
+
+    Shards the largest dimension divisible by ``nshards`` (ties broken
+    toward the trailing dim — for conv HWIO / dense (in, out) kernels
+    that is the output-features dim, giving contiguous lanes-friendly
+    shards).  Leaves with fewer than ``min_size`` elements, or no
+    divisible dim, stay replicated.
+
+    ``base`` composes with an existing spec (the fsdp x tp layouts):
+    only dims the base leaves unsharded are candidates, and the base's
+    entries are preserved in the result.
+
+    The rule is a pure function of shape (and base), so a parameter and
+    its optimizer-state slots (momentum/Adam moments have the param's
+    shape) always agree — the property that lets one spec tree cover the
+    whole ``TrainState``.
+    """
+    entries = (
+        list(base) + [None] * (len(shape) - len(base))
+        if base is not None
+        else [None] * len(shape)
+    )
+    keep = P(*entries) if base is not None else P()
+    if not shape or int(np.prod(shape)) < min_size:
+        return keep
+    best = None  # (extent, dim)
+    for d, extent in enumerate(shape):
+        if entries[d] is None and extent % nshards == 0 and extent >= nshards:
+            if best is None or extent >= best[0]:
+                best = (extent, d)
+    if best is None:
+        return keep
+    entries[best[1]] = axis
+    return P(*entries)
 
 
 @dataclasses.dataclass(frozen=True)
 class ShardLargest:
     """Shape-driven rule value: shard the leaf's largest
     still-unsharded dim divisible by the axis size over ``axis``
-    (``fsdp.fsdp_leaf_spec`` semantics — ties break toward the
+    (:func:`fsdp_leaf_spec` semantics — ties break toward the
     trailing dim; leaves under ``min_size`` elements, or with no
     divisible dim, keep their base spec).  Resolution needs a mesh
     (the axis size), which :func:`match_partition_rules` provides."""
@@ -114,6 +161,10 @@ class RuleReport:
     large_unmatched: list
 
 
+def _is_spec(x) -> bool:
+    return isinstance(x, P)
+
+
 def _leaf_path(kp) -> str:
     return "/".join(
         str(getattr(k, "key", getattr(k, "name", getattr(k, "idx", k))))
@@ -122,8 +173,6 @@ def _leaf_path(kp) -> str:
 
 def _resolve_value(value, shape, mesh: Optional[Mesh], base: P = None):
     if isinstance(value, ShardLargest):
-        from .fsdp import fsdp_leaf_spec
-
         if mesh is None:
             raise ValueError(
                 "a ShardLargest rule value needs a mesh to resolve "
@@ -165,8 +214,6 @@ def match_partition_rules(
     when any rule value is a :class:`ShardLargest` and is also used to
     pre-validate axis names via :func:`validate_rules`.
     """
-    import jax
-
     if mesh is not None:
         validate_rules(rules, mesh)
     compiled = [(re.compile(pat), pat, val) for pat, val in rules]
@@ -212,17 +259,15 @@ def with_fsdp(
     """Overlay ZeRO-style fully-sharded placement on an existing spec
     tree: every large leaf's largest still-unsharded dim is sharded
     over ``axis`` (existing entries — e.g. tensor-parallel dims — are
-    preserved).  ``rules → with_fsdp`` is the 2-D/3-D composition the
-    hand-built ``fsdp.hybrid_fsdp_tp_specs`` special-cased for TP."""
-    import jax
-
-    from .fsdp import fsdp_leaf_spec
-
+    preserved).  ``rules → with_fsdp`` is the 2-D/3-D composition: the
+    standard large-model recipe of tensor parallelism per the table
+    PLUS ZeRO sharding of what the table left whole, so per-device
+    param/opt memory is about size / (|fsdp| x |model|)."""
     n = int(mesh.shape[axis])
     return jax.tree_util.tree_map(
         lambda spec, leaf: fsdp_leaf_spec(
             np.shape(leaf), axis, n, min_size=min_size, base=spec),
-        specs, params, is_leaf=lambda x: isinstance(x, P))
+        specs, params, is_leaf=_is_spec)
 
 
 def rule_report(rules: Sequence[Rule], params: Pytree,
@@ -285,7 +330,6 @@ def validate_specs(specs: Pytree, shapes: Pytree, mesh: Mesh,
     with arrays as leaves) because ``check_spec_tree``'s raw-tuple
     heuristic would otherwise mistake tuple-structured state — Adam's
     ``(m, v)`` pairs — for shape literals."""
-    import jax
     from jax.tree_util import keystr
 
     from ..analysis.jaxpr_checks import check_spec_tree
@@ -314,26 +358,39 @@ def validate_specs(specs: Pytree, shapes: Pytree, mesh: Mesh,
             f"finding(s)): {msgs}")
 
 
-def train_state_specs(state, p_specs: Pytree):
+def broadcast_prefix(specs: Pytree, tree: Pytree) -> Pytree:
+    """Broadcast a prefix tree of PartitionSpecs over a deeper tree.
+
+    Optimizer states mirror the param tree but may nest extra structure
+    per param (Adam's ``(m, v)`` tuples); each param's spec is applied to
+    every array in its state subtree.
+    """
+    treedef = jax.tree.structure(specs, is_leaf=_is_spec)
+    subtrees = treedef.flatten_up_to(tree)
+    leaves = jax.tree.leaves(specs, is_leaf=_is_spec)
+    mapped = [jax.tree.map(lambda _, s=s: s, sub) for s, sub in zip(leaves, subtrees)]
+    return jax.tree.unflatten(treedef, mapped)
+
+
+def train_state_specs(state: TrainState, p_specs: Pytree) -> TrainState:
     """A ``TrainState`` of specs from a param spec tree: optimizer
-    state broadcast from its param's spec (``tp.broadcast_prefix`` —
+    state broadcast from its param's spec (:func:`broadcast_prefix` —
     Adam moments share the param's shape, so the shape-driven and
     broadcast answers agree), mutable model state and the step counter
-    replicated.  The same recipe ``tp.state_specs`` uses — shared so a
-    rule-derived tree drops into every consumer a hand-built one
-    could."""
-    from .tp import state_specs
-
-    return state_specs(state, p_specs)
+    replicated."""
+    return TrainState(
+        params=p_specs,
+        opt_state=broadcast_prefix(p_specs, state.opt_state),
+        model_state=jax.tree.map(lambda _: P(), state.model_state),
+        step=P(),
+    )
 
 
 # -- committed rule tables ---------------------------------------------------
 #
-# The hand-built variants, as data.  Each table reproduces its legacy
-# builder's spec tree leaf-for-leaf (parity-pinned in
-# tests/test_rules.py).  Patterns are ordered specific-first: the
-# matcher takes the FIRST hit ("qkv/kernel$" must win before a
-# hypothetical broad "kernel$").
+# Each table's spec tree is pinned leaf for leaf in tests/test_rules.py.
+# Patterns are ordered specific-first: the matcher takes the FIRST hit
+# ("qkv/kernel$" must win before a hypothetical broad "kernel$").
 
 
 def dp_rules() -> list:
@@ -347,17 +404,24 @@ def dp_rules() -> list:
 def fsdp_rules(axis: str = mesh_lib.FSDP_AXIS,
                min_size: int = FALLBACK_MIN_SIZE) -> list:
     """ZeRO-3 placement as ONE rule: every large leaf's largest
-    divisible dim shards over ``axis``.  With ``axis=mesh.DATA_AXIS``
-    on a 1-D mesh this reproduces ``fsdp.fsdp_specs`` exactly."""
+    divisible dim shards over ``axis``; small leaves (BatchNorm
+    scales, biases) stay replicated."""
     return [(r".*", ShardLargest(axis, min_size=min_size))]
 
 
 def lm_tp_rules_table(model_axis: str = mesh_lib.MODEL_AXIS,
                       shard_vocab: bool = True) -> list:
-    """``tp.lm_tp_rules`` as data — the Megatron transformer recipe in
-    13 lines: qkv/q/kv column-sharded over heads, attention out
-    row-sharded, MLP up (gelu Dense_0 / swiglu gate+up) column- and
-    down (Dense_1/down) row-sharded, vocab embedding sharded."""
+    """The Megatron recipe for ``models.transformer_lm.TransformerLM``:
+    qkv (or, with ``num_kv_heads`` set, the separate q and kv
+    projections) column-sharded over heads, attention out row-sharded,
+    MLP up (gelu Dense_0 / swiglu gate+up) column- and down
+    (Dense_1/down) row-sharded, so each block needs exactly two
+    all-reduces.  ``embed/embedding [vocab, dim]`` is vocab-sharded
+    (Megatron's parallel vocab embedding — with tied embeddings the
+    logits come out vocab-sharded and GSPMD all-gathers at the f32
+    log-softmax).  Requires heads, kv heads, mlp_dim and (if
+    ``shard_vocab``) vocab divisible by the model-axis size.
+    "qkv/" is listed before "kv/" and "q/": the first hit decides."""
     rules = []
     if shard_vocab:
         rules.append((r"embed/embedding$", P(model_axis, None)))
@@ -381,9 +445,11 @@ def lm_tp_rules_table(model_axis: str = mesh_lib.MODEL_AXIS,
 
 
 def vit_tp_rules_table(model_axis: str = mesh_lib.MODEL_AXIS) -> list:
-    """``tp.vit_tp_rules`` as data: the encoder-block Megatron pattern
-    (ViT MLPs live under MlpBlock; patch embed / norms / head
-    replicate via the fallback)."""
+    """The Megatron recipe for ``models.vit.ViT`` encoder blocks: qkv
+    kernel ``[dim, 3, heads, head_dim]`` and out kernel ``[heads,
+    head_dim, dim]`` sharded over heads, MlpBlock Dense_0 column- and
+    Dense_1 row-sharded; patch embed / norms / head and the biases of
+    row-sharded layers replicate via the fallback."""
     return [
         (r"qkv/kernel$", P(None, None, model_axis, None)),
         (r"qkv/bias$", P(None, model_axis, None)),
@@ -414,7 +480,6 @@ def _probe_params(model, sample_shape, dtype="float32"):
     """eval_shape the model's init — param SHAPES without allocating
     a single buffer (rule matching and FDT108 only need paths and
     shapes)."""
-    import jax
     import jax.numpy as jnp
 
     sample = jax.ShapeDtypeStruct(sample_shape, jnp.dtype(dtype))

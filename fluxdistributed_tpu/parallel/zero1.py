@@ -19,7 +19,7 @@ elementwise update, only *where* each element is updated changes.
 
 Sharding is on the **flattened** leaf: each parameter/gradient leaf is
 raveled to 1-D and zero-padded to a multiple of the data-axis size, so
-ANY leaf shape shards evenly (contrast ``fsdp.fsdp_leaf_spec``, which
+ANY leaf shape shards evenly (contrast ``rules.fsdp_leaf_spec``, which
 must hunt for a divisible dimension and leaves indivisible leaves
 replicated).  Optimizer state mirrors that layout — flat padded leaves,
 nested per-param exactly like the unsharded state (momentum/Adam slots

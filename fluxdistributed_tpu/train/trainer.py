@@ -117,6 +117,15 @@ def _eval_view(dataset):
     return dataset
 
 
+#: the spmd= names that were a second spelling of a layout, each with
+#: the layout= that says the same
+_RETIRED_SPMD = {
+    "fsdp": 'layout="fsdp"',
+    "tp": 'layout=Layout("tp", dp=D, tp=K)',
+    "fsdp_tp": 'layout="fsdp_tp" (or Layout("fsdp_tp", fsdp=F, tp=K))',
+}
+
+
 def prepare_training(
     model,
     dataset,
@@ -158,11 +167,17 @@ def prepare_training(
     the per-device replication/buffers replaced by sharding annotations.
 
     ``val_samples`` defaults to the reference's 300-sample val slice
-    (src/ddp_tasks.jl:145).  ``spmd`` selects the compiled path: ``"jit"``
-    (auto-sharded DP; ``"dp"`` is an alias), ``"shard_map"`` (explicit
-    collectives), or ``"fsdp"`` (ZeRO-3: params + optimizer state sharded
-    across the data axis, see ``parallel/fsdp.py`` — same step math, ~N×
-    lower state memory on an N-way mesh).
+    (src/ddp_tasks.jl:145).  ``spmd`` selects the compiled program:
+    ``"jit"`` (auto-sharded DP; ``"dp"`` is an alias), ``"shard_map"``
+    (explicit collectives), ``"sp"``, ``"ep"``, ``"pp"``, ``"pp_1f1b"``.
+
+    ``layout`` says where the state's tensors live: a
+    :class:`~..parallel.layout.Layout` (or preset name: ``"fsdp"``,
+    ``"tp"``, ``"fsdp_tp"``, ``"dp_fsdp"``, ...) on the dp x fsdp x tp
+    grid.  The model family's rule table (``parallel/rules.py``) gives
+    every leaf its spec and the step is the same ``dp.make_train_step``
+    compiled with those shardings — same step math, ~N× lower state
+    memory on an N-way fsdp axis.  It is the only way to shard state.
 
     ``zero1=True`` upgrades the DP paths (``"jit"``/``"dp"``/
     ``"shard_map"``) to ZeRO-1 weight-update sharding
@@ -241,7 +256,8 @@ def prepare_training(
     detect bad steps at ONE extra scalar fetch per step and zero extra
     compiles.  Supported on the paths that ride
     ``dp.make_train_step`` — ``jit``/``dp`` (with or without
-    ``zero1``), ``sp``, ``ep`` and the GPipe ``pp`` — and requires
+    ``zero1``, under any ``layout``), ``sp``, ``ep`` and the GPipe
+    ``pp`` — and requires
     ``donate=False``: recovery re-uses the pre-step state, exactly like
     OOM-skip.  Other modes still run the guard loss-only (non-finite
     loss + spikes) without this flag.
@@ -268,6 +284,11 @@ def prepare_training(
 
     if spmd == "dp":  # explicit-name alias for the auto-sharded DP path
         spmd = "jit"
+    if spmd in _RETIRED_SPMD:
+        raise ValueError(
+            f"spmd={spmd!r} is gone: where tensors live is said by "
+            f"layout=, e.g. {_RETIRED_SPMD[spmd]} (rule tables in "
+            "parallel/rules.py, the grid in parallel/layout.py)")
     if layout is not None:
         # the declarative path (parallel/rules.py + parallel/layout.py):
         # a dp×fsdp×tp Layout (or preset name) whose rule-derived spec
@@ -302,8 +323,7 @@ def prepare_training(
     if zero1 and spmd not in ("jit", "shard_map"):
         raise ValueError(
             "zero1=True applies to the DP paths only (spmd='jit'/'dp'/"
-            f"'shard_map'); got spmd={spmd!r} — fsdp already shards the "
-            "optimizer state (ZeRO-3 subsumes ZeRO-1)"
+            f"'shard_map'); got spmd={spmd!r}"
         )
     if guard:
         if donate:
@@ -387,18 +407,13 @@ def prepare_training(
         # spec tree; the step itself is the UNCHANGED dp step compiled
         # with those shardings and the batch split over (data, fsdp) —
         # GSPMD derives the dp/ZeRO-3/Megatron collective composition
-        # from the annotations, same as the hand-built fsdp/tp variants
+        # from the annotations
         from ..parallel import layout as layout_lib
-        from ..sharding import make_shardings, unaliased
 
-        state = TrainState.create(params, optimizer, model_state=model_state)
-        spec_state = layout_lib.state_specs_for(model, state, layout, mesh)
-        sh = make_shardings(spec_state, mesh)
-
-        def _put(x, s):
-            return None if x is None else jax.device_put(unaliased(x), s)
-
-        state = jax.tree.map(_put, state, sh, is_leaf=lambda x: x is None)
+        state, sh = layout_lib.shard_state(
+            model,
+            TrainState.create(params, optimizer, model_state=model_state),
+            layout, mesh)
         batch_axes = layout.batch_axes
         if batch_size % layout.batch_shards:
             raise ValueError(
@@ -413,57 +428,6 @@ def prepare_training(
         eval_fn = make_eval_step(
             loss_fn, mesh, axis=batch_axes, topk=tuple(topk),
             state_shardings=sh)
-    elif spmd in ("tp", "fsdp_tp"):
-        # Megatron tensor parallelism over a (data, model) mesh; sharding
-        # rules picked by model family ("fsdp_tp" additionally
-        # FSDP-shards each large leaf's leftover dim over the data axis —
-        # the hybrid 2-D recipe).  No rng stream threads through the TP
-        # step — fine for the default dropout=0 configs.
-        from ..models.transformer_lm import TransformerLM
-        from ..models.vit import ViT
-        from ..parallel.tp import (
-            lm_tp_rules, make_train_step_tp, param_specs, shard_state,
-            state_specs, vit_tp_rules,
-        )
-        from ..sharding import make_shardings
-
-        if accum_steps != 1:
-            raise ValueError("accum_steps > 1 requires spmd='jit' or 'fsdp'")
-        if mesh_lib.MODEL_AXIS not in mesh.shape:
-            raise ValueError(
-                f"spmd={spmd!r} needs a mesh with a 'model' axis, e.g. "
-                "make_mesh({'data': D, 'model': K})"
-            )
-        if getattr(model, "dropout", 0.0):
-            raise ValueError(
-                f"spmd={spmd!r} supports dropout=0 only (no rng stream "
-                "threads through the TP step)"
-            )
-        if isinstance(model, ViT):
-            rules = vit_tp_rules()
-        elif isinstance(model, TransformerLM):
-            rules = lm_tp_rules()
-        else:
-            raise ValueError(
-                f"no TP sharding rules for {type(model).__name__}; "
-                f"spmd={spmd!r} supports ViT and TransformerLM (CNN params "
-                "are small — use DP/FSDP there)"
-            )
-        if spmd == "fsdp_tp":
-            from ..parallel.fsdp import hybrid_fsdp_tp_specs
-
-            specs = hybrid_fsdp_tp_specs(params, mesh, rules)
-        else:
-            specs = param_specs(params, rules)
-        state = TrainState.create(params, optimizer, model_state=model_state)
-        state = shard_state(state, mesh, specs)
-        step_fn = make_train_step_tp(
-            loss_fn, optimizer, mesh, specs, state, donate=donate
-        )
-        eval_fn = make_eval_step(
-            loss_fn, mesh, topk=tuple(topk),
-            state_shardings=make_shardings(state_specs(state, specs), mesh),
-        )
     elif spmd in ("pp", "pp_1f1b"):
         # Pipeline-parallel LM training as a first-class trainer mode:
         # decoder blocks stage-sharded over a 'pipe' axis, composed with
@@ -482,7 +446,7 @@ def prepare_training(
                 "change activation shapes mid-network)"
             )
         if accum_steps != 1:
-            raise ValueError("accum_steps > 1 requires spmd='jit' or 'fsdp'")
+            raise ValueError("accum_steps > 1 requires spmd='jit'")
         if custom_loss_fn:
             raise ValueError(
                 f"spmd={spmd!r} trains on the pipeline's own per-microbatch "
@@ -614,7 +578,7 @@ def prepare_training(
         # must have been CONSTRUCTED with that moe_fn — it closes over
         # the mesh (bin/driver.py builds it from --spmd ep flags).
         from ..models.transformer_lm import TransformerLM, lm_loss_fn, lm_moe_specs
-        from ..parallel.tp import state_specs
+        from ..parallel.rules import train_state_specs
         from ..sharding import make_shardings
 
         if not isinstance(model, TransformerLM) or not model.moe_every:
@@ -623,7 +587,7 @@ def prepare_training(
                 "mesh-bound moe_fn (models.moe_expert_fn via ep.moe_apply)"
             )
         if accum_steps != 1:
-            raise ValueError("accum_steps > 1 requires spmd='jit' or 'fsdp'")
+            raise ValueError("accum_steps > 1 requires spmd='jit'")
         for ax in (mesh_lib.EXPERT_AXIS, mesh_lib.DATA_AXIS):
             if ax not in mesh.shape:
                 raise ValueError(
@@ -634,29 +598,18 @@ def prepare_training(
             loss_fn = lm_loss_fn(model)  # token protocol, not image loss
         topk = ()  # image metrics can never apply to the LM
         state = TrainState.create(params, optimizer, model_state=model_state)
-        sh = make_shardings(state_specs(state, lm_moe_specs(params)), mesh)
+        sh = make_shardings(train_state_specs(state, lm_moe_specs(params)), mesh)
         state = jax.tree.map(jax.device_put, state, sh)
         step_fn = make_train_step(
             loss_fn, optimizer, mesh, axis=mesh_lib.DATA_AXIS,
             donate=donate, seed=seed, state_shardings=sh, guard=guard,
         )
         eval_fn = make_eval_step(loss_fn, mesh, topk=(), state_shardings=sh)
-    elif spmd == "fsdp":
-        from ..parallel import fsdp as fsdp_lib
-
-        state = TrainState.create(params, optimizer, model_state=model_state)
-        specs = fsdp_lib.fsdp_specs(state, mesh)
-        state = fsdp_lib.shard_state(state, specs, mesh)
-        step_fn = fsdp_lib.make_train_step_fsdp(
-            loss_fn, optimizer, mesh, specs,
-            donate=donate, accum_steps=accum_steps, seed=seed,
-        )
-        eval_fn = fsdp_lib.make_eval_step_fsdp(loss_fn, mesh, specs, topk=tuple(topk))
     else:
         if spmd not in ("jit", "shard_map", "sp"):
             raise ValueError(
                 f"unknown spmd mode {spmd!r}; pick one of jit (alias dp) / "
-                "shard_map / fsdp / tp / fsdp_tp / pp / pp_1f1b / ep / sp"
+                "shard_map / pp / pp_1f1b / ep / sp (layout= shards state)"
             )
         if spmd == "sp":
             # sequence/context parallelism rides the plain jit path with

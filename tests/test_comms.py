@@ -173,7 +173,7 @@ def test_fsdp_hlo_signature(variants):
     v = variants["fsdp"]
     compiled = v.fn.lower(*v.args).compile()
     assert _by_key(hlo_collectives(compiled, mesh=v.mesh)) == {
-        ("all_reduce", ("data",)): 1}
+        ("all_reduce", ("fsdp",)): 1}
 
 
 # ---- counting semantics ---------------------------------------------------

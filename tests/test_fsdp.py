@@ -1,4 +1,5 @@
-"""FSDP (ZeRO-3 via GSPMD) invariants, on the 8-device mesh.
+"""FSDP (ZeRO-3 via GSPMD) invariants, on the 8-device mesh, through
+the ``fsdp`` / ``fsdp_tp`` layouts.
 
 Sharding annotations must never change the math: the FSDP step's params
 after N steps must match the replicated DP step's bit-for-bit behavior
@@ -18,14 +19,15 @@ from fluxdistributed_tpu import optim, sharding
 from fluxdistributed_tpu.models import SimpleCNN
 from fluxdistributed_tpu.ops import logitcrossentropy
 from fluxdistributed_tpu.parallel import (
+    Layout,
     TrainState,
-    fsdp,
-    fsdp_specs,
-    make_eval_step_fsdp,
+    make_eval_step,
     make_train_step,
-    make_train_step_fsdp,
+    rules,
 )
 from fluxdistributed_tpu.parallel.dp import flax_loss_fn
+
+from _layout_step import layout_step
 
 BATCH = 32
 NCLASS = 10
@@ -43,28 +45,28 @@ def setup():
     )
     params = model.init(jax.random.PRNGKey(0), x[:2], train=True)["params"]
     loss_fn = flax_loss_fn(model, logitcrossentropy)
-    return mesh, params, loss_fn, {"image": x, "label": y}
+    return mesh, model, params, loss_fn, {"image": x, "label": y}
 
 
 def test_leaf_spec_rule():
     # large 2D leaf: shard the larger dim; trailing wins ties
-    assert fsdp.fsdp_leaf_spec((4096, 512), "data", 8) == P("data", None)
-    assert fsdp.fsdp_leaf_spec((512, 4096), "data", 8) == P(None, "data")
-    assert fsdp.fsdp_leaf_spec((4096, 4096), "data", 8) == P(None, "data")
+    assert rules.fsdp_leaf_spec((4096, 512), "data", 8) == P("data", None)
+    assert rules.fsdp_leaf_spec((512, 4096), "data", 8) == P(None, "data")
+    assert rules.fsdp_leaf_spec((4096, 4096), "data", 8) == P(None, "data")
     # conv HWIO: features dim, not the 3x3 window
-    assert fsdp.fsdp_leaf_spec((3, 3, 256, 256), "data", 8) == P(
+    assert rules.fsdp_leaf_spec((3, 3, 256, 256), "data", 8) == P(
         None, None, None, "data"
     )
     # small leaves (BN scale etc.) stay replicated
-    assert fsdp.fsdp_leaf_spec((64,), "data", 8) == P()
+    assert rules.fsdp_leaf_spec((64,), "data", 8) == P()
     # no divisible dim -> replicated
-    assert fsdp.fsdp_leaf_spec((63, 65), "data", 8, min_size=1) == P()
+    assert rules.fsdp_leaf_spec((63, 65), "data", 8, min_size=1) == P()
     # scalars
-    assert fsdp.fsdp_leaf_spec((), "data", 8) == P()
+    assert rules.fsdp_leaf_spec((), "data", 8) == P()
 
 
 def test_fsdp_matches_dp(setup):
-    mesh, params, loss_fn, batch = setup
+    mesh, model, params, loss_fn, batch = setup
     opt = optim.momentum(0.05, 0.9)
     b = sharding.shard_batch(batch, mesh)
 
@@ -72,15 +74,15 @@ def test_fsdp_matches_dp(setup):
     dp_state = TrainState.create(sharding.replicate(params, mesh), opt)
     dp_step = make_train_step(loss_fn, opt, mesh, donate=False)
 
-    # FSDP: same initial params, sharded state
-    fs_state = TrainState.create(params, opt)
-    specs = fsdp_specs(fs_state, mesh, min_size=64)  # small model: force sharding
-    fs_state = fsdp.shard_state(fs_state, specs, mesh)
-    fs_step = make_train_step_fsdp(loss_fn, opt, mesh, specs, donate=False)
+    # FSDP: same initial params, sharded state (min_size=64: a small
+    # model, force sharding)
+    fs_mesh, fs_state, fs_step = layout_step(
+        model, params, opt, loss_fn, "fsdp")
+    fs_b = sharding.shard_batch(batch, fs_mesh, axis="fsdp")
 
     for _ in range(3):
         dp_state, dp_m = dp_step(dp_state, b)
-        fs_state, fs_m = fs_step(fs_state, b)
+        fs_state, fs_m = fs_step(fs_state, fs_b)
         np.testing.assert_allclose(
             np.asarray(dp_m["loss"]), np.asarray(fs_m["loss"]), rtol=1e-6
         )
@@ -96,20 +98,16 @@ def test_fsdp_matches_dp(setup):
 
 
 def test_fsdp_shards_memory(setup):
-    mesh, params, loss_fn, batch = setup
+    mesh, model, params, loss_fn, batch = setup
     opt = optim.adam(1e-3)
-    state = TrainState.create(params, opt)
-    specs = fsdp_specs(state, mesh, min_size=64)
-    state = fsdp.shard_state(state, specs, mesh)
+    fs_mesh, state, _ = layout_step(model, params, opt, loss_fn, "fsdp")
 
-    n = mesh.shape["data"]
+    n = fs_mesh.shape["fsdp"]
     sharded = 0
-    for spec, leaf in zip(
-        jax.tree.leaves(specs.params, is_leaf=lambda x: isinstance(x, P)),
-        jax.tree.leaves(state.params),
-    ):
+    for leaf in jax.tree.leaves(state.params):
+        spec = leaf.sharding.spec
         shard = leaf.addressable_shards[0].data
-        if spec != P():
+        if any(spec):  # the overlay pads a whole leaf's spec to its rank
             assert shard.size == leaf.size // n, (spec, leaf.shape, shard.shape)
             sharded += 1
         else:
@@ -121,24 +119,22 @@ def test_fsdp_shards_memory(setup):
 
 
 def test_fsdp_through_trainer():
-    """The user path: prepare_training(spmd='fsdp') → train → loss falls,
+    """The user path: prepare_training(layout='fsdp') → train → loss falls,
     and the trainer's state really is sharded."""
-    import fluxdistributed_tpu.mesh as mesh_lib
     from fluxdistributed_tpu.data import SyntheticDataset
     from fluxdistributed_tpu.train import prepare_training, train
     from fluxdistributed_tpu.train.logging import NullLogger
 
-    mesh = mesh_lib.data_mesh(8)
     ds = SyntheticDataset(nsamples=64, nclasses=4, shape=(8, 8, 3))
     task = prepare_training(
         SimpleCNN(num_classes=4), ds, optim.momentum(0.1, 0.9),
-        mesh=mesh, batch_size=16, cycles=30, spmd="fsdp",
+        batch_size=16, cycles=30, layout="fsdp",
     )
-    n = mesh.shape["data"]
+    n = task.mesh.shape["fsdp"]
     assert any(
         l.addressable_shards[0].data.size == l.size // n
         for l in jax.tree.leaves(task.state.params)
-    ), "no trainer param leaf is sharded under spmd='fsdp'"
+    ), "no trainer param leaf is sharded under layout='fsdp'"
     losses = []
     orig = task.step_fn
 
@@ -158,18 +154,16 @@ def test_fsdp_checkpoint_roundtrip(setup, tmp_path):
     silent gather-to-replicated on resume)."""
     from fluxdistributed_tpu.train.checkpoint import load_checkpoint, save_checkpoint
 
-    mesh, params, loss_fn, batch = setup
+    mesh, model, params, loss_fn, batch = setup
     opt = optim.momentum(0.05, 0.9)
-    state = TrainState.create(params, opt)
-    specs = fsdp_specs(state, mesh, min_size=64)
-    state = fsdp.shard_state(state, specs, mesh)
-    step = make_train_step_fsdp(loss_fn, opt, mesh, specs, donate=False)
-    state, _ = step(state, sharding.shard_batch(batch, mesh))
+    mesh, state, step = layout_step(model, params, opt, loss_fn, "fsdp")
+    b = sharding.shard_batch(batch, mesh, axis="fsdp")
+    state, _ = step(state, b)
 
     save_checkpoint(state, str(tmp_path), 1)
     restored = load_checkpoint(str(tmp_path), state, mesh=mesh)
 
-    n = mesh.shape["data"]
+    n = mesh.shape["fsdp"]
     resharded = 0
     for old, new in zip(jax.tree.leaves(state.params), jax.tree.leaves(restored.params)):
         np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
@@ -178,22 +172,15 @@ def test_fsdp_checkpoint_roundtrip(setup, tmp_path):
             resharded += 1
     assert resharded > 0
     # and the restored state steps
-    st2, m = step(restored, sharding.shard_batch(batch, mesh))
+    st2, m = step(restored, b)
     assert np.isfinite(np.asarray(m["loss"]))
 
 
 def test_hybrid_fsdp_tp_lm():
-    """2-D sharding on (data=2, model=4): TP rules + FSDP on the leftover
+    """2-D sharding on (fsdp=2, model=4): TP rules + FSDP on the leftover
     dim → per-device shards ~1/8 of large leaves, numerics match DP."""
     import fluxdistributed_tpu.mesh as mesh_lib
     from fluxdistributed_tpu.models import lm_loss_fn, lm_tiny
-    from fluxdistributed_tpu.parallel import (
-        hybrid_fsdp_tp_specs,
-        lm_tp_rules,
-        make_train_step,
-        make_train_step_tp,
-    )
-    from fluxdistributed_tpu.parallel.tp import shard_state as tp_shard_state
 
     vocab = 32
     model = lm_tiny(vocab=vocab, dtype=jnp.float32)
@@ -202,18 +189,15 @@ def test_hybrid_fsdp_tp_lm():
     opt = optim.momentum(0.05, 0.9)
     loss_fn = lm_loss_fn(model)
 
-    mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
-    specs = hybrid_fsdp_tp_specs(params, mesh, lm_tp_rules(), min_size=64)
-    # embedding: vocab over model (TP) + dim over data (FSDP)
-    assert specs["embed"]["embedding"] == P("model", "data")
-    qkv = specs["block0"]["CausalSelfAttention_0"]["qkv"]["kernel"]
-    assert qkv == P("data", None, "model", None)
-
-    hy_state = tp_shard_state(TrainState.create(params, opt), mesh, specs)
+    mesh, hy_state, hy_step = layout_step(
+        model, params, opt, loss_fn, Layout("fsdp_tp", fsdp=2, tp=4))
+    # embedding: vocab over model (TP) + dim over fsdp (FSDP)
+    assert hy_state.params["embed"]["embedding"].sharding.spec == P(
+        "model", "fsdp")
     qkv_leaf = hy_state.params["block0"]["CausalSelfAttention_0"]["qkv"]["kernel"]
+    assert qkv_leaf.sharding.spec == P("fsdp", None, "model", None)
     assert qkv_leaf.addressable_shards[0].data.size == qkv_leaf.size // 8
-    hy_step = make_train_step_tp(loss_fn, opt, mesh, specs, hy_state, donate=False)
-    b_hy = sharding.shard_batch({"tokens": toks}, mesh, axis="data")
+    b_hy = sharding.shard_batch({"tokens": toks}, mesh, axis="fsdp")
 
     dp_mesh = mesh_lib.data_mesh(8)
     dp_state = TrainState.create(sharding.replicate(params, dp_mesh), opt)
@@ -234,22 +218,21 @@ def test_hybrid_fsdp_tp_lm():
 @pytest.mark.slow
 def test_fsdp_tp_through_trainer():
     """The user path for the hybrid 2-D recipe: prepare_training(
-    spmd='fsdp_tp') shards state over BOTH axes and training learns."""
-    import fluxdistributed_tpu.mesh as mesh_lib
+    layout=fsdp x tp) shards state over BOTH axes and training learns."""
     from fluxdistributed_tpu.data import SyntheticTextDataset
     from fluxdistributed_tpu.models import lm_loss_fn, lm_tiny
     from fluxdistributed_tpu.train import prepare_training, train
     from fluxdistributed_tpu.train.logging import NullLogger
 
-    mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
     model = lm_tiny(vocab=32, dtype=jnp.float32)
     ds = SyntheticTextDataset(vocab=32, seqlen=32, peak=0.9)
     task = prepare_training(
-        model, ds, optim.adam(3e-3), mesh=mesh, batch_size=32, cycles=30,
-        loss_fn=lm_loss_fn(model), topk=(), spmd="fsdp_tp",
+        model, ds, optim.adam(3e-3), batch_size=32, cycles=30,
+        loss_fn=lm_loss_fn(model), topk=(),
+        layout=Layout("fsdp_tp", fsdp=2, tp=4),
     )
     emb = task.state.params["embed"]["embedding"]
-    assert emb.sharding.spec == P("model", "data")
+    assert emb.sharding.spec == P("model", "fsdp")
     assert emb.addressable_shards[0].data.size == emb.size // 8
     losses = []
     orig = task.step_fn
@@ -265,19 +248,19 @@ def test_fsdp_tp_through_trainer():
 
 
 def test_fsdp_eval_and_accum(setup):
-    mesh, params, loss_fn, batch = setup
+    mesh, model, params, loss_fn, batch = setup
     opt = optim.momentum(0.05, 0.9)
-    b = sharding.shard_batch(batch, mesh)
-    state = TrainState.create(params, opt)
-    specs = fsdp_specs(state, mesh, min_size=64)
-    state = fsdp.shard_state(state, specs, mesh)
-
     # grad accumulation composes with FSDP (scan over microbatches)
-    step = make_train_step_fsdp(loss_fn, opt, mesh, specs, donate=False, accum_steps=2)
+    mesh, state, step = layout_step(
+        model, params, opt, loss_fn, "fsdp", accum_steps=2)
+    b = sharding.shard_batch(batch, mesh, axis="fsdp")
     state2, m = step(state, b)
     assert np.isfinite(np.asarray(m["loss"]))
 
-    ev = make_eval_step_fsdp(loss_fn, mesh, specs, topk=(1,))
+    # eval takes the sharded state directly (no gather, no resharding)
+    ev = make_eval_step(
+        loss_fn, mesh, axis=("data", "fsdp"), topk=(1,),
+        state_shardings=jax.tree.map(lambda x: x.sharding, state))
     loss, metrics = ev(state2, b)
     assert np.isfinite(np.asarray(loss))
     assert 0.0 <= float(metrics["top1"]) <= 1.0

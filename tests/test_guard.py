@@ -216,7 +216,19 @@ def test_prepare_guard_validation():
     with pytest.raises(ValueError, match="loss-only"):
         prepare_training(MLP(features=(4,)), ds, optim.adam(1e-3),
                          batch_size=8, cycles=2, topk=(),
-                         guard=True, spmd="fsdp")
+                         guard=True, spmd="shard_map")
+
+
+def test_guard_sentinel_under_fsdp_layout():
+    """A layout rides dp.make_train_step, so it takes the sentinel."""
+    ds = SyntheticDataset(nsamples=16, nclasses=4, shape=(8, 8, 3))
+    task = prepare_training(MLP(features=(4,)), ds, optim.adam(1e-3),
+                            batch_size=8, cycles=2, topk=(),
+                            guard=True, layout="fsdp")
+    _, m = task.step_fn(task.state, next(iter(task.loader)))
+    poisoned_loss, gnorm = np.asarray(m["guard"])
+    assert poisoned_loss == np.float32(m["loss"])
+    assert np.isfinite(gnorm) and gnorm > 0
 
 
 # ---------------------------------------------------------------------------

@@ -137,7 +137,7 @@ def test_driver_cli_fake_cluster_fsdp(tmp_path):
         "--batch-size", "8", "--cycles", "3",
         "--opt", "momentum", "--lr", "0.05",
         "--print-every", "1", "--eval-every", "0",
-        "--spmd", "fsdp",
+        "--layout", "fsdp",
         "--checkpoint-dir", ck, "--checkpoint-every", "2",
         "--coordinator", f"localhost:{port}",
         "--num-processes", "2", "--platform", "cpu", "--local-devices", "2",

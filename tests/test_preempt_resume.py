@@ -48,7 +48,7 @@ def _clean_plan():
     faults.clear_plan()
 
 
-def make_task(mesh=None, cycles=CYCLES, zero1=False, spmd="jit"):
+def make_task(mesh=None, cycles=CYCLES, zero1=False, layout=None):
     # MLP (10, 10): deliberately non-multiple-of-8 leaf sizes so the
     # ZeRO-1 flat pad CHANGES between 8- and 4-device meshes (bias 10
     # pads to 16 vs 12) — the elastic re-split is actually exercised
@@ -56,7 +56,7 @@ def make_task(mesh=None, cycles=CYCLES, zero1=False, spmd="jit"):
     return prepare_training(
         MLP(features=(10, 10)), ds, optim.adam(1e-3),
         mesh=mesh, batch_size=8, cycles=cycles, topk=(),
-        zero1=zero1, spmd=spmd)
+        zero1=zero1, layout=layout)
 
 
 def record_losses(task):
@@ -306,12 +306,18 @@ def test_driver_elastic_resume_different_device_count(tmp_path):
 
 @pytest.mark.slow
 def test_elastic_resume_fsdp(tmp_path):
-    """fsdp state (per-leaf data-axis shardings, full global shapes)
+    """fsdp state (per-leaf fsdp-axis shardings, full global shapes)
     rides the same elastic restore: shapes need no adaptation, only the
     re-commit to the new mesh's shardings."""
-    baseline = run_uninterrupted(spmd="fsdp")
-    head = run_preempted(tmp_path, spmd="fsdp")
-    tail, _ = run_resumed(tmp_path, spmd="fsdp", mesh=data_mesh(4))
+    import jax
+
+    from fluxdistributed_tpu.parallel import Layout
+
+    baseline = run_uninterrupted(layout="fsdp")
+    head = run_preempted(tmp_path, layout="fsdp")
+    tail, _ = run_resumed(
+        tmp_path, layout="fsdp",
+        mesh=Layout("fsdp", fsdp=4).build_mesh(devs=jax.devices()[:4]))
     np.testing.assert_allclose(
         np.asarray(head + tail), np.asarray(baseline),
         rtol=1e-4, atol=1e-6)

@@ -79,22 +79,23 @@ class _CaptureLogger:
 
 
 def test_tp_sharded_resume(tmp_path):
-    """spmd='tp' checkpoints save model-sharded and restore model-sharded
-    (the abstract-target path), then training continues."""
+    """A tp layout's checkpoints save model-sharded and restore
+    model-sharded (the abstract-target path), then training continues."""
     from jax.sharding import PartitionSpec as P
 
     from fluxdistributed_tpu.data import SyntheticTextDataset
     from fluxdistributed_tpu.models import lm_loss_fn, lm_tiny
+    from fluxdistributed_tpu.parallel import Layout
     from fluxdistributed_tpu.train import restore_training
 
-    mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
     model = lm_tiny(vocab=32, dtype=np.float32)
     ds = SyntheticTextDataset(vocab=32, seqlen=32)
 
     def mk(cycles):
         return prepare_training(
-            model, ds, optim.adam(1e-3), mesh=mesh, batch_size=16,
-            cycles=cycles, loss_fn=lm_loss_fn(model), topk=(), spmd="tp",
+            model, ds, optim.adam(1e-3), batch_size=16, cycles=cycles,
+            loss_fn=lm_loss_fn(model), topk=(),
+            layout=Layout("tp", dp=2, tp=4),
         )
 
     task = mk(4)

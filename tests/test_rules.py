@@ -2,11 +2,12 @@
 
 The headline contracts:
 
-* PARITY — the committed rule tables reproduce every hand-built spec
-  builder leaf-for-leaf (dp/zero1 = replicated, fsdp = the shape walk,
-  lm/vit tp = the Megatron callables, fsdp x tp = the hybrid special
-  case), so the refactor cannot move a single leaf's placement — the
-  old AOT keys and the memory baseline survive.
+* PINNED PLACEMENT — each committed rule table gives exactly the spec
+  tree written out below for its probe model (dp/zero1 = replicated,
+  fsdp = the one-rule shape walk, lm/vit tp = the Megatron tables,
+  fsdp x tp = table + overlay), so an edit to a table that moves a
+  single leaf's placement is seen — the AOT keys and the memory
+  baseline depend on it.
 * FALLBACK HONESTY — unmatched leaves replicate, but dead rules and
   large silently-replicating leaves are reported (and raise under
   strict=True).
@@ -15,7 +16,7 @@ The headline contracts:
   named.
 * END-TO-END — a ~10-line rule list shards a model through
   prepare_training with NO hand-written spec code, at loss parity
-  with the hand-built variant.
+  with the unsharded step.
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from fluxdistributed_tpu import mesh as mesh_lib, optim
-from fluxdistributed_tpu.parallel import dp, fsdp, rules, tp
+from fluxdistributed_tpu.parallel import dp, rules
 
 
 def _spec_leaves(tree):
@@ -37,11 +38,13 @@ def _spec_leaves(tree):
         tree, is_leaf=lambda x: x is None or isinstance(x, P))[0]
 
 
-def assert_spec_trees_equal(a, b, ctx=""):
-    fa, fb = _spec_leaves(a), _spec_leaves(b)
-    assert len(fa) == len(fb), (ctx, len(fa), len(fb))
-    for (pa, sa), (_, sb) in zip(fa, fb):
-        assert sa == sb, (ctx, jax.tree_util.keystr(pa), sa, sb)
+def assert_specs(tree, expected, ctx=""):
+    """``tree`` holds exactly the leaves of ``expected`` ('/'-joined
+    path -> PartitionSpec), each with exactly that spec."""
+    got = {rules._leaf_path(kp): s for kp, s in _spec_leaves(tree)}
+    assert sorted(got) == sorted(expected), (ctx, sorted(got))
+    for path, spec in expected.items():
+        assert got[path] == spec, (ctx, path, got[path], spec)
 
 
 def _lm_params(**kw):
@@ -80,19 +83,124 @@ def test_dp_table_is_replicated_everywhere():
         assert s == P(), jax.tree_util.keystr(pth)
 
 
+# What each table must give, as data: '/'-joined leaf path -> spec.
+# M/D are the model and data axes of mesh24 (data=2 x model=4).
+M, D = mesh_lib.MODEL_AXIS, mesh_lib.DATA_AXIS
+
+
+def _blocks(per_block, rest, depth=2):
+    out = {f"block{i}/{k}": v for i in range(depth)
+           for k, v in per_block.items()}
+    out.update(rest)
+    return out
+
+
+_LN = {f"LayerNorm_{i}/{k}": P() for i in (0, 1) for k in ("bias", "scale")}
+_LM_ATTN = {
+    "CausalSelfAttention_0/qkv/kernel": P(None, None, M, None),
+    "CausalSelfAttention_0/qkv/bias": P(None, M, None),
+    "CausalSelfAttention_0/out/kernel": P(M, None, None),
+    "CausalSelfAttention_0/out/bias": P(),
+}
+_LM_GQA_ATTN = {
+    "CausalSelfAttention_0/q/kernel": P(None, M, None),
+    "CausalSelfAttention_0/q/bias": P(M, None),
+    "CausalSelfAttention_0/kv/kernel": P(None, None, M, None),
+    "CausalSelfAttention_0/kv/bias": P(None, M, None),
+    "CausalSelfAttention_0/out/kernel": P(M, None, None),
+    "CausalSelfAttention_0/out/bias": P(),
+}
+_GELU = {
+    "Dense_0/kernel": P(None, M), "Dense_0/bias": P(M),
+    "Dense_1/kernel": P(M, None), "Dense_1/bias": P(),
+}
+_SWIGLU = {
+    "gate/kernel": P(None, M), "up/kernel": P(None, M),
+    "down/kernel": P(M, None),
+}
+_LM_OUTER = {"embed/embedding": P(M, None),
+             "final_ln/bias": P(), "final_ln/scale": P()}
+
+LM_TP_EXPECTED = {
+    "plain": ({}, _blocks({**_LM_ATTN, **_GELU, **_LN}, _LM_OUTER)),
+    "gqa": ({"num_kv_heads": 2},
+            _blocks({**_LM_GQA_ATTN, **_GELU, **_LN}, _LM_OUTER)),
+    "swiglu": ({"mlp": "swiglu"},
+               _blocks({**_LM_ATTN, **_SWIGLU, **_LN}, _LM_OUTER)),
+    "untied": ({"tie_embeddings": False},
+               _blocks({**_LM_ATTN, **_GELU, **_LN},
+                       {**_LM_OUTER, "head/kernel": P(None, M),
+                        "head/bias": P(M)})),
+}
+
+VIT_TP_EXPECTED = _blocks(
+    {"MultiHeadAttention_0/qkv/kernel": P(None, None, M, None),
+     "MultiHeadAttention_0/qkv/bias": P(None, M, None),
+     "MultiHeadAttention_0/out/kernel": P(M, None, None),
+     "MultiHeadAttention_0/out/bias": P(),
+     "MlpBlock_0/Dense_0/kernel": P(None, M),
+     "MlpBlock_0/Dense_0/bias": P(M),
+     "MlpBlock_0/Dense_1/kernel": P(M, None),
+     "MlpBlock_0/Dense_1/bias": P(),
+     **_LN},
+    {k: P() for k in (
+        "final_norm/bias", "final_norm/scale", "head/bias", "head/kernel",
+        "patch_embed/bias", "patch_embed/kernel", "pos_embed")})
+
+
+def _cnn_state_expected(conv, dense):
+    """SimpleCNN + adam: each param's spec, its two moments following
+    it, the step replicated."""
+    params = {"Conv_0/kernel": conv, "Conv_0/bias": P(),
+              "Conv_1/kernel": conv, "Conv_1/bias": P(),
+              "Dense_0/kernel": dense, "Dense_0/bias": P()}
+    out = {f"params/{k}": v for k, v in params.items()}
+    out.update({f"opt_state/{k}/{i}": v
+                for k, v in params.items() for i in (0, 1)})
+    out["step"] = P()
+    return out
+
+
+# the largest kernel here has 3*3*8*16 = 1,152 elements: under the
+# default threshold every leaf stays whole, at 64 the kernels split
+FSDP_STATE_EXPECTED = {
+    rules.FALLBACK_MIN_SIZE: _cnn_state_expected(P(), P()),
+    64: _cnn_state_expected(P(None, None, None, D), P(D, None)),
+}
+
+# the overlay keeps the table's entries and, at 64, gives the data axis
+# the largest dim the table left whole; a leaf the overlay leaves alone
+# keeps its table spec padded to its rank (P(None), not P())
+_R1 = P(None)
+_LN_R1 = {k: _R1 for k in _LN}
+FSDP_TP_EXPECTED = {
+    rules.FALLBACK_MIN_SIZE: _blocks(
+        {**_LM_ATTN, **_GELU, **_LN_R1,
+         "CausalSelfAttention_0/out/bias": _R1, "Dense_1/bias": _R1},
+        {"embed/embedding": P(M, None),
+         "final_ln/bias": _R1, "final_ln/scale": _R1}),
+    64: _blocks(
+        {"CausalSelfAttention_0/qkv/kernel": P(D, None, M, None),
+         "CausalSelfAttention_0/qkv/bias": P(None, M, None),
+         "CausalSelfAttention_0/out/kernel": P(M, None, D),
+         "CausalSelfAttention_0/out/bias": _R1,
+         "Dense_0/kernel": P(D, M), "Dense_0/bias": P(M),
+         "Dense_1/kernel": P(M, D), "Dense_1/bias": _R1,
+         **_LN_R1},
+        {"embed/embedding": P(M, D),
+         "final_ln/bias": _R1, "final_ln/scale": _R1}),
+}
+
+
 @pytest.mark.parametrize("variant", ["plain", "gqa", "swiglu", "untied"])
-def test_lm_tp_table_matches_hand_built(variant, mesh24):
-    kw = {"plain": {}, "gqa": {"num_kv_heads": 2},
-          "swiglu": {"mlp": "swiglu"},
-          "untied": {"tie_embeddings": False}}[variant]
-    params = _lm_params(**kw)
-    hand = tp.param_specs(params, tp.lm_tp_rules())
+def test_lm_tp_table_gives_pinned_specs(variant, mesh24):
+    kw, expected = LM_TP_EXPECTED[variant]
     table = rules.match_partition_rules(
-        rules.lm_tp_rules_table(), params, mesh=mesh24)
-    assert_spec_trees_equal(hand, table, variant)
+        rules.lm_tp_rules_table(), _lm_params(**kw), mesh=mesh24)
+    assert_specs(table, expected, variant)
 
 
-def test_vit_tp_table_matches_hand_built(mesh24):
+def test_vit_tp_table_gives_pinned_specs(mesh24):
     from fluxdistributed_tpu.models.vit import ViT
 
     model = ViT(patch=4, depth=2, dim=16, num_heads=4, mlp_dim=32,
@@ -100,38 +208,33 @@ def test_vit_tp_table_matches_hand_built(mesh24):
     params = jax.eval_shape(
         lambda s: model.init(jax.random.PRNGKey(0), s, train=False),
         jax.ShapeDtypeStruct((1, 8, 8, 3), "float32"))["params"]
-    hand = tp.param_specs(params, tp.vit_tp_rules())
     table = rules.match_partition_rules(
         rules.vit_tp_rules_table(), params, mesh=mesh24)
-    assert_spec_trees_equal(hand, table, "vit")
+    assert_specs(table, VIT_TP_EXPECTED, "vit")
 
 
-def test_fsdp_table_matches_hand_built_state_tree():
-    """ONE ShardLargest rule == the whole fsdp_specs shape walk, for
-    the FULL TrainState (params + Adam moments broadcast from their
-    param; model_state/step replicated)."""
+@pytest.mark.parametrize("min_size", sorted(FSDP_STATE_EXPECTED))
+def test_fsdp_table_gives_pinned_state_tree(min_size):
+    """ONE ShardLargest rule places the FULL TrainState (params + Adam
+    moments broadcast from their param; model_state/step replicated)."""
     state = _cnn_state()
     mesh = mesh_lib.data_mesh(8)
-    hand = fsdp.fsdp_specs(state, mesh)
     p_specs = rules.match_partition_rules(
-        rules.fsdp_rules(axis=mesh_lib.DATA_AXIS,
-                         min_size=fsdp.MIN_SHARD_ELEMS),
+        rules.fsdp_rules(axis=D, min_size=min_size),
         state.params, mesh=mesh)
     derived = rules.train_state_specs(state, p_specs)
-    assert_spec_trees_equal(hand, derived, "fsdp")
+    assert_specs(derived, FSDP_STATE_EXPECTED[min_size], "fsdp")
 
 
-def test_fsdp_overlay_matches_hybrid_special_case(mesh24):
-    """rules table + with_fsdp == hybrid_fsdp_tp_specs, leaf-for-leaf
-    — the 2-D composition, derived instead of special-cased."""
+@pytest.mark.parametrize("min_size", sorted(FSDP_TP_EXPECTED))
+def test_fsdp_overlay_on_tp_table_gives_pinned_specs(min_size, mesh24):
+    """rules table + with_fsdp: the 2-D composition, leaf for leaf."""
     params = _lm_params()
-    hand = fsdp.hybrid_fsdp_tp_specs(params, mesh24, tp.lm_tp_rules())
     base = rules.match_partition_rules(
         rules.lm_tp_rules_table(), params, mesh=mesh24)
-    derived = rules.with_fsdp(base, params, mesh24,
-                              axis=mesh_lib.DATA_AXIS,
-                              min_size=fsdp.MIN_SHARD_ELEMS)
-    assert_spec_trees_equal(hand, derived, "hybrid")
+    derived = rules.with_fsdp(base, params, mesh24, axis=D,
+                              min_size=min_size)
+    assert_specs(derived, FSDP_TP_EXPECTED[min_size], "fsdp_tp")
 
 
 # ------------------------------------------------------- matcher semantics
@@ -203,7 +306,7 @@ def test_bad_rule_value_type():
 def test_ten_line_table_trains_at_loss_parity():
     """The acceptance bar: a ~10-line rule list shards a model through
     prepare_training with NO hand-written spec code, at loss parity
-    with the hand-built fsdp variant (same math, different axis name —
+    with the unsharded step (same math, different placement —
     allclose, not bitwise: GSPMD may order reductions differently)."""
     from fluxdistributed_tpu.data.synthetic import SyntheticDataset
     from fluxdistributed_tpu.models.simple import SimpleCNN
@@ -222,10 +325,10 @@ def test_ten_line_table_trains_at_loss_parity():
             out.append(float(metrics["loss"]))
         return out
 
-    hand = losses(spmd="fsdp")
+    whole = losses(spmd="jit")
     derived = losses(layout="fsdp")  # the ONE-rule fsdp table
-    assert np.allclose(hand, derived, rtol=2e-4, atol=2e-5), (
-        hand, derived)
+    assert np.allclose(whole, derived, rtol=2e-4, atol=2e-5), (
+        whole, derived)
 
 
 def test_layout_conflicts_rejected():
@@ -237,13 +340,29 @@ def test_layout_conflicts_rejected():
     ds = SyntheticDataset(nsamples=64, nclasses=4, shape=(8, 8, 3))
     with pytest.raises(ValueError, match="cannot combine with spmd"):
         prepare_training(model, ds, optim.adam(1e-3), layout="fsdp",
-                         spmd="fsdp", batch_size=16, cycles=1)
+                         spmd="shard_map", batch_size=16, cycles=1)
     with pytest.raises(ValueError, match="ZeRO-3 placement subsumes"):
         prepare_training(model, ds, optim.adam(1e-3), layout="fsdp",
                          zero1=True, batch_size=16, cycles=1)
     with pytest.raises(ValueError, match="divisible by the"):
         prepare_training(model, ds, optim.adam(1e-3), layout="dp_fsdp",
                          batch_size=12, cycles=1)
+
+
+@pytest.mark.parametrize("name, spelling", [
+    ("fsdp", 'layout="fsdp"'),
+    ("tp", 'layout=Layout("tp", dp=D, tp=K)'),
+    ("fsdp_tp", 'layout="fsdp_tp"'),
+])
+def test_retired_spmd_names_point_to_layout(name, spelling):
+    """The three spmd= names that were a second spelling of a layout
+    are refused, and the refusal says the layout= that replaces them."""
+    from fluxdistributed_tpu.models.simple import SimpleCNN
+    from fluxdistributed_tpu.train.trainer import prepare_training
+
+    with pytest.raises(ValueError, match=re.escape(spelling)):
+        prepare_training(SimpleCNN(num_classes=4), None, optim.adam(1e-3),
+                         spmd=name)
 
 
 def test_layout_over_device_subset_mesh():

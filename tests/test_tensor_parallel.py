@@ -2,8 +2,8 @@
 
 The reference's invariant (distributed == single-device,
 test/single_device.jl:115-168) applied to the model axis: a ViT trained
-with Megatron-sharded params on a (data=2, model=4) mesh must produce
-the same losses and parameters as the plain replicated DP step.
+with Megatron-sharded params under ``Layout("tp", dp=2, tp=4)`` must
+produce the same losses and parameters as the plain replicated DP step.
 """
 
 import jax
@@ -15,16 +15,13 @@ import fluxdistributed_tpu as fd
 from fluxdistributed_tpu import optim, sharding
 from fluxdistributed_tpu.mesh import make_mesh
 from fluxdistributed_tpu.models import vit_tiny
-from fluxdistributed_tpu.parallel import TrainState, make_train_step
+from fluxdistributed_tpu.parallel import Layout, TrainState, make_train_step, rules
 from fluxdistributed_tpu.parallel.dp import flax_loss_fn
-from fluxdistributed_tpu.parallel.tp import (
-    broadcast_prefix,
-    make_train_step_tp,
-    param_specs,
-    shard_state,
-    vit_tp_rules,
-)
 from jax.sharding import PartitionSpec as P
+
+from _layout_step import layout_step
+
+TP = Layout("tp", dp=2, tp=4)
 
 
 @pytest.fixture(scope="module")
@@ -42,7 +39,7 @@ def setup():
 
 def test_specs_cover_attention_and_mlp(setup):
     _, _, _, _, params, _ = setup
-    specs = param_specs(params, vit_tp_rules())
+    specs = rules.match_partition_rules(rules.vit_tp_rules_table(), params)
     flat = {
         "/".join(str(k.key) for k in kp): s
         for kp, s in jax.tree_util.tree_flatten_with_path(
@@ -60,8 +57,8 @@ def test_broadcast_prefix_handles_adam_tuples(setup):
     _, _, _, _, params, _ = setup
     opt = optim.adam(1e-3)
     st = opt.init(params)
-    specs = param_specs(params, vit_tp_rules())
-    st_specs = broadcast_prefix(specs, st)
+    specs = rules.match_partition_rules(rules.vit_tp_rules_table(), params)
+    st_specs = rules.broadcast_prefix(specs, st)
     # The qkv kernel's (m, v) tuple must both carry the qkv spec.
     got = st_specs["block0"]["MultiHeadAttention_0"]["qkv"]["kernel"]
     assert got == (P(None, None, "model", None), P(None, None, "model", None))
@@ -79,11 +76,10 @@ def test_tp_matches_dp(setup):
     dp_state, m_dp2 = dp_step(dp_state, b)
 
     # TP: same initial params, Megatron shardings.
-    specs = param_specs(params, vit_tp_rules())
-    tp_state = shard_state(TrainState.create(params, opt), mesh, specs)
-    tp_step = make_train_step_tp(loss_fn, opt, mesh, specs, tp_state, donate=False)
-    tp_state, m_tp = tp_step(tp_state, b)
-    tp_state, m_tp2 = tp_step(tp_state, b)
+    tp_mesh, tp_state, tp_step = layout_step(model, params, opt, loss_fn, TP)
+    tp_b = sharding.shard_batch(batch, tp_mesh, axis=TP.batch_axes)
+    tp_state, m_tp = tp_step(tp_state, tp_b)
+    tp_state, m_tp2 = tp_step(tp_state, tp_b)
 
     np.testing.assert_allclose(float(m_tp["loss"]), float(m_dp["loss"]), rtol=1e-5)
     np.testing.assert_allclose(float(m_tp2["loss"]), float(m_dp2["loss"]), rtol=1e-5)
@@ -92,24 +88,26 @@ def test_tp_matches_dp(setup):
 
 
 def test_donated_state_does_not_delete_source_params(setup):
-    """replicate/shard_state must copy: donating the state into the
-    compiled step would otherwise delete the caller's original arrays
+    """replicate/layout.shard_state must copy: donating the state into
+    the compiled step would otherwise delete the caller's original arrays
     (device_put is zero-copy on shared devices)."""
     mesh, model, loss_fn, opt, params, batch = setup
     state = TrainState.create(sharding.replicate(params, mesh), opt)
     step = make_train_step(loss_fn, opt, mesh, donate=True)
     b = sharding.shard_batch(batch, mesh)
     state, _ = step(state, b)  # donates the pre-step state buffers
-    # Source params must still be alive and usable.
-    specs = param_specs(params, vit_tp_rules())
-    tp_state = shard_state(TrainState.create(params, opt), mesh, specs)
+    # Source params must still be alive and usable, also after a
+    # layout-placed state made from them was donated.
+    tp_mesh, tp_state, tp_step = layout_step(
+        model, params, opt, loss_fn, TP, donate=True)
+    tp_step(tp_state, sharding.shard_batch(batch, tp_mesh, axis=TP.batch_axes))
+    _, tp_state, _ = layout_step(model, params, opt, loss_fn, TP)
     assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(tp_state.params))
 
 
 def test_tp_params_actually_sharded(setup):
     mesh, model, loss_fn, opt, params, batch = setup
-    specs = param_specs(params, vit_tp_rules())
-    tp_state = shard_state(TrainState.create(params, opt), mesh, specs)
+    _, tp_state, _ = layout_step(model, params, opt, loss_fn, TP)
     qkv = tp_state.params["block0"]["MultiHeadAttention_0"]["qkv"]["kernel"]
     assert "model" in qkv.sharding.spec
     # Each device holds 1/4 of the heads.

@@ -23,13 +23,9 @@ from fluxdistributed_tpu import optim, sharding
 from fluxdistributed_tpu.data import SyntheticTextDataset
 from fluxdistributed_tpu.models import lm_loss_fn, lm_tiny
 from fluxdistributed_tpu.models.transformer_lm import next_token_loss, rope
-from fluxdistributed_tpu.parallel import (
-    TrainState,
-    fsdp,
-    fsdp_specs,
-    make_train_step,
-    make_train_step_fsdp,
-)
+from fluxdistributed_tpu.parallel import Layout, TrainState, make_train_step, rules
+
+from _layout_step import layout_step
 
 VOCAB = 32
 
@@ -325,11 +321,9 @@ def test_ulysses_attention_lm_matches_dense():
 
 
 def test_lm_tensor_parallel_matches_dp():
-    """Megatron-sharded LM over a (data=2, model=4) mesh: same initial
-    params, same batch → same loss/params trajectory as replicated DP."""
+    """Megatron-sharded LM under dp=2 x tp=4: same initial params, same
+    batch → same loss/params trajectory as replicated DP."""
     import fluxdistributed_tpu.mesh as mesh_lib
-    from fluxdistributed_tpu.parallel import lm_tp_rules, make_train_step_tp
-    from fluxdistributed_tpu.parallel.tp import param_specs, shard_state
 
     model = lm_tiny(vocab=VOCAB, dtype=jnp.float32)  # heads=4, mlp=512, vocab 32
     toks = np.random.default_rng(7).integers(0, VOCAB, (16, 24)).astype(np.int32)
@@ -342,13 +336,11 @@ def test_lm_tensor_parallel_matches_dp():
     dp_step = make_train_step(loss_fn, opt, dp_mesh, donate=False)
     b_dp = sharding.shard_batch({"tokens": toks}, dp_mesh)
 
-    tp_mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
-    specs = param_specs(params, lm_tp_rules())
+    tp_mesh, tp_state, tp_step = layout_step(
+        model, params, opt, loss_fn, Layout("tp", dp=2, tp=4))
     # the vocab table must actually be sharded (rule fired)
     from jax.sharding import PartitionSpec as P
-    assert specs["embed"]["embedding"] == P("model", None)
-    tp_state = shard_state(TrainState.create(params, opt), tp_mesh, specs)
-    tp_step = make_train_step_tp(loss_fn, opt, tp_mesh, specs, tp_state, donate=False)
+    assert tp_state.params["embed"]["embedding"].sharding.spec == P("model", None)
     b_tp = sharding.shard_batch({"tokens": toks}, tp_mesh)
 
     for _ in range(3):
@@ -372,13 +364,12 @@ def test_lm_tp_untied_head_specs_and_step():
     and one compiled TP step runs (loss matches an unsharded forward)."""
     import fluxdistributed_tpu.mesh as mesh_lib
     from jax.sharding import PartitionSpec as P
-    from fluxdistributed_tpu.parallel import lm_tp_rules, make_train_step_tp
-    from fluxdistributed_tpu.parallel.tp import param_specs, shard_state
 
     model = lm_tiny(vocab=VOCAB, dtype=jnp.float32, tie_embeddings=False)
     toks = np.random.default_rng(8).integers(0, VOCAB, (8, 16)).astype(np.int32)
     params = model.init(jax.random.PRNGKey(0), toks[:2], train=False)["params"]
-    specs = param_specs(params, lm_tp_rules(shard_vocab=False))
+    specs = rules.match_partition_rules(
+        rules.lm_tp_rules_table(shard_vocab=False), params)
     assert specs["embed"]["embedding"] == P()
     assert specs["head"]["kernel"] == P(None, "model")
     assert specs["head"]["bias"] == P("model")
@@ -386,8 +377,11 @@ def test_lm_tp_untied_head_specs_and_step():
     tp_mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
     opt = optim.momentum(0.05, 0.9)
     loss_fn = lm_loss_fn(model)
-    st = shard_state(TrainState.create(params, opt), tp_mesh, specs)
-    step = make_train_step_tp(loss_fn, opt, tp_mesh, specs, st, donate=False)
+    st = TrainState.create(params, opt)
+    sh = sharding.make_shardings(rules.train_state_specs(st, specs), tp_mesh)
+    st = jax.tree.map(jax.device_put, st, sh)
+    step = make_train_step(loss_fn, opt, tp_mesh, donate=False,
+                           state_shardings=sh)
     st, m = step(st, sharding.shard_batch({"tokens": toks}, tp_mesh))
     ref, _ = loss_fn(params, {}, {"tokens": toks}, True)
     np.testing.assert_allclose(float(m["loss"]), float(ref), rtol=1e-5)
@@ -440,18 +434,16 @@ def test_lm_pipeline_matches_dense():
 
 
 def test_lm_tp_through_trainer():
-    """prepare_training(spmd='tp') on a (data=2, model=4) mesh: state is
-    model-sharded, training runs, eval works, loss falls."""
-    import fluxdistributed_tpu.mesh as mesh_lib
+    """prepare_training(layout=dp=2 x tp=4): state is model-sharded,
+    training runs, eval works, loss falls."""
     from fluxdistributed_tpu.train import prepare_training, train
     from fluxdistributed_tpu.train.logging import NullLogger
 
-    mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
     model = lm_tiny(vocab=VOCAB, dtype=jnp.float32)
     ds = SyntheticTextDataset(vocab=VOCAB, seqlen=32, peak=0.9)
     task = prepare_training(
-        model, ds, optim.adam(3e-3), mesh=mesh, batch_size=32, cycles=30,
-        loss_fn=lm_loss_fn(model), topk=(), spmd="tp",
+        model, ds, optim.adam(3e-3), batch_size=32, cycles=30,
+        loss_fn=lm_loss_fn(model), topk=(), layout=Layout("tp", dp=2, tp=4),
         val_dataset=SyntheticTextDataset(vocab=VOCAB, seqlen=32, peak=0.9),
         val_samples=16,
     )
@@ -471,18 +463,17 @@ def test_lm_tp_through_trainer():
 
 
 def test_trainer_tp_rejects_cnn():
-    import fluxdistributed_tpu.mesh as mesh_lib
     from fluxdistributed_tpu.data import SyntheticDataset
     from fluxdistributed_tpu.models import SimpleCNN
     from fluxdistributed_tpu.train import prepare_training
 
-    mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
-    with pytest.raises(ValueError, match="no TP sharding rules"):
+    with pytest.raises(
+            ValueError, match="SimpleCNN has no tensor-parallel rule table"):
         prepare_training(
             SimpleCNN(num_classes=4),
             SyntheticDataset(nsamples=32, nclasses=4, shape=(8, 8, 3)),
-            optim.momentum(0.1, 0.9), mesh=mesh, batch_size=16, cycles=1,
-            spmd="tp",
+            optim.momentum(0.1, 0.9), batch_size=16, cycles=1,
+            layout=Layout("tp", dp=2, tp=4),
         )
 
 
@@ -493,7 +484,7 @@ def test_moe_lm_trains_on_expert_mesh():
     from fluxdistributed_tpu.mesh import make_mesh
     from fluxdistributed_tpu.models import lm_moe_specs, moe_expert_fn
     from fluxdistributed_tpu.parallel.ep import moe_apply
-    from fluxdistributed_tpu.parallel.tp import state_specs
+    from fluxdistributed_tpu.parallel.rules import train_state_specs as state_specs
     from fluxdistributed_tpu.sharding import make_shardings
 
     mesh = make_mesh({"expert": 8})
@@ -572,7 +563,7 @@ def test_moe_lm_dp_ep_mesh():
     from fluxdistributed_tpu.mesh import make_mesh
     from fluxdistributed_tpu.models import lm_moe_specs, moe_expert_fn
     from fluxdistributed_tpu.parallel.ep import moe_apply
-    from fluxdistributed_tpu.parallel.tp import state_specs
+    from fluxdistributed_tpu.parallel.rules import train_state_specs as state_specs
     from fluxdistributed_tpu.sharding import make_shardings
 
     mesh = make_mesh({"data": 2, "expert": 4})
@@ -630,19 +621,14 @@ def test_lm_pipeline_chunked_stages():
 def test_lm_fsdp_step():
     """FSDP shards the LM state (embedding table is the biggest leaf)
     and the compiled step runs the same lm loss unchanged."""
-    import fluxdistributed_tpu.mesh as mesh_lib
-
-    mesh = mesh_lib.data_mesh(8)
     model = lm_tiny(vocab=64, dtype=jnp.float32)
     toks = np.random.default_rng(3).integers(0, 64, (16, 32)).astype(np.int32)
     params = model.init(jax.random.PRNGKey(0), toks[:2], train=False)["params"]
-    opt = optim.adam(1e-3)
-    state = TrainState.create(params, opt)
-    specs = fsdp_specs(state, mesh)
-    state = fsdp.shard_state(state, specs, mesh)
-    step = make_train_step_fsdp(lm_loss_fn(model), opt, mesh, specs, donate=False)
-    b = sharding.shard_batch({"tokens": toks}, mesh)
-    n = mesh.shape["data"]
+    mesh, state, step = layout_step(
+        model, params, optim.adam(1e-3), lm_loss_fn(model), "fsdp",
+        min_size=None)
+    b = sharding.shard_batch({"tokens": toks}, mesh, axis="fsdp")
+    n = mesh.shape["fsdp"]
     emb = state.params["embed"]["embedding"]
     assert emb.addressable_shards[0].data.size == emb.size // n
     state, m = step(state, b)
@@ -755,12 +741,10 @@ def test_gqa_lm_with_flash_kernel():
 
 def test_gqa_lm_tensor_parallel_matches_dp():
     """GQA LM under TP: the separate q/kv projections must be head-
-    sharded by lm_tp_rules (not silently replicated), and the TP
+    sharded by the lm_tp table (not silently replicated), and the TP
     trajectory must match replicated DP."""
     import fluxdistributed_tpu.mesh as mesh_lib
     from jax.sharding import PartitionSpec as P
-    from fluxdistributed_tpu.parallel import lm_tp_rules, make_train_step_tp
-    from fluxdistributed_tpu.parallel.tp import param_specs, shard_state
 
     # heads=4, kv_heads=2: model axis 2 divides both
     model = lm_tiny(vocab=VOCAB, dtype=jnp.float32, num_kv_heads=2)
@@ -774,13 +758,11 @@ def test_gqa_lm_tensor_parallel_matches_dp():
     dp_step = make_train_step(loss_fn, opt, dp_mesh, donate=False)
     b_dp = sharding.shard_batch({"tokens": toks}, dp_mesh)
 
-    tp_mesh = mesh_lib.make_mesh({"data": 4, "model": 2})
-    specs = param_specs(params, lm_tp_rules())
-    attn = specs["block0"]["CausalSelfAttention_0"]
-    assert attn["q"]["kernel"] == P(None, "model", None)
-    assert attn["kv"]["kernel"] == P(None, None, "model", None)
-    tp_state = shard_state(TrainState.create(params, opt), tp_mesh, specs)
-    tp_step = make_train_step_tp(loss_fn, opt, tp_mesh, specs, tp_state, donate=False)
+    tp_mesh, tp_state, tp_step = layout_step(
+        model, params, opt, loss_fn, Layout("tp", dp=4, tp=2))
+    attn = tp_state.params["block0"]["CausalSelfAttention_0"]
+    assert attn["q"]["kernel"].sharding.spec == P(None, "model", None)
+    assert attn["kv"]["kernel"].sharding.spec == P(None, None, "model", None)
     b_tp = sharding.shard_batch({"tokens": toks}, tp_mesh)
 
     for _ in range(3):
@@ -979,27 +961,21 @@ def test_rmsnorm_swiglu_lm_learns_and_decodes():
 
 
 def test_rmsnorm_swiglu_tp_specs_and_step():
-    """SwiGLU projections must be Megatron-paired under lm_tp_rules
+    """SwiGLU projections must be Megatron-paired under the lm_tp table
     (gate/up column, down row) and the TP step must run."""
-    import fluxdistributed_tpu.mesh as mesh_lib
     from jax.sharding import PartitionSpec as P
-    from fluxdistributed_tpu.parallel import lm_tp_rules, make_train_step_tp
-    from fluxdistributed_tpu.parallel.tp import param_specs, shard_state
 
     model = lm_tiny(vocab=VOCAB, dtype=jnp.float32, norm="rmsnorm", mlp="swiglu")
     toks = np.random.default_rng(37).integers(0, VOCAB, (8, 16)).astype(np.int32)
     params = model.init(jax.random.PRNGKey(0), toks[:2], train=False)["params"]
-    specs = param_specs(params, lm_tp_rules())
-    blk = specs["block0"]
-    assert blk["gate"]["kernel"] == P(None, "model")
-    assert blk["up"]["kernel"] == P(None, "model")
-    assert blk["down"]["kernel"] == P("model", None)
+    tp_mesh, st, step = layout_step(
+        model, params, optim.adam(1e-3), lm_loss_fn(model),
+        Layout("tp", dp=2, tp=4))
+    blk = st.params["block0"]
+    assert blk["gate"]["kernel"].sharding.spec == P(None, "model")
+    assert blk["up"]["kernel"].sharding.spec == P(None, "model")
+    assert blk["down"]["kernel"].sharding.spec == P("model", None)
 
-    tp_mesh = mesh_lib.make_mesh({"data": 2, "model": 4})
-    opt = optim.adam(1e-3)
-    st = shard_state(TrainState.create(params, opt), tp_mesh, specs)
-    step = make_train_step_tp(lm_loss_fn(model), opt, tp_mesh, specs, st,
-                              donate=False)
     st, m = step(st, sharding.shard_batch({"tokens": toks}, tp_mesh))
     assert int(st.step) == 1 and np.isfinite(float(m["loss"]))
 

@@ -220,7 +220,7 @@ def test_zero1_rejects_non_dp_modes():
     with pytest.raises(ValueError, match="zero1"):
         prepare_training(
             SimpleCNN(num_classes=2), None, optim.adam(1e-3),
-            spmd="fsdp", zero1=True,
+            layout="fsdp", zero1=True,
         )
 
 
