@@ -16,8 +16,21 @@ Design (standard TPU flash schedule):
   the block softmax without a second online pass;
 * Q/K/V blocks are DMA'd HBM→VMEM by ``pallas_call`` per the BlockSpecs;
   the two matmuls (q·kᵀ and p·v) hit the MXU with f32 accumulation;
-* causal masking uses global positions; fully-masked KV blocks are
-  skipped with ``pl.when`` (no MXU work);
+* every grid step is classed by where its tile lies against the band of
+  attendable pairs (``_tile_class``: the causal diagonal, a window's
+  lower edge, sinks, the padded last key block), from scalars alone:
+  - **outside** the band: no body, and nothing fetched — the BlockSpec
+    index maps name the nearest live block of the same row of the grid
+    (``_kv_block_index``, ``_q_block_index``), which the pipeline
+    already holds;
+  - **inside** it: the body without a mask (no iotas, no compares, no
+    selects);
+  - **across** its edge: the masked body, and on the plain causal
+    diagonal of square blocks the quarter above the diagonal is left
+    out (``_across_parts``).
+  ``tile_census`` counts the classes for a call's shapes, and the gauge
+  ``fdtpu_flash_tiles{kernel, kind}`` holds the census of the call
+  traced last;
 * backward = two dedicated Pallas kernels (FlashAttention-2 schedule):
   - dQ kernel, grid (BH, Tq/bq, Tk/bk) with KV innermost: recomputes
     p = exp(s − LSE) per tile, folds dS·K into a VMEM f32 accumulator,
@@ -38,18 +51,21 @@ exercise identical code on the CPU CI mesh.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.metrics import get_registry
 from .attention import NEG_INF, online_softmax_update
 
 __all__ = [
     "flash_attention",
     "flash_attention_lse",
     "interpret_mode",
+    "tile_census",
 ]
 
 # m/l scratch rows are replicated across the VPU lane width.
@@ -84,58 +100,199 @@ def interpret_mode() -> bool:
 _LSE_PAD = 1e30
 
 
-def _block_relevant(q_start, k_start, block_q, block_k,
-                    causal, causal_offset, window, sinks):
-    """Static-shape predicate: does KV block ``kj`` intersect the causal
-    (and sliding-window) band of Q block ``qi`` at all?  False blocks are
-    skipped with ``pl.when`` — with a window this is where the FLOPs
-    saving comes from: far-past KV blocks never touch the MXU.  ``sinks``
-    (attention sinks, StreamingLLM-style) keeps the first ``sinks`` key
-    positions attendable from everywhere, so their blocks stay live."""
-    cond = True
-    if causal:
-        # any (q, k) with k <= q + offset?
-        cond = k_start <= q_start + block_q - 1 + causal_offset
-        if window is not None:
-            # any (q, k) with k >= q + offset - (window-1)?
-            in_band = (k_start + block_k - 1
-                       >= q_start + causal_offset - (window - 1))
-            if sinks:
-                in_band |= k_start < sinks  # sink blocks never go dead
-            cond &= in_band
-    return cond
+class _Band(NamedTuple):
+    """What decides which (query, key) pairs attend: static for a call.
+
+    Query ``q`` attends key ``k`` when ``k < tk_valid`` and, if
+    ``causal``, ``k <= q + causal_offset`` and (with a ``window``) ``k``
+    is among the row's ``window`` most recent keys or one of the first
+    ``sinks``.  ``causal_offset = Tk - Tq`` end-aligns the diagonal, the
+    KV-cache-decode convention of ``dot_product_attention``.  ``padded``
+    says the last key block holds columns beyond ``tk_valid``."""
+
+    causal: bool
+    causal_offset: int
+    window: int | None
+    sinks: int
+    tk_valid: int
+    padded: bool
 
 
-def _band_mask(s_shape, q_start, k_start, *,
-               causal, tk_valid, causal_offset, window, padded, sinks):
-    """The shared fwd/bwd attend-mask for one [block_q, block_k] tile
-    (None when every position is attendable)."""
-    if not (causal or padded):
-        return None
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 1)
-    mask = k_pos < tk_valid
-    if causal:
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s_shape, 0)
-        causal_ok = k_pos <= q_pos + causal_offset
-        mask &= causal_ok
-        if window is not None:
-            in_band = k_pos >= q_pos + causal_offset - (window - 1)
-            if sinks:
-                # sinks stay attendable (still causally: causal_ok above)
-                in_band |= k_pos < sinks
-            mask &= in_band
+def _band(tq, tk, block_k, causal, window, sinks):
+    return _Band(causal, tk - tq, window, sinks, tk, tk % block_k != 0)
+
+
+def _tile_class(q_start, k_start, block_q, block_k, band):
+    """Where the ``[block_q, block_k]`` tile at ``(q_start, k_start)``
+    lies against the band: ``(live, full)``, exact, from scalar
+    arithmetic alone (Python ints in ``tile_census``, program ids in the
+    kernels and their index maps).
+
+    * **outside** (``not live``): no pair attends — no body, and the
+      index maps name a block already in VMEM, so nothing is fetched;
+    * **inside** (``full``): every pair attends — the body without a mask;
+    * **across** (``live and not full``): the diagonal, a window's lower
+      edge, a sink boundary, the padded last block — the masked body.
+
+    With a window this is also where the FLOPs saving comes from:
+    far-past key blocks never touch the MXU; ``sinks`` keep the blocks
+    of the first keys live from everywhere."""
+    k_last = k_start + block_k - 1
+    live = True  # a grid's tile always holds one real key column
+    full = k_last < band.tk_valid if band.padded else True
+    if band.causal:
+        hi_first = q_start + band.causal_offset  # last key row 0 sees
+        hi_last = hi_first + block_q - 1
+        live = k_start <= hi_last
+        full &= k_last <= hi_first
+        if band.window is not None:
+            lo_first = hi_first - (band.window - 1)
+            lo_last = hi_last - (band.window - 1)
+            in_band = (k_last >= lo_first) & (band.tk_valid - 1 >= lo_first)
+            all_in_band = k_start >= lo_last
+            if band.sinks:
+                in_band |= k_start < band.sinks
+                all_in_band |= (band.sinks >= lo_last) | (k_last < band.sinks)
+            live &= in_band
+            full &= all_in_band
+    return live, full
+
+
+def tile_census(tq, tk, block_q, block_k, causal, window=None, sinks=0):
+    """How many tiles of one (row, head)'s grid fall in each class:
+    ``{"outside", "inside", "across"}``.  The three kernels walk the same
+    tiles (dK/dV in the other order, once per query head), so one census
+    serves each; static for a call's shapes, counted at trace time."""
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    band = _band(tq, tk, block_k, causal, window, sinks)
+    census = {"outside": 0, "inside": 0, "across": 0}
+    for q_start in range(0, tq, block_q):
+        for k_start in range(0, tk, block_k):
+            live, full = _tile_class(q_start, k_start, block_q, block_k, band)
+            census["outside" if not live else
+                   "inside" if full else "across"] += 1
+    return census
+
+
+def _publish_census(kernels, *call):
+    """``fdtpu_flash_tiles{kernel, kind}``: ``tile_census(*call)`` of the
+    call traced last, per (row, head)."""
+    census = tile_census(*call)
+    gauge = get_registry().gauge(
+        "fdtpu_flash_tiles",
+        "tiles of one (row, head) by class, of the flash kernel call "
+        "traced last", ("kernel", "kind"))
+    for kernel in kernels:
+        for kind, n in census.items():
+            gauge.labels(kernel, kind).set(n)
+
+
+def _kv_block_index(i, j, block_q, block_k, nk, band):
+    """The key block that forward / dQ grid step ``(i, j)`` names: ``j``
+    on a live step, and on a dead one the nearest live block of row
+    ``i`` at or before ``j`` (after it, below a window without sinks) —
+    the block the pipeline already holds, so a dead step copies nothing."""
+    if not band.causal:
+        return j
+    hi_first = i * block_q + band.causal_offset
+    last = jnp.minimum(
+        jnp.maximum(hi_first + block_q - 1, 0) // block_k, nk - 1)
+    jj = jnp.minimum(j, last)
+    if band.window is not None:
+        first = jnp.minimum(
+            jnp.maximum(hi_first - (band.window - 1), 0) // block_k, last)
+        below = first
+        if band.sinks:  # the gap between sinks and band: the last sink block
+            below = jnp.minimum(
+                j, jnp.minimum((band.sinks - 1) // block_k, last))
+        jj = jnp.where(j < first, below, jj)
+    return jj
+
+
+def _q_block_index(j, qi, block_q, block_k, nq, band):
+    """The query block that dK/dV grid step ``(j, qi)`` names, the same
+    way: the first live query block of key block ``j`` before its run,
+    the last one after it (a window's; sink blocks stay live to the end)."""
+    if not band.causal:
+        return qi
+    k_start = j * block_k
+    first = jnp.minimum(
+        jnp.maximum(k_start - band.causal_offset, 0) // block_q, nq - 1)
+    qq = jnp.maximum(qi, first)
+    if band.window is not None:
+        k_hi = jnp.minimum(k_start + block_k, band.tk_valid) - 1
+        reach = k_hi - band.causal_offset + band.window - 1
+        last = jnp.clip(jnp.maximum(reach, 0) // block_q, first, nq - 1)
+        if band.sinks:
+            last = jnp.where(k_start < band.sinks, nq - 1, last)
+        qq = jnp.minimum(qq, last)
+    return qq
+
+
+def _band_mask(shape, q_start, k_start, band):
+    """The shared fwd/bwd attend-mask of the (sub-)tile whose first pair
+    is ``(q_start, k_start)``, for a tile across the band's edge (so the
+    call is causal or padded).  Positions are taken relative to the
+    tile, so the tile's place enters through scalars only."""
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = col < band.tk_valid - k_start if band.padded else None
+    if band.causal:
+        # k <= q + offset  <=>  (k - k_start) - (q - q_start) <= shift
+        rel = col - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        shift = q_start + band.causal_offset - k_start
+        ok = rel <= shift
+        if band.window is not None:
+            in_band = rel >= shift - (band.window - 1)
+            if band.sinks:
+                # sinks stay attendable (still causally: ``ok`` above)
+                in_band |= col < band.sinks - k_start
+            ok &= in_band
+        mask = ok if mask is None else mask & ok
     return mask
+
+
+def _across_parts(block_q, block_k, band, by_cols):
+    """The (rows, cols) sub-tiles an across tile is computed over.  The
+    plain causal diagonal of square blocks leaves out its quarter above
+    the diagonal (rows of the first half see nothing in the columns of
+    the second), split so that each accumulator row is written once: by
+    rows where the kernel accumulates per query (forward, dQ), by
+    columns where per key (dK/dV).  Halves stay (16, 128)-tileable from
+    blocks of 256 up, also along the lanes the rows' statistics ride;
+    every other across tile is one masked whole."""
+    if not (band.causal and band.window is None and block_q == block_k
+            and block_k % 256 == 0 and band.causal_offset % block_k == 0):
+        return ((slice(0, block_q), slice(0, block_k)),)
+    h = block_q // 2
+    if by_cols:
+        return ((slice(0, block_q), slice(0, h)),
+                (slice(h, block_q), slice(h, block_k)))
+    return ((slice(0, h), slice(0, h)),
+            (slice(h, block_q), slice(0, block_k)))
+
+
+def _by_tile_class(update, q_start, k_start, block_q, block_k, band,
+                   by_cols=False):
+    """Run ``update(rows, cols, masked)`` as the tile's class asks:
+    nothing outside the band, the whole tile unmasked inside it, the
+    masked parts of ``_across_parts`` across it."""
+    whole = (slice(0, block_q), slice(0, block_k))
+    if not (band.causal or band.padded):
+        update(*whole, masked=False)  # every tile of the grid is inside
+        return
+    live, full = _tile_class(q_start, k_start, block_q, block_k, band)
+    pl.when(live & full)(lambda: update(*whole, masked=False))
+
+    @pl.when(live & ~full)
+    def _across():
+        for rows, cols in _across_parts(block_q, block_k, band, by_cols):
+            update(rows, cols, masked=True)
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale, causal, tk_valid, causal_offset, padded, window, sinks,
+    *, scale, band,
 ):
-    """``causal_offset = Tk_valid - Tq_valid`` end-aligns the causal mask
-    (query i attends keys <= i + offset), matching
-    ``dot_product_attention``'s KV-cache-decode convention.  ``window``
-    (sliding-window attention, causal only) restricts each query to its
-    ``window`` most recent keys."""
     _, block_q, _ = q_ref.shape
     _, block_k, _ = k_ref.shape
     qi = pl.program_id(1)
@@ -151,41 +308,32 @@ def _flash_kernel(
     q_start = qi * block_q
     k_start = kj * block_k
 
-    def _body():
+    def _update(rows, cols, masked):
         # Operands stay in their stored dtype: bf16 inputs ride the
         # MXU's native bf16×bf16→f32-accumulate path (casting to f32
         # first would halve MXU throughput).  The scale multiplies the
         # f32 scores, not the inputs, so no precision is lost to it.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k] f32
-
+        )  # [rows, cols] f32
         mask = _band_mask(
-            s.shape, q_start, k_start, causal=causal, tk_valid=tk_valid,
-            causal_offset=causal_offset, window=window, padded=padded,
-            sinks=sinks,
-        )
+            s.shape, q_start + rows.start, k_start + cols.start, band,
+        ) if masked else None
         p, corr, m_new, l_new = online_softmax_update(
-            s, m_ref[:, 0], l_ref[:, 0], mask=mask
+            s, m_ref[rows, 0], l_ref[rows, 0], mask=mask
         )
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
+        acc_ref[rows] = acc_ref[rows] * corr[:, None] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        width = (m_new.shape[0], m_ref.shape[1])
+        m_ref[rows] = jnp.broadcast_to(m_new[:, None], width)
+        l_ref[rows] = jnp.broadcast_to(l_new[:, None], width)
 
-    if causal:
-        # Skip KV blocks entirely outside the causal/window band.
-        pl.when(_block_relevant(
-            q_start, k_start, block_q, block_k, causal, causal_offset,
-            window, sinks,
-        ))(_body)
-    else:
-        _body()
+    _by_tile_class(_update, q_start, k_start, block_q, block_k, band)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -197,9 +345,9 @@ def _flash_kernel(
 
 
 def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-              *, scale, causal, tk_valid, causal_offset, padded, window,
-              sinks, q_start, k_start):
-    """Shared dQ/dKV tile recompute: returns (p, ds), both [bq, bk] f32.
+              rows, cols, masked, *, scale, band, q_start, k_start):
+    """Shared dQ/dKV (sub-)tile recompute: returns (p, ds), both
+    [rows, cols] f32.
 
     ``p`` is the exact forward block softmax, rebuilt from LSE;
     ``ds = p * (dP - delta)`` is the score gradient.  Masked positions
@@ -208,35 +356,33 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     K columns are re-masked too: their K rows are zero so a FINITE p
     contributes nothing to dQ, but their score is 0 and exp(0 - LSE)
     can overflow to inf when a row's LSE < ~-88, and inf · 0 = NaN.
+    A tile inside the band has neither, so it takes no mask.
     """
     # native-dtype operands → bf16 MXU path, f32 accumulation (see fwd)
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    q = q_ref[0, rows]
+    k = k_ref[0, cols]
+    v = v_ref[0, cols]
+    do = do_ref[0, rows]
+    lse = lse_ref[0, 0, rows]
+    delta = delta_ref[0, 0, rows]
     s = scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [block_q, block_k] f32
+    )  # [rows, cols] f32
     p = jnp.exp(s - lse[:, None])
-    mask = _band_mask(
-        s.shape, q_start, k_start, causal=causal, tk_valid=tk_valid,
-        causal_offset=causal_offset, window=window, padded=padded,
-        sinks=sinks,
-    )
-    if mask is not None:
-        p = jnp.where(mask, p, 0.0)
+    if masked:
+        p = jnp.where(_band_mask(
+            s.shape, q_start + rows.start, k_start + cols.start, band,
+        ), p, 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [block_q, block_k]
+    )  # [rows, cols]
     ds = p * (dp - delta[:, None])
     return p, ds
 
 
 def _flash_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
-    *, scale, causal, tk_valid, causal_offset, padded, window, sinks,
+    *, scale, band,
 ):
     _, block_q, _ = q_ref.shape
     _, block_k, _ = k_ref.shape
@@ -251,26 +397,19 @@ def _flash_dq_kernel(
     q_start = qi * block_q
     k_start = kj * block_k
 
-    def _body():
+    def _update(rows, cols, masked):
         _, ds = _bwd_tile(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            scale=scale, causal=causal, tk_valid=tk_valid,
-            causal_offset=causal_offset, padded=padded, window=window,
-            sinks=sinks, q_start=q_start, k_start=k_start,
+            rows, cols, masked,
+            scale=scale, band=band, q_start=q_start, k_start=k_start,
         )
-        k = k_ref[0]
-        dq_acc_ref[:] += scale * jax.lax.dot_general(
+        k = k_ref[0, cols]
+        dq_acc_ref[rows] += scale * jax.lax.dot_general(
             ds.astype(k.dtype), k,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        pl.when(_block_relevant(
-            q_start, k_start, block_q, block_k, causal, causal_offset,
-            window, sinks,
-        ))(_body)
-    else:
-        _body()
+    _by_tile_class(_update, q_start, k_start, block_q, block_k, band)
 
     @pl.when(kj == nk - 1)
     def _finalize():
@@ -280,7 +419,7 @@ def _flash_dq_kernel(
 def _flash_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
-    *, scale, causal, tk_valid, causal_offset, padded, nq, window, sinks,
+    *, scale, band, nq,
 ):
     """Inner grid axis t = member * nq + qi: with GQA, each KV head's
     accumulator folds the q-blocks of all `group` query heads sharing
@@ -300,31 +439,25 @@ def _flash_dkv_kernel(
     q_start = qi * block_q
     k_start = kj * block_k
 
-    def _body():
+    def _update(rows, cols, masked):
         p, ds = _bwd_tile(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            scale=scale, causal=causal, tk_valid=tk_valid,
-            causal_offset=causal_offset, padded=padded, window=window,
-            sinks=sinks, q_start=q_start, k_start=k_start,
+            rows, cols, masked,
+            scale=scale, band=band, q_start=q_start, k_start=k_start,
         )
-        do = do_ref[0]
-        q = q_ref[0]
-        dv_acc_ref[:] += jax.lax.dot_general(
+        do = do_ref[0, rows]
+        q = q_ref[0, rows]
+        dv_acc_ref[cols] += jax.lax.dot_general(
             p.astype(do.dtype), do,
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # pᵀ·dO: contract over the q dimension → [block_k, d]
-        dk_acc_ref[:] += scale * jax.lax.dot_general(
+        )  # pᵀ·dO: contract over the q dimension → [cols, d]
+        dk_acc_ref[cols] += scale * jax.lax.dot_general(
             ds.astype(q.dtype), q,
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # dSᵀ·Q → [block_k, d]
+        )  # dSᵀ·Q → [cols, d]
 
-    if causal:
-        pl.when(_block_relevant(
-            q_start, k_start, block_q, block_k, causal, causal_offset,
-            window, sinks,
-        ))(_body)
-    else:
-        _body()
+    _by_tile_class(_update, q_start, k_start, block_q, block_k, band,
+                   by_cols=True)
 
     @pl.when(t == ntot - 1)
     def _finalize():
@@ -387,18 +520,19 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k,
     def kv_bh(bh):  # query-head program → its KV head's fold index
         return (bh // h) * hkv + (bh % h) // group
 
-    grid = (b * h, tq_p // block_q, tk_p // block_k)
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, tk_valid=tk,
-        causal_offset=tk - tq, padded=tk_p != tk, window=window, sinks=sinks,
-    )
+    nk = tk_p // block_k
+    band = _band(tq, tk, block_k, causal, window, sinks)
+    _publish_census(
+        KERNEL_NAMES[:1], tq, tk, block_q, block_k, causal, window, sinks)
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (
+        kv_bh(bh), _kv_block_index(i, j, block_q, block_k, nk, band), 0))
     out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_flash_kernel, scale=scale, band=band),
+        grid=(b * h, tq_p // block_q, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (kv_bh(bh), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (kv_bh(bh), j, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -465,9 +599,16 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     def q_bh(bh_, t):  # (KV-head program, inner step) → q-head fold index
         return (bh_ // hkv) * h + (bh_ % hkv) * group + t // nq
 
+    band = _band(tq, tk, block_k, causal, window, sinks)
+    _publish_census(
+        KERNEL_NAMES[1:], tq, tk, block_q, block_k, causal, window, sinks)
+
+    def inner_q(j, t):  # dK/dV step → the query block it names
+        return _q_block_index(j, t % nq, block_q, block_k, nq, band)
+
     q_spec_i = pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0))
-    kv_spec_j = pl.BlockSpec(
-        (1, block_k, d), lambda bh_, i, j: (kv_bh(bh_), j, 0))
+    kv_spec_j = pl.BlockSpec((1, block_k, d), lambda bh_, i, j: (
+        kv_bh(bh_), _kv_block_index(i, j, block_q, block_k, nk, band), 0))
     # lse/delta as [BH, 1, Tq] rows (see the forward's LSE out_spec)
     row_spec_i = pl.BlockSpec(
         (1, 1, block_q), lambda bh_, i, j: (bh_, 0, i))
@@ -475,16 +616,13 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     # nq q-blocks of each of the `group` query heads sharing this KV
     # head: t = member * nq + qi.
     q_spec_inner = pl.BlockSpec(
-        (1, block_q, d), lambda bh_, j, t: (q_bh(bh_, t), t % nq, 0))
+        (1, block_q, d), lambda bh_, j, t: (q_bh(bh_, t), inner_q(j, t), 0))
     kv_spec_outer = pl.BlockSpec(
         (1, block_k, d), lambda bh_, j, t: (bh_, j, 0))
     row_spec_inner = pl.BlockSpec(
-        (1, 1, block_q), lambda bh_, j, t: (q_bh(bh_, t), 0, t % nq))
+        (1, 1, block_q), lambda bh_, j, t: (q_bh(bh_, t), 0, inner_q(j, t)))
 
-    common = dict(
-        scale=scale, causal=causal, tk_valid=tk, causal_offset=tk - tq,
-        padded=tk_p != tk, window=window, sinks=sinks,
-    )
+    common = dict(scale=scale, band=band)
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **common),
         grid=(bh, nq, nk),
@@ -544,10 +682,11 @@ def flash_attention(
     f32 accumulation.  Grouped-query KV ([B, T, Hkv, D]) is consumed
     natively (never repeated in HBM).  ``window`` (requires ``causal``)
     restricts each query to its ``window`` most recent keys — KV blocks
-    outside the band are SKIPPED, so long-T cost is O(T·window), not
-    O(T²).  ``sinks`` (StreamingLLM attention sinks; needs ``window``)
-    keeps the first ``sinks`` key positions always attendable — their
-    blocks stay live while everything between sink and band is skipped.
+    outside the band are neither fetched nor computed, so long-T cost is
+    O(T·window), not O(T²).  ``sinks`` (StreamingLLM attention sinks;
+    needs ``window``) keeps the first ``sinks`` key positions always
+    attendable — their blocks stay live while everything between sink
+    and band is skipped.
     """
     _validate_window(causal, window, sinks)
     out, _ = _flash_fwd_impl(q, k, v, causal, block_q, block_k,

@@ -132,8 +132,13 @@ def test_flash_backward_latent_attention_widths(chip, as_on_tpu):
     q = chip((1, 4096, 20, 256), BF)
     text = _compile(_grads(lambda q, k, v: pa.flash_attention(
         q, k, v, True, 1024, 1024)), q, q, q)
+    from fluxdistributed_tpu.obs import get_registry
+
     for name in pa.KERNEL_NAMES:
         assert f"%{name}" in text, name
+        # a (row, head)'s 4 x 4 tiles by class, as traced for this call
+        assert [get_registry().value("fdtpu_flash_tiles", name, kind)
+                for kind in ("outside", "inside", "across")] == [6, 6, 4]
 
 
 def test_held_experts_grouped_products(chip):
