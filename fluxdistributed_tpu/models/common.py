@@ -2,9 +2,29 @@
 
 from __future__ import annotations
 
+import jax.numpy as jnp
 from flax import linen as nn
 
-__all__ = ["maybe_remat"]
+__all__ = ["maybe_remat", "rms_norm", "json_kwargs"]
+
+
+def rms_norm(dtype, eps: float, name: str):
+    """The RMSNorm the expert models share: a learned scale, ``eps`` as
+    the public configs state it."""
+    return nn.RMSNorm(dtype=dtype, epsilon=eps, name=name)
+
+
+def json_kwargs(kw: dict, *tuple_keys: str) -> dict:
+    """A factory's keywords as plain JSON holds them, for a frozen
+    config: ``dtype`` may be a string, and each of ``tuple_keys`` a list
+    where the config wants a tuple."""
+    kw = dict(kw)
+    if isinstance(kw.get("dtype"), str):
+        kw["dtype"] = jnp.dtype(kw["dtype"])
+    for key in tuple_keys:
+        if kw.get(key) is not None:
+            kw[key] = tuple(kw[key])
+    return kw
 
 
 def maybe_remat(block_cls, enabled: bool, train_argnum: int | None = None):

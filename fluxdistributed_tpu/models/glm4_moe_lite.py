@@ -4,7 +4,7 @@ dense layers and a multi-token-prediction module, for the training path.
 
 Built beside :class:`~.transformer_lm.TransformerLM` and reusing its
 RMSNorm, rotary helper and SwiGLU block; ``lm_loss_fn(model)`` serves
-both.  What is new:
+both, and the experts live in :mod:`.experts`.  What is new:
 
 * :class:`LatentAttention` (MLA): queries and keys/values go through
   low-rank projections with an RMSNorm between, the rotary part of a key
@@ -14,7 +14,8 @@ both.  What is new:
   ``q``, ``k``, ``v`` to ``ops.pallas_attention.flash_attention``, so no
   ``[T, T]`` score matrix is stored; ``"xla"`` is the plain path for
   the CPU.
-* :class:`ExpertMLP`: ``parallel.ep.sigmoid_route`` over all
+* :class:`~.experts.ExpertMLP` (shared with ``lfm2_moe``):
+  ``parallel.ep.sigmoid_route`` over all
   ``n_routed_experts`` in float32, ``held_experts_apply`` for the
   ``experts_held = (first, count)`` that live here (no capacity, no
   drops), plus the shared expert.  The router balances by a selection
@@ -43,20 +44,17 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.attention import dot_product_attention
-from ..parallel.ep import compact_rows, held_experts_apply, sigmoid_route
-from .common import maybe_remat
-from .transformer_lm import rope, swiglu_mlp
+from .common import json_kwargs, maybe_remat, rms_norm
+from .experts import ExpertMLP, SwiGLU, router_step_metrics
+from .transformer_lm import rope
 
-__all__ = ["Glm4Config", "Glm4MoeLite", "LatentAttention", "ExpertMLP",
-           "glm4_moe_lite", "NO_DECODE", "ROUTER_COLLECTION"]
+__all__ = ["Glm4Config", "Glm4MoeLite", "LatentAttention", "glm4_moe_lite",
+           "NO_DECODE"]
 
 NO_DECODE = (
     "glm4_moe_lite has no decode path: serving it needs a latent cache row "
     "(the compressed key-value features and the shared rotary key of a "
     "position), which neither the decode caches nor LMEngine have")
-
-#: the flax collection of the routers' selection bias and step load
-ROUTER_COLLECTION = "router"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,22 +94,6 @@ class Glm4Config:
     remat: bool = False
 
 
-def _rms_norm(dtype, eps: float, name: str):
-    return nn.RMSNorm(dtype=dtype, epsilon=eps, name=name)
-
-
-class SwiGLU(nn.Module):
-    """The dense gated MLP (and the shared expert) in a scope of its
-    own: ``gate``, ``up``, ``down`` as in ``DecoderBlock(mlp="swiglu")``."""
-
-    mlp_dim: int
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        return swiglu_mlp(x, self.mlp_dim, self.dtype)
-
-
 class LatentAttention(nn.Module):
     """Multi-head latent attention, training forward only."""
 
@@ -139,7 +121,7 @@ class LatentAttention(nn.Module):
             n, dtype=self.dtype, use_bias=False, name=name)
         heads = lambda f, name: nn.DenseGeneral(  # noqa: E731
             (h, f), axis=-1, dtype=self.dtype, use_bias=False, name=name)
-        norm = partial(_rms_norm, self.dtype, self.norm_eps)
+        norm = partial(rms_norm, self.dtype, self.norm_eps)
 
         c_q = norm("q_a_norm")(dense(self.q_lora_rank, "q_a")(x))
         q = heads(nope + rot, "q_b")(c_q)  # [B, T, H, nope + rot]
@@ -172,54 +154,6 @@ class LatentAttention(nn.Module):
                                use_bias=False, name="o")(out)
 
 
-class ExpertMLP(nn.Module):
-    """The routed experts held here; the router's bias and load."""
-
-    moe_dim: int
-    n_routed_experts: int
-    experts_held: Tuple[int, int]
-    top_k: int
-    routed_scaling_factor: float = 1.0
-    norm_topk_prob: bool = True
-    bias_update_rate: float = 0.001
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x, train: bool = True):
-        e, (first, held) = self.n_routed_experts, self.experts_held
-        if not (0 <= first and held >= 1 and first + held <= e):
-            raise ValueError(
-                f"experts_held {self.experts_held} is not a range of the "
-                f"{e} routed experts")
-        d, m = x.shape[-1], self.moe_dim
-        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
-                                            batch_axis=(0,))
-        router = self.param("router", nn.initializers.lecun_normal(), (d, e),
-                            jnp.float32)
-        w_gate = self.param("w_gate", init, (held, d, m), jnp.float32)
-        w_up = self.param("w_up", init, (held, d, m), jnp.float32)
-        w_down = self.param("w_down", init, (held, m, d), jnp.float32)
-        bias = self.variable(ROUTER_COLLECTION, "bias",
-                             lambda: jnp.zeros((e,), jnp.float32))
-        load = self.variable(ROUTER_COLLECTION, "load",
-                             lambda: jnp.zeros((e,), jnp.float32))
-        toks = x.reshape(-1, d)
-        with jax.named_scope("fdtpu/moe_route"):
-            chosen, weights, count = sigmoid_route(
-                toks, router, bias.value, top_k=self.top_k,
-                scale=self.routed_scaling_factor,
-                normalize=self.norm_topk_prob)
-        if train and not self.is_initializing():
-            count = jax.lax.stop_gradient(count)
-            load.value = count
-            bias.value = bias.value + self.bias_update_rate * jnp.sign(
-                jnp.mean(count) - count)
-        with jax.named_scope("fdtpu/moe_experts"):
-            y = held_experts_apply(toks.astype(self.dtype), chosen, weights,
-                                   w_gate, w_up, w_down, e, first=first)
-        return y.reshape(x.shape)
-
-
 class Glm4Block(nn.Module):
     """Pre-norm block: latent attention, then the dense SwiGLU
     (``dense_dim``) or the experts with their shared expert."""
@@ -230,7 +164,7 @@ class Glm4Block(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = True):
         c = self.cfg
-        norm = partial(_rms_norm, c.dtype, c.rms_norm_eps)
+        norm = partial(rms_norm, c.dtype, c.rms_norm_eps)
         with jax.named_scope("fdtpu/mla"):
             x = x + LatentAttention(
                 c.num_heads, c.q_lora_rank, c.kv_lora_rank,
@@ -262,7 +196,7 @@ class MultiTokenModule(nn.Module):
     @nn.compact
     def __call__(self, h, emb, train: bool = True):
         c = self.cfg
-        norm = partial(_rms_norm, c.dtype, c.rms_norm_eps)
+        norm = partial(rms_norm, c.dtype, c.rms_norm_eps)
         both = jnp.concatenate([norm("hnorm")(h), norm("enorm")(emb)], axis=-1)
         x = nn.Dense(c.dim, dtype=c.dtype, use_bias=False, name="eh_proj")(both)
         block = maybe_remat(Glm4Block, c.remat, train_argnum=2)
@@ -286,33 +220,9 @@ class Glm4MoeLite(nn.Module):
         return self.cfg.mtp_weight
 
     def step_metrics(self, model_state) -> dict:
-        """Of the state a training step leaves: each router's load
-        ``moe_load`` [routers, experts]; over all routers the
-        token-slots of the experts held here and of the absent ones
-        (``moe_slots``) and those that found no row (``moe_dropped``:
-        nought, since a step that overflows ``held_experts_apply``'s
-        bounded buffer takes the one with a row for every slot); and
-        how many routers' layers took the bounded buffer and how many
-        the whole one (``moe_compact``: the layer's own predicate over
-        the same load; a layer whose bound is all its slots has no
-        branch and counts as whole).  Nothing for a model without a
-        router."""
-        routers = model_state.get(ROUTER_COLLECTION)
-        if not routers:
-            return {}
-        load = jnp.stack([leaf for path, leaf in
-                          jax.tree_util.tree_flatten_with_path(routers)[0]
-                          if path[-1].key == "load"])
-        first, held = self.cfg.experts_held or (0, self.cfg.n_routed_experts)
-        here = jnp.sum(load[:, first:first + held], axis=-1)
-        slots = jnp.sum(load, axis=-1)
-        rows = compact_rows(slots.astype(jnp.int32), held,
-                            self.cfg.n_routed_experts)
-        compact = jnp.sum((rows < slots) & (here <= rows), dtype=jnp.float32)
-        return {"moe_load": load,
-                "moe_slots": jnp.stack([jnp.sum(here), jnp.sum(slots - here)]),
-                "moe_dropped": jnp.zeros((), jnp.float32),
-                "moe_compact": jnp.stack([compact, len(load) - compact])}
+        """:func:`~.experts.router_step_metrics` of this model's share."""
+        return router_step_metrics(model_state, self.cfg.experts_held,
+                                   self.cfg.n_routed_experts)
 
     def __post_init__(self):
         if self.decode:
@@ -334,7 +244,7 @@ class Glm4MoeLite(nn.Module):
         for i in range(c.num_layers):
             x = block(c, i < c.first_k_dense_replace, name=f"layer{i}")(x, train)
         logits = logits_of(
-            _rms_norm(c.dtype, c.rms_norm_eps, "final_norm")(x))
+            rms_norm(c.dtype, c.rms_norm_eps, "final_norm")(x))
         if c.num_nextn_predict_layers and (train or self.is_initializing()):
             from .transformer_lm import next_token_loss
 
@@ -354,8 +264,4 @@ class Glm4MoeLite(nn.Module):
 def glm4_moe_lite(**kw) -> Glm4MoeLite:
     """The model from plain JSON: ``dtype`` may be a string and
     ``experts_held`` a list."""
-    if isinstance(kw.get("dtype"), str):
-        kw["dtype"] = jnp.dtype(kw["dtype"])
-    if kw.get("experts_held") is not None:
-        kw["experts_held"] = tuple(int(n) for n in kw["experts_held"])
-    return Glm4MoeLite(Glm4Config(**kw))
+    return Glm4MoeLite(Glm4Config(**json_kwargs(kw, "experts_held")))

@@ -27,8 +27,9 @@ if ROOT not in sys.path:
 
 from chipbench import harness, refcommon  # noqa: E402
 from fluxdistributed_tpu import models  # noqa: E402
+from fluxdistributed_tpu.models.experts import ExpertMLP, SwiGLU  # noqa: E402
 from fluxdistributed_tpu.models.glm4_moe_lite import (  # noqa: E402
-    ExpertMLP, LatentAttention, NO_DECODE, SwiGLU)
+    LatentAttention, NO_DECODE)
 from fluxdistributed_tpu.parallel import ep  # noqa: E402
 
 REF = harness.load_module(

@@ -141,6 +141,25 @@ def test_flash_backward_latent_attention_widths(chip, as_on_tpu):
                 for kind in ("outside", "inside", "across")] == [6, 6, 4]
 
 
+def test_flash_backward_grouped_query_widths(chip, as_on_tpu):
+    """The `lfm2_8b_a1b` cell's attention call as the step makes it: 4
+    rows of 4,096 positions, 32 query heads over 8 key-value heads of 64
+    (half the MXU's lanes), 1,024 x 1,024 blocks; `k`, `v` and their
+    gradients stay at 8 heads, and the kernels keep the names the trace
+    reducer reads (`chipbench/metrics/gqa_attn_roofline_pct.py`)."""
+    q, kv = chip((4, 4096, 32, 64), BF), chip((4, 4096, 8, 64), BF)
+    fn = _grads(lambda q, k, v: pa.flash_attention(q, k, v, True, 1024, 1024))
+    text = _compile(fn, q, kv, kv)
+    out = jax.eval_shape(fn, q, kv, kv)
+    assert [x.shape for x in out] == [q.shape, kv.shape, kv.shape]
+    from fluxdistributed_tpu.obs import get_registry
+
+    for name in pa.KERNEL_NAMES:
+        assert f"%{name}" in text, name
+        assert [get_registry().value("fdtpu_flash_tiles", name, kind)
+                for kind in ("outside", "inside", "across")] == [6, 6, 4]
+
+
 def test_held_experts_grouped_products(chip):
     """The same cell's expert layer: 16,384 tokens, 4 of 64 experts a
     token, 8 held; XLA lowers `ragged_dot` to its grouped-matmul kernel,
