@@ -2,8 +2,11 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+from ..ops.pallas_attention import KEPT_LSE, KEPT_OUT
 
 __all__ = ["maybe_remat", "rms_norm", "json_kwargs"]
 
@@ -43,9 +46,19 @@ def maybe_remat(block_cls, enabled: bool, train_argnum: int | None = None):
     auto-name: the wrapper class is named ``Checkpoint<Block>`` and
     would otherwise rename flax scopes, orphaning checkpoints and
     imported torch weights (asserted by ``tests/test_remat.py``).
+
+    One thing is kept across the block and not made again: what a flash
+    forward call returned (``out`` and the rows' ``lse``, named in
+    ``ops.pallas_attention``), ``B*T*H*D`` in the compute type a call.
+    The backward pass runs projections, norms and rotary again for the
+    kernels' ``q``, ``k``, ``v``; the forward kernel it does not.  A
+    block that holds no flash call has nothing by those names, and its
+    program is plain ``nn.remat``'s.
     """
     if not enabled:
         return block_cls
-    if train_argnum is None:
-        return nn.remat(block_cls)
-    return nn.remat(block_cls, static_argnums=(train_argnum,))
+    return nn.remat(
+        block_cls,
+        static_argnums=() if train_argnum is None else (train_argnum,),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            KEPT_OUT, KEPT_LSE))

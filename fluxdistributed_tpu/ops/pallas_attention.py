@@ -55,6 +55,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,6 +63,8 @@ from ..obs.metrics import get_registry
 from .attention import NEG_INF, online_softmax_update
 
 __all__ = [
+    "KEPT_OUT",
+    "KEPT_LSE",
     "flash_attention",
     "flash_attention_lse",
     "interpret_mode",
@@ -75,6 +78,13 @@ _LANES = 128
 #: trace's ``XLA Ops`` (forward, dQ, dK/dV): fixed here, so that a trace
 #: reducer finds them wherever the call sits (under jvp, remat, a scan)
 KERNEL_NAMES = ("fdtpu_flash_fwd", "fdtpu_flash_dq", "fdtpu_flash_dkv")
+
+#: what a forward call made and its backward reads besides q, k, v, under
+#: the names ``jax.ad_checkpoint.checkpoint_name`` gives them in the
+#: ``custom_vjp`` forward rules: a rematerialised block that saves these
+#: two (``models.common.maybe_remat``) never runs the forward kernel again
+KEPT_OUT = "fdtpu_flash_out"
+KEPT_LSE = "fdtpu_flash_lse"
 
 
 def interpret_mode() -> bool:
@@ -185,6 +195,19 @@ def _publish_census(kernels, *call):
     for kernel in kernels:
         for kind, n in census.items():
             gauge.labels(kernel, kind).set(n)
+
+
+def _kept(out, lse):
+    """A forward rule's two results under their names (``KEPT_OUT``,
+    ``KEPT_LSE``), and ``fdtpu_flash_kept_bytes{kernel}``: the bytes of
+    the two, of the call traced last."""
+    get_registry().gauge(
+        "fdtpu_flash_kept_bytes",
+        "bytes of out and lse that the flash forward call traced last "
+        "keeps for its backward", ("kernel",),
+    ).labels(KERNEL_NAMES[0]).set(
+        out.size * out.dtype.itemsize + lse.size * lse.dtype.itemsize)
+    return checkpoint_name(out, KEPT_OUT), checkpoint_name(lse, KEPT_LSE)
 
 
 def _kv_block_index(i, j, block_q, block_k, nk, band):
@@ -713,8 +736,8 @@ def _fwd(q, k, v, causal, block_q, block_k, window, sinks):
     # custom_vjp skips the primal body under jax.grad — re-validate here
     # or invalid combos would silently trace through in training steps
     _validate_window(causal, window, sinks)
-    out, lse = _flash_fwd_impl(q, k, v, causal, block_q, block_k,
-                               window=window, sinks=sinks)
+    out, lse = _kept(*_flash_fwd_impl(q, k, v, causal, block_q, block_k,
+                                      window=window, sinks=sinks))
     return out, (q, k, v, out, lse)
 
 
@@ -761,8 +784,8 @@ def flash_attention_lse(
 
 
 def _fwd_lse(q, k, v, causal, block_q, block_k, window):
-    out, lse = _flash_fwd_impl(q, k, v, causal, block_q, block_k,
-                               window=window)
+    out, lse = _kept(*_flash_fwd_impl(q, k, v, causal, block_q, block_k,
+                                      window=window))
     b, tq, h, _ = q.shape
     return (out, lse.reshape(b, h, tq)), (q, k, v, out, lse)
 

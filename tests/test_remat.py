@@ -11,14 +11,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# tier-2 (slow): bit-level grad parity across remat'd full models — the tier-1 iteration loop must fit the
-# 870s verify window (ROADMAP); CI's slow job still runs this file
-pytestmark = pytest.mark.slow
-
 import fluxdistributed_tpu as fd
+from fluxdistributed_tpu import models
 from fluxdistributed_tpu.models import convnext_test, lm_tiny, resnet18, vit_tiny
 from fluxdistributed_tpu.models import lm_loss_fn
+from fluxdistributed_tpu.ops import attention_core
+from fluxdistributed_tpu.ops import pallas_attention as pa
 from fluxdistributed_tpu.parallel.dp import flax_loss_fn
+
+# tier-2 (slow): bit-level grad parity across remat'd full models — the tier-1 iteration loop must fit the
+# 870s verify window (ROADMAP); CI's slow job still runs these.  What a rematerialised block keeps of its
+# flash call (`test_a_rematerialised_block_keeps_its_flash_call`) is tier-1.
+slow = pytest.mark.slow
 
 
 def _grad_parity(m0, mr, loss_of, params):
@@ -37,6 +41,7 @@ def _grad_parity(m0, mr, loss_of, params):
     return aux0, aux1
 
 
+@slow
 @pytest.mark.parametrize("family", ["resnet", "vit", "convnext"])
 def test_image_model_remat_parity(family):
     mk = {
@@ -67,6 +72,7 @@ def test_image_model_remat_parity(family):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
 
+@slow
 def test_lm_remat_parity():
     m0 = lm_tiny(vocab=32, dtype=jnp.float32)
     mr = lm_tiny(vocab=32, dtype=jnp.float32, remat=True)
@@ -80,6 +86,7 @@ def test_lm_remat_parity():
     _grad_parity(m0, mr, loss_of, params)
 
 
+@slow
 def test_lm_remat_decode_unaffected():
     """decode=True ignores remat (no backward pass at inference; the
     cache write must not go through a checkpoint boundary)."""
@@ -94,3 +101,72 @@ def test_lm_remat_decode_unaffected():
     out_r = np.asarray(generate(mr, params, toks, total_len=6))
     out_0 = np.asarray(generate(m0, params, toks, total_len=6))
     np.testing.assert_array_equal(out_r, out_0)
+
+
+# -- what a rematerialised block keeps of its flash call ----------------------
+
+def _tiny_glm4(**kw):
+    return models.glm4_moe_lite(
+        vocab=64, dim=32, num_layers=2, num_heads=2, q_lora_rank=16,
+        kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate_size=64, moe_intermediate_size=16, n_routed_experts=8,
+        experts_held=[0, 4], num_experts_per_tok=2,
+        num_nextn_predict_layers=1, dtype="float32",
+        attention_impl="pallas", attn_block_q=8, attn_block_k=8, **kw)
+
+
+def _tiny_lfm2(**kw):
+    return models.lfm2_moe(
+        vocab=64, dim=32, num_layers=3, num_heads=8, num_kv_heads=2,
+        layer_types=["conv", "full_attention", "full_attention"],
+        intermediate_size=64, moe_intermediate_size=16, n_routed_experts=8,
+        experts_held=[0, 4], num_experts_per_tok=2, num_dense_layers=1,
+        dtype="float32", attention_impl="pallas", attn_block_q=8,
+        attn_block_k=8, **kw)
+
+
+def _tiny_lm(**kw):
+    return lm_tiny(vocab=64, dtype=jnp.float32,
+                   attn_fn=attention_core("flash", block=8), **kw)
+
+
+@pytest.mark.parametrize("make", [_tiny_glm4, _tiny_lfm2, _tiny_lm],
+                         ids=["glm4_moe_lite", "lfm2_moe", "transformer_lm"])
+def test_a_rematerialised_block_keeps_its_flash_call(make, monkeypatch):
+    """``remat=True`` saves what the flash forward call made (``out``,
+    ``lse``) and nothing else: loss and every gradient leaf equal plain
+    ``nn.remat``'s to the bit (the same kernel on the same operands,
+    called once where plain remat calls it twice), and the model's
+    without remat within this file's tolerances."""
+    tokens = np.random.default_rng(2).integers(0, 64, (2, 16)).astype(np.int32)
+    variables = make().init(jax.random.PRNGKey(0), tokens, train=True)
+    params = variables["params"]
+    state = {k: v for k, v in variables.items()
+             if k not in ("params", "losses")}
+
+    def run(model):
+        """The gradient's jaxpr as text, and (loss, gradients): one trace."""
+        traced = jax.jit(jax.value_and_grad(lambda p: lm_loss_fn(model)(
+            p, state, {"tokens": tokens}, True)[0])).trace(params)
+        return str(traced.jaxpr), traced.lower().compile()(params)
+
+    kept_text, (kept_loss, kept) = run(make(remat=True))
+    for name in (pa.KEPT_OUT, pa.KEPT_LSE):
+        assert f"name={name}" in kept_text, name
+    _, (loss, grads) = run(make())
+    with monkeypatch.context() as m:  # maybe_remat without its policy
+        m.setattr(jax.checkpoint_policies, "save_only_these_names",
+                  lambda *names: None)
+        plain_text, (plain_loss, plain) = run(make(remat=True))
+    # plain remat runs the forward kernel again in the backward pass
+    calls = lambda t: t.count(f"name={pa.KERNEL_NAMES[0]}")  # noqa: E731
+    assert calls(plain_text) > calls(kept_text)
+
+    assert float(kept_loss) == float(plain_loss)
+    np.testing.assert_allclose(float(kept_loss), float(loss), rtol=1e-6)
+    for (path, a), b, c in zip(jax.tree_util.tree_leaves_with_path(kept),
+                               jax.tree.leaves(plain), jax.tree.leaves(grads)):
+        where = jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), where)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-5,
+                                   atol=1e-6, err_msg=where)

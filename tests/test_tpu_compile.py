@@ -141,6 +141,69 @@ def test_flash_backward_latent_attention_widths(chip, as_on_tpu):
                 for kind in ("outside", "inside", "across")] == [6, 6, 4]
 
 
+def _kept_out(q, k, v):
+    return pa.flash_attention(q, k, v, True, 1024, 1024)
+
+
+def _kept_lse(q, k, v):
+    """`flash_attention_lse` with both results used, as ring attention
+    uses them: the rows' statistics weigh the output."""
+    out, lse = pa.flash_attention_lse(q, k, v, True, 1024, 1024)
+    return out * jax.nn.sigmoid(lse).transpose(0, 2, 1)[..., None].astype(BF)
+
+
+@pytest.mark.parametrize("attn,policy,forwards", [
+    (_kept_out, True, 1), (_kept_lse, True, 1), (_kept_out, False, 2),
+], ids=["flash_attention", "flash_attention_lse", "plain_remat"])
+def test_a_rematerialised_stack_runs_the_forward_kernel_once(
+        chip, as_on_tpu, monkeypatch, attn, policy, forwards):
+    """Two rematerialised layers (`models.common.maybe_remat`) of the
+    `glm47_flash` cell's attention widths, forward and backward: a layer
+    holds ONE forward call, one dQ, one dK/dV.  What the forward made
+    (`out`, `lse`) is kept by name across the block, so the backward
+    pass's second run of the block drops its forward call as dead code;
+    under plain `nn.remat`, the third case, it runs twice."""
+    import re
+
+    from flax import linen as nn
+
+    from fluxdistributed_tpu.models.common import maybe_remat
+    from fluxdistributed_tpu.obs import get_registry
+
+    if not policy:
+        monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                            lambda *names: None)
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            qkv = nn.DenseGeneral((3, 20, 256), dtype=BF, use_bias=False)(x)
+            out = attn(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
+            return x + nn.DenseGeneral(
+                x.shape[-1], axis=(-2, -1), dtype=BF, use_bias=False)(out)
+
+    class Stack(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            block = maybe_remat(Block, True)
+            for i in range(2):
+                x = block(name=f"layer{i}")(x)
+            return x
+
+    x = chip((2, 4096, 2048), BF)
+    params = jax.tree.map(
+        lambda p: chip(p.shape, p.dtype),
+        jax.eval_shape(Stack().init, jax.random.PRNGKey(0), x))
+    text = _compile(jax.value_and_grad(lambda p, x: Stack().apply(
+        p, x).astype(jnp.float32).sum()), params, x)
+    calls = [len(re.findall(rf"%{name}[.\d]* = ", text))
+             for name in pa.KERNEL_NAMES]
+    assert calls == [2 * forwards, 2, 2]
+    # out in bf16 and the rows' statistics in float32, of one call
+    assert get_registry().value("fdtpu_flash_kept_bytes", pa.KERNEL_NAMES[0]) \
+        == 2 * 4096 * 20 * (256 * 2 + 4)
+
+
 def test_flash_backward_grouped_query_widths(chip, as_on_tpu):
     """The `lfm2_8b_a1b` cell's attention call as the step makes it: 4
     rows of 4,096 positions, 32 query heads over 8 key-value heads of 64
