@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+from typing import Any
+
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
@@ -11,9 +13,29 @@ from ..ops.pallas_attention import KEPT_LSE, KEPT_OUT
 __all__ = ["maybe_remat", "rms_norm", "json_kwargs"]
 
 
-def rms_norm(dtype, eps: float, name: str):
-    """The RMSNorm the expert models share: a learned scale, ``eps`` as
-    the public configs state it."""
+class UnitOffsetRMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * (1 + w)``, ``w`` from nought
+    (``norm_add_unit_offset``): float32 inside, the result in ``dtype``."""
+
+    dtype: Any
+    epsilon: float
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                       jnp.float32)
+        x = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return (x * jax.lax.rsqrt(var + self.epsilon) * (1.0 + w)).astype(
+            self.dtype)
+
+
+def rms_norm(dtype, eps: float, name: str, unit_offset: bool = False):
+    """The RMSNorm the decoder models share: a learned scale, ``eps`` as
+    the public configs state it; with ``unit_offset`` the scale is ``1 +
+    w``."""
+    if unit_offset:
+        return UnitOffsetRMSNorm(dtype, eps, name=name)
     return nn.RMSNorm(dtype=dtype, epsilon=eps, name=name)
 
 

@@ -223,6 +223,40 @@ def test_flash_backward_grouped_query_widths(chip, as_on_tpu):
                 for kind in ("outside", "inside", "across")] == [6, 6, 4]
 
 
+def test_eva_attention_calls_at_the_cells_widths(chip, as_on_tpu):
+    """The `evabyte` cell's EVA layer as the step makes it: 2 rows of
+    8,192 positions, 8 held heads of 128, windows of 2,048 in chunks of
+    16.  The exact part is ONE causal call on the 8 folded windows in
+    1,024 x 1,024 blocks (a 2 x 2 grid a window and head: 1 tile outside
+    the band, 1 inside, 2 across); the summarised part one non-causal
+    call a window after the first over 128, 256 and 384 summaries, every
+    tile inside.  Forward and gradients: 4 calls of each kernel."""
+    from fluxdistributed_tpu.ops.eva_attention import eva_attention
+
+    q, mu = chip((2, 8192, 8, 128), BF), chip((8, 128), jnp.float32)
+    fn = jax.grad(lambda q, k, v, mu, phi: eva_attention(
+        q, k, v, mu, phi, window=2048, chunk=16, impl="pallas",
+        block_q=1024, block_k=1024).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))
+    text = _compile(fn, q, q, q, mu, mu)
+    assert [x.shape for x in jax.eval_shape(fn, q, q, q, mu, mu)] == [
+        q.shape] * 3 + [mu.shape] * 2
+    for name in pa.KERNEL_NAMES:
+        assert text.count(f"%{name}") >= 4, name
+    assert pa.tile_census(2048, 2048, 1024, 1024, True) == {
+        "outside": 1, "inside": 1, "across": 2}
+    for tk in (128, 256, 384):
+        assert pa.tile_census(2048, tk, 1024, tk, False) == {
+            "outside": 0, "inside": 2, "across": 0}
+    # the two expert cells' calls, whose census this PR leaves alone
+    assert pa.tile_census(4096, 4096, 1024, 1024, True) == {
+        "outside": 6, "inside": 6, "across": 4}
+    from fluxdistributed_tpu.obs import get_registry
+
+    assert get_registry().value("fdtpu_eva_pairs", "summary") == 1572864
+    assert get_registry().value("fdtpu_eva_pairs", "local") == 8392704
+
+
 def test_held_experts_grouped_products(chip):
     """The same cell's expert layer: 16,384 tokens, 4 of 64 experts a
     token, 8 held; XLA lowers `ragged_dot` to its grouped-matmul kernel,
