@@ -91,11 +91,13 @@ def router_step_metrics(model_state, experts_held: Optional[Tuple[int, int]],
     ``moe_load`` [routers, experts]; over all routers the token-slots of
     the experts held here and of the absent ones (``moe_slots``) and
     those that found no row (``moe_dropped``: nought, since a step that
-    overflows ``held_experts_apply``'s bounded buffer takes the one with
-    a row for every slot); and how many routers' layers took the bounded
-    buffer and how many the whole one (``moe_compact``: the layer's own
-    predicate over the same load; a layer whose bound is all its slots
-    has no branch and counts as whole).  Nothing for a model without a
+    overflows every bounded rung of ``held_experts_apply``'s buffer
+    takes the one with a row for every slot); how many routers' layers
+    took a rung below the whole buffer and how many the whole one
+    (``moe_compact``: the layer's own ladder over the same load; a layer
+    whose only rung is all its slots has no branch and counts as whole);
+    and the held rows that were live beside the rows of the buffers the
+    layers took (``moe_rows``).  Nothing for a model without a
     router."""
     routers = model_state.get(ROUTER_COLLECTION)
     if not routers:
@@ -106,9 +108,14 @@ def router_step_metrics(model_state, experts_held: Optional[Tuple[int, int]],
     first, held = experts_held or (0, n_routed_experts)
     here = jnp.sum(load[:, first:first + held], axis=-1)
     slots = jnp.sum(load, axis=-1)
-    rows = compact_rows(slots.astype(jnp.int32), held, n_routed_experts)
-    compact = jnp.sum((rows < slots) & (here <= rows), dtype=jnp.float32)
+    *rungs, taken = compact_rows(slots.astype(jnp.int32), held,
+                                 n_routed_experts)
+    for rows in reversed(rungs):  # the first rung that holds the held slots
+        taken = jnp.where(here <= rows, rows, taken)
+    compact = jnp.sum(taken < slots, dtype=jnp.float32)
     return {"moe_load": load,
             "moe_slots": jnp.stack([jnp.sum(here), jnp.sum(slots - here)]),
             "moe_dropped": jnp.zeros((), jnp.float32),
-            "moe_compact": jnp.stack([compact, len(load) - compact])}
+            "moe_compact": jnp.stack([compact, len(load) - compact]),
+            "moe_rows": jnp.stack([jnp.sum(here),
+                                   jnp.sum(taken, dtype=jnp.float32)])}

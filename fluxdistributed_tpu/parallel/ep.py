@@ -32,22 +32,28 @@ leading size of its weights): the token-slots are sorted by expert, the
 held experts' slots come first, one ``jax.lax.ragged_dot`` a projection
 runs over the sorted rows (on the TPU XLA lowers it to a grouped-matmul
 kernel that visits the live row tiles only), and the weighted results
-are gathered back to their tokens.  The buffer is as long as the rows
-this chip can expect to hold (``compact_rows``: twice the held experts'
-even share of the token-slots), as a chip's buffer is behind an
-exchange; a step whose held slots overflow it takes a buffer with a row
-for every token-slot instead (a ``lax.cond`` on the step's own load),
-so nothing is dropped, and where the bound is every slot (most experts
-held) that is the only path.  What the absent experts would add is left
-out.  The trainer counts the path a layer took in
-``fdtpu_moe_compact_total{path}``.  On one chip the layer runs without
-an exchange; the exchange between chips that hold different experts is
-not built yet.
+are gathered back to their tokens.  The buffer is as long as the step's
+held rows need: ``compact_rows`` is a short ladder of lengths, multiples
+of the held experts' even share of the token-slots and the last a row
+for every slot, and a ``lax.switch`` on the step's own load takes the
+first rung that holds the held slots (everything around the products
+runs over the buffer's length, live or not, so a shorter buffer is a
+cheaper step; the products visit live tiles only).  Nothing is dropped:
+every rung computes the same rows in the same order, and a step too
+large for every rung takes the whole buffer; where the first rung is
+already every slot (most experts held) that is the only path and there
+is no branch.  What the absent experts would add is left out.  The
+trainer counts the path a layer took in
+``fdtpu_moe_compact_total{path}`` and the rows it took beside the rows
+that were live in ``fdtpu_moe_buffer_rows_total{kind}``.  On one chip
+the layer runs without an exchange; the exchange between chips that hold
+different experts is not built yet.
 """
 
 from __future__ import annotations
 
 import math
+from fractions import Fraction
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -314,26 +320,36 @@ def sigmoid_route(x, router_w, bias, *, top_k: int, scale: float = 1.0,
     return chosen, w * scale, load.astype(jnp.float32)
 
 
-#: the compact buffer's rows over the held slots expected of an even
-#: router.  Fixed by chip runs (PERF.md §6, PR 28): training one chip's
-#: share sends its experts from the even share to 1.5-2 times it within
-#: 60 steps (single steps 2.5).  At 1.5 a sixth of the layers overflowed;
-#: 3 would hold them all and every step pay half as many rows again; at 2
-#: some 95% fit, and a step over it takes the whole buffer.
-COMPACT_OVER_EXPECTED = 2
-_COMPACT_TILE = 512  # the bound is a whole number of row tiles
+#: the sorted buffer's lengths over the held slots expected of an even
+#: router, ascending: the ladder's rungs below a row for every slot.
+#: Each rung is one more compiled copy of the layer, forward and
+#: backward, so there are four.  Fixed by chip runs (PERF.md §6, PR 28
+#: and 34): a router that stays even holds 1.0-1.1 times the even share
+#: in every step (the first rung); training one chip's share of the
+#: experts against a drifting router sends them from the even share to
+#: 1.5-2 times it within 60 steps (the middle rungs), single steps to
+#: 2.5 (the last, where PR 28's two lengths took every slot).
+COMPACT_OVER_EXPECTED = (Fraction(9, 8), Fraction(3, 2), Fraction(2),
+                         Fraction(3))
+_COMPACT_TILE = 512  # a rung is a whole number of row tiles
 
 
-def compact_rows(slots, held: int, experts: int):
-    """Rows of the sorted buffer for ``slots`` token-slots routed over
-    ``experts`` of which ``held`` live here: ``COMPACT_OVER_EXPECTED``
-    times the expected share, up to the next tile, and never more than
-    the slots.  Of shapes alone, so the trace decides; ``slots`` may
-    also be an array of whole numbers (a step's loads, for the counter
-    of the path taken)."""
-    want = COMPACT_OVER_EXPECTED * slots * held
-    bound = -(-want // (experts * _COMPACT_TILE)) * _COMPACT_TILE
-    return min(slots, bound) if isinstance(slots, int) else jnp.minimum(slots, bound)
+def compact_rows(slots, held: int, experts: int) -> tuple:
+    """The lengths the sorted buffer may take for ``slots`` token-slots
+    routed over ``experts`` of which ``held`` live here, ascending: each
+    of ``COMPACT_OVER_EXPECTED`` times the expected share, up to the
+    next tile, and last a row for every slot; a rung at or over the
+    slots is left out, so where most experts are held the ladder is
+    ``(slots,)``.  Of shapes alone, so the trace decides; ``slots`` may
+    also be an array of whole numbers (a step's loads, for the counters
+    of the rung taken): the rungs are then arrays, none left out and
+    none over the slots."""
+    rungs = [-(-over.numerator * slots * held
+               // (over.denominator * experts * _COMPACT_TILE)) * _COMPACT_TILE
+             for over in COMPACT_OVER_EXPECTED]
+    if isinstance(slots, int):
+        return (*sorted({r for r in rungs if r < slots}), slots)
+    return (*(jnp.minimum(r, slots) for r in rungs), slots)
 
 
 def _sum_rows(rows, at, scale=None):
@@ -414,37 +430,53 @@ def _sorted_experts(rows, x, weights, w_gate, w_up, w_down, order, inverse,
     return _from_sorted(y, weights, order, inverse)
 
 
-def _fits(rows, slots, sizes, path):
-    """``path(rows)`` in a step whose held slots fit ``rows``, else
-    ``path(slots)``, a row for every slot: nothing is dropped."""
-    return jax.lax.cond(jnp.sum(sizes) <= rows, lambda: path(rows),
-                        lambda: path(slots))
+def _fits(ladder, sizes, path):
+    """``path(rows)`` for the first rung of ``ladder`` that holds the
+    step's held slots; the last is a row for every slot, so nothing is
+    dropped."""
+    rung = jnp.sum(jnp.sum(sizes) > jnp.asarray(ladder[:-1]), dtype=jnp.int32)
+    return jax.lax.switch(rung, [partial(path, rows) for rows in ladder])
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bounded_experts(rows, x, weights, w_gate, w_up, w_down, order, inverse,
+def _bounded_experts(ladder, x, weights, w_gate, w_up, w_down, order, inverse,
                      sizes):
-    """:func:`_sorted_experts` over ``rows`` rows where the step fits,
-    over all the slots where not.  A ``cond``'s own transpose keeps the
-    union of both branches' residuals and writes noughts for the branch
-    not taken, the whole buffer's in every step; so what crosses from
-    forward to backward is the arguments alone, the same in both
-    branches, and the backward chooses again and recomputes its branch's
-    products (under ``jax.checkpoint`` the forward's then fall away)."""
-    args = (x, weights, w_gate, w_up, w_down)
-    return _fits(rows, len(order), sizes, lambda r: _sorted_experts(
-        r, *args, order, inverse, sizes))
+    """:func:`_sorted_experts` over the first rung of ``ladder`` that
+    the step fits.  A ``switch``'s own transpose keeps the union of all
+    branches' residuals and writes noughts for the branches not taken,
+    the whole buffer's in every step; so what crosses from forward to
+    backward is the arguments alone, the same in every branch, and the
+    backward chooses again and recomputes its branch's products (under
+    ``jax.checkpoint`` the forward's then fall away)."""
+    return _rung_forward(ladder, x, weights, w_gate, w_up, w_down, order,
+                         inverse, sizes)
 
 
-def _bounded_fwd(rows, *args):
-    return _bounded_experts(rows, *args), args
+# jitted, so that a model's expert layers, whose shapes are the same, are
+# traced and lowered once a direction and not once a layer with every rung
+# again: in the step, and in an un-jitted ``model.init``, where each layer's
+# ``switch`` would else be lowered and loaded as a program of its own
+@partial(jax.jit, static_argnums=(0,))
+def _rung_forward(ladder, x, weights, w_gate, w_up, w_down, order, inverse,
+                  sizes):
+    return _fits(ladder, sizes, lambda rows: _sorted_experts(
+        rows, x, weights, w_gate, w_up, w_down, order, inverse, sizes))
 
 
-def _bounded_bwd(rows, args, g):
+@partial(jax.jit, static_argnums=(0,))
+def _rung_backward(ladder, args, g):
     *diff, order, inverse, sizes = args
-    grads = _fits(rows, len(order), sizes, lambda r: jax.vjp(
-        lambda *a: _sorted_experts(r, *a, order, inverse, sizes), *diff)[1](g))
-    return (*grads, None, None, None)
+    return _fits(ladder, sizes, lambda rows: jax.vjp(
+        lambda *a: _sorted_experts(rows, *a, order, inverse, sizes),
+        *diff)[1](g))
+
+
+def _bounded_fwd(ladder, *args):
+    return _bounded_experts(ladder, *args), args
+
+
+def _bounded_bwd(ladder, args, g):
+    return (*_rung_backward(ladder, args, g), None, None, None)
 
 
 _bounded_experts.defvjp(_bounded_fwd, _bounded_bwd)
@@ -460,13 +492,13 @@ def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, experts, *,
     :func:`sigmoid_route` over ``experts`` experts; ``w_gate``, ``w_up``
     (held, D, M) and ``w_down`` (held, M, D).  The N*k token-slots are
     sorted by expert, the held experts' first and the absent experts'
-    behind them; the three grouped products run over the first
-    :func:`compact_rows` rows of that order with the held experts' group
-    sizes, and a slot behind them adds nought to its token without a row
-    being touched for it.  Nothing is dropped: a step whose held slots
-    overflow the bound takes a buffer with a row for every slot, and
-    where the bound is all the slots (most experts held) that buffer is
-    the only path and there is no branch.
+    behind them; the three grouped products run over the first rows of
+    that order with the held experts' group sizes, as many as the first
+    rung of :func:`compact_rows` that holds the step's held slots, and a
+    slot behind them adds nought to its token without a row being
+    touched for it.  Nothing is dropped: a step whose held slots
+    overflow every rung takes a buffer with a row for every slot, and
+    where that is the only rung (most experts held) there is no branch.
     """
     n, k, held = x.shape[0], chosen.shape[-1], w_gate.shape[0]
     local = chosen.reshape(-1) - first
@@ -476,6 +508,8 @@ def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, experts, *,
     inverse = jnp.argsort(order).reshape(n, k)
     sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
-    rows = compact_rows(n * k, held, experts)
-    path = _sorted_experts if rows == n * k else _bounded_experts
-    return path(rows, x, weights, w_gate, w_up, w_down, order, inverse, sizes)
+    ladder = compact_rows(n * k, held, experts)
+    args = (x, weights, w_gate, w_up, w_down, order, inverse, sizes)
+    if len(ladder) == 1:
+        return _sorted_experts(n * k, *args)
+    return _bounded_experts(ladder, *args)

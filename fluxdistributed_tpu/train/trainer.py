@@ -1176,12 +1176,16 @@ class _RouterCounters:
                             "expert layers of a step that ran over the "
                             "bounded buffer and over the whole one",
                             ("path",)),
+                reg.counter("fdtpu_moe_buffer_rows_total",
+                            "rows of the expert layers' sorted buffers "
+                            "that held a slot, and rows of the buffers "
+                            "the layers took", ("kind",)),
                 reg.histogram(
                     "fdtpu_moe_load_max_over_mean",
                     "a step's largest expert load over its mean load",
                     ("layer",), buckets=(1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0,
                                          16.0, 64.0)))
-        slots, dropped, paths, balance = self._made
+        slots, dropped, paths, rows, balance = self._made
         load = np.asarray(metrics["moe_load"], np.float64)
         load = load.reshape((-1,) + load.shape[-2:])  # steps_per_call > 1
         held, absent = np.asarray(
@@ -1193,6 +1197,10 @@ class _RouterCounters:
             metrics["moe_compact"], np.float64).reshape(-1, 2).sum(axis=0)
         paths.labels(path="compact").inc(float(compact))
         paths.labels(path="full").inc(float(full))
+        live, taken = np.asarray(
+            metrics["moe_rows"], np.float64).reshape(-1, 2).sum(axis=0)
+        rows.labels(kind="live").inc(float(live))
+        rows.labels(kind="taken").inc(float(taken))
         for step in load:
             for layer, row in enumerate(step):
                 mean = row.mean()

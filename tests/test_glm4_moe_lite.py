@@ -122,10 +122,11 @@ def test_the_flash_path_wants_one_head_size():
 
 # -- the expert layer ---------------------------------------------------------
 
-def skewed_layer(held, tokens=128, skew=True):
+def skewed_layer(held, tokens=128, skew=True, bias=0.0):
     """A layer's weights and tokens with the router skewed towards
     expert ``held[0] + 1``: its score is the largest for every token
-    (``skew=False``: the seeded router as it is, an even load)."""
+    (``skew=False``: the seeded router as it is, an even load), and a
+    selection bias of ``bias`` on the held experts."""
     cfg = tiny_cfg(experts_held=list(held), num_nextn_predict_layers=0)
     params, state = REF.make_params(cfg, jax.random.PRNGKey(7))
     p = copy.deepcopy(params["layer1"])
@@ -136,14 +137,19 @@ def skewed_layer(held, tokens=128, skew=True):
         x = x + 4.0 * mean
         p["moe"]["router"] = p["moe"]["router"].at[:, held[0] + 1].set(
             2.0 * mean)
-    return cfg, p, state["router"]["layer1"], x
+    state = copy.deepcopy(state["router"]["layer1"])
+    state["moe"]["bias"] = state["moe"]["bias"].at[
+        held[0]:held[0] + held[1]].add(bias)
+    return cfg, p, state, x
 
 
 def path_counted(cfg, router_state):
-    """[compact, full] as the model's step metrics count one layer."""
+    """[compact, full] and [live, taken] rows as the model's step
+    metrics count one layer."""
     model = models.glm4_moe_lite(**cfg["model"]["kwargs"])
-    return [int(n) for n in model.step_metrics(
-        {"router": {"layer1": router_state}})["moe_compact"]]
+    counted = model.step_metrics({"router": {"layer1": router_state}})
+    return ([int(n) for n in counted["moe_compact"]],
+            [int(n) for n in counted["moe_rows"]])
 
 
 def program_layer(cfg, p, state, x):
@@ -160,16 +166,24 @@ def program_layer(cfg, p, state, x):
     return y, shared, new["router"]
 
 
-# 128 tokens: the bound is all 512 slots, one path and no branch.  1,024
-# tokens: 4,096 slots, a bound of 1,024 rows; the even router holds some
-# 512 slots and fits, the skewed one over 1,300 and takes the whole buffer
-@pytest.mark.parametrize("held,tokens,skew,path", [
-    ((0, 8), 128, True, [0, 1]), ((0, 64), 128, True, [0, 1]),
-    ((24, 8), 128, True, [0, 1]), ((0, 8), 1024, True, [0, 1]),
-    ((0, 8), 1024, False, [1, 0]), ((24, 8), 1024, False, [1, 0])])
+# 128 tokens: the only rung is all 512 slots, one path and no branch.
+# 1,024 tokens: 4,096 slots, a ladder of 1,024, 1,536 and every slot; the
+# even router holds some 512 slots and takes the first, the skewed one
+# 1,300-1,536 and the second, a bias on the held experts the whole buffer.
+# 8,192 tokens: 32,768 slots and all four rungs (4,608, 6,144, 8,192,
+# 12,288); the even router holds some 4,800, and a selection bias on the
+# held experts lands the step on each rung in turn
+@pytest.mark.parametrize("held,tokens,skew,bias,taken", [
+    ((0, 8), 128, True, 0, 512), ((0, 64), 128, True, 0, 512),
+    ((24, 8), 128, True, 0, 512), ((0, 8), 1024, True, 0, 1536),
+    ((0, 8), 1024, False, 0, 1024), ((24, 8), 1024, False, 0, 1024),
+    ((24, 8), 1024, False, 0.15, 4096),
+    ((0, 8), 8192, False, -0.02, 4608), ((24, 8), 8192, False, 0, 6144),
+    ((0, 8), 8192, False, 0.05, 8192), ((24, 8), 8192, False, 0.1, 12288),
+    ((0, 8), 8192, False, 0.15, 32768)])
 def test_expert_layer_matches_the_reference_and_drops_nothing(
-        held, tokens, skew, path):
-    cfg, p, state, x = skewed_layer(held, tokens, skew)
+        held, tokens, skew, bias, taken):
+    cfg, p, state, x = skewed_layer(held, tokens, skew, bias)
     want, ref_state = jax.jit(
         lambda p, x: REF.expert_mlp(cfg, PREC, p, state, x))(p, x)
     y, shared, new = jax.jit(
@@ -182,9 +196,12 @@ def test_expert_layer_matches_the_reference_and_drops_nothing(
         # expert, and each is in the result (the reference has no capacity)
         assert load[held[0] + 1] == len(x)
     np.testing.assert_array_equal(load, np.asarray(ref_state["moe"]["load"]))
-    fits = load[held[0]:held[0] + held[1]].sum() <= ep.compact_rows(
-        4 * tokens, held[1], 64) < 4 * tokens
-    assert path_counted(cfg, new) == path == [int(fits), int(not fits)]
+    # the rung the layer's own ladder gives this load, from the step's metrics
+    live = int(load[held[0]:held[0] + held[1]].sum())
+    ladder = ep.compact_rows(4 * tokens, held[1], 64)
+    assert taken == next(rows for rows in ladder if live <= rows)
+    below = int(taken < 4 * tokens)
+    assert path_counted(cfg, new) == ([below, 1 - below], [live, taken])
     # gradients of the routed part, the router's among them
     w = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
 
@@ -211,21 +228,34 @@ def routed_grads(args, first):
         lambda *a: jnp.sum(out(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args), text
 
 
+# 8,192 tokens, 32,768 slots, 8 of 64 held: the ladder by hand, and held
+# slots that land on each rung in turn and on the whole buffer
+LADDER = (4608, 6144, 8192, 12288, 32768)
+
+
+@pytest.mark.parametrize("rung,live", enumerate((4000, 6144, 7000, 8193, 20000)))
 @pytest.mark.parametrize("first", [0, 24])
-def test_the_bounded_buffer_and_the_whole_one_agree(first, monkeypatch):
-    """The same step over 1,024 rows and over all 4,096: output and the
-    five gradients to float32 rounding, and both the reference's."""
-    cfg, p, state, x = skewed_layer((first, 8), 1024, skew=False)
-    chosen, weights, load = ep.sigmoid_route(
+def test_every_rung_and_the_whole_buffer_agree(first, rung, live, monkeypatch):
+    """The same step over the rung its held slots choose and over all
+    32,768 rows: output and the five gradients to float32 rounding, and
+    both the reference's."""
+    cfg, p, state, x = skewed_layer((first, 8), 8192, skew=False)
+    _, weights, _ = ep.sigmoid_route(
         x, p["moe"]["router"], state["moe"]["bias"], top_k=4,
         scale=cfg["routed_scaling_factor"])
-    assert float(load[first:first + 8].sum()) <= 1024
+    # the first ``live`` slots to the held experts, the others to absent ones
+    rng = np.random.default_rng(17 + rung)
+    chosen = (first + 8 + rng.integers(0, 56, 4 * len(x))) % 64
+    chosen[:live] = first + rng.integers(0, 8, live)
+    chosen = jnp.asarray(chosen.reshape(len(x), 4), jnp.int32)
+    assert ep.compact_rows(4 * len(x), 8, 64) == LADDER
+    assert LADDER[rung] == next(rows for rows in LADDER if live <= rows)
     part = {k: p["moe"][k] for k in ("w_gate", "w_up", "w_down")}
     args = (x, weights, *part.values(), chosen)
     bounded, text = routed_grads(args, first)
     assert " cond[" in text
-    # a bound of eight times the expected share is every slot
-    monkeypatch.setattr(ep, "COMPACT_OVER_EXPECTED", 8)
+    # no rung below every slot: one path, no branch
+    monkeypatch.setattr(ep, "COMPACT_OVER_EXPECTED", ())
     whole, text = routed_grads(args, first)
     assert " cond[" not in text
     trees_close(bounded, whole)
@@ -240,24 +270,41 @@ def test_the_bounded_buffer_and_the_whole_one_agree(first, monkeypatch):
     trees_close(bounded, want)
 
 
-@pytest.mark.parametrize("slots,held,experts,rows", [
-    (65536, 8, 64, 16384),    # the cell: twice the 8,192 expected
-    (65536, 64, 64, 65536),   # every expert held
-    (65536, 40, 64, 65536),   # over half of them
-    (512, 4, 8, 512),         # the tiny sizes
-    (4096, 8, 64, 1024), (3840, 8, 64, 1024)])  # up to a tile of 512
-def test_compact_rows_by_hand_and_no_branch_where_it_is_every_slot(
-        slots, held, experts, rows):
-    assert ep.compact_rows(slots, held, experts) == rows
-    # the same of an array of loads, as the step's metrics call it
-    assert int(ep.compact_rows(jnp.int32(slots), held, experts)) == rows
+@pytest.mark.parametrize("slots,held,experts,ladder", [
+    # the GLM cell: 1.125, 1.5, 2 and 3 times the 8,192 expected, every slot
+    (65536, 8, 64, (9216, 12288, 16384, 24576, 65536)),
+    # the LFM2 cell: the same of 16,384
+    (65536, 8, 32, (18432, 24576, 32768, 49152, 65536)),
+    (65536, 64, 64, (65536,)),   # every expert held
+    (65536, 60, 64, (65536,)),   # most of them: 1.125 times is over the slots
+    (65536, 40, 64, (46080, 61440, 65536)),  # the rungs under the slots stay
+    (512, 4, 8, (512,)),         # the tiny sizes
+    # up to a tile of 512, and a rung met twice counts once
+    (4096, 8, 64, (1024, 1536, 4096)), (3840, 8, 64, (1024, 1536, 3840))])
+def test_the_ladder_by_hand_and_no_branch_where_it_is_one_rung(
+        slots, held, experts, ladder):
+    assert ep.compact_rows(slots, held, experts) == ladder
+    assert len(ladder) <= 5 and all(r % 512 == 0 for r in ladder[:-1])
+    # the same of an array of loads, as the step's metrics call it: none left
+    # out, none over the slots
+    rungs = [int(r) for r in ep.compact_rows(jnp.int32(slots), held, experts)]
+    assert len(rungs) == 5 and sorted(set(rungs)) == list(ladder)
     n, k = slots // 64, 4  # the layer at a sixteenth of the slots, 4 a token
-    bound = ep.compact_rows(n * k, held, experts)
+    small = ep.compact_rows(n * k, held, experts)
     text = str(jax.make_jaxpr(
         lambda x, c, w, a, b: ep.held_experts_apply(x, c, w, a, a, b, experts))(
             jnp.zeros((n, 8)), jnp.zeros((n, k), jnp.int32), jnp.zeros((n, k)),
             jnp.zeros((held, 8, 4)), jnp.zeros((held, 4, 8))))
-    assert (" cond[" in text) == (bound < n * k)
+    assert (" cond[" in text) == (len(small) > 1)
+
+
+def test_the_ladder_keeps_what_the_chip_runs_fixed():
+    """No step gets a longer buffer than two lengths gave it: the rung
+    at twice the even share stays, one lies under 1.25 and one between 2
+    and every slot; four at most, each a compiled copy of the layer."""
+    over = ep.COMPACT_OVER_EXPECTED
+    assert list(over) == sorted(over) and len(over) <= 4
+    assert 2 in over and over[0] <= 1.25 and any(2 < o for o in over)
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
@@ -365,15 +412,15 @@ def test_eval_leaves_the_routers_alone_and_adds_no_multi_token_term():
     close(loss, models.next_token_loss(logits, tokens))
 
 
-@pytest.mark.parametrize("sizes,rows,paths", [
-    # 128 and 120 slots: the bound is every slot, no branch, counted whole
+@pytest.mark.parametrize("sizes,rows,paths,taken", [
+    # 128 and 120 slots: the only rung is every slot, no branch, counted whole
     (dict(router_experts=8, experts_held=[2, 4], num_experts_per_tok=2), 4,
-     [0, 2]),
+     [0, 2], 128 + 120),
     # 4,096 and 3,840 slots of which an eighth are held: 1,024 rows hold them
     (dict(router_experts=64, experts_held=[8, 8], num_experts_per_tok=4), 64,
-     [2, 0])])
+     [2, 0], 1024 + 1024)])
 def test_step_metrics_carry_the_load_through_the_step_to_the_counters(
-        sizes, rows, paths):
+        sizes, rows, paths, taken):
     """The step's metrics hold what the model reports of its routers,
     and the trainer's watcher callback feeds the registry from them; a
     model without a router reports and registers nothing."""
@@ -402,6 +449,7 @@ def test_step_metrics_carry_the_load_through_the_step_to_the_counters(
     assert held + absent == load.sum()
     assert float(metrics["moe_dropped"]) == 0.0
     assert [int(n) for n in metrics["moe_compact"]] == paths
+    assert [int(n) for n in metrics["moe_rows"]] == [held, taken]
     np.testing.assert_array_equal(
         load[0], np.asarray(new.model_state["router"]["layer1"]["moe"]["load"]))
 
@@ -419,6 +467,8 @@ def test_step_metrics_carry_the_load_through_the_step_to_the_counters(
     feed(metrics)  # a second step: its layers are counted on top
     assert [reg.value("fdtpu_moe_compact_total", path) / 2
             for path in ("compact", "full")] == paths
+    assert [reg.value("fdtpu_moe_buffer_rows_total", kind) / 2
+            for kind in ("live", "taken")] == [held, taken]
 
     dense = models.lm_tiny(vocab=64)
     assert not hasattr(models.lm_loss_fn(dense), "step_metrics")

@@ -242,7 +242,8 @@ def program_layer(cfg, p, state, x):
     return y, new["router"]
 
 
-# 1,024 tokens, 4,096 slots: at 8 of 32 held the bound is 2,048 rows
+# 1,024 tokens, 4,096 slots: at 8 of 32 held the ladder is 1,536, 2,048, 3,072
+# and every slot
 @pytest.mark.parametrize("held,tokens", [
     ((0, 8), 128), ((0, 32), 128), ((24, 8), 128), ((0, 8), 1024)])
 def test_expert_layer_matches_the_reference_and_drops_nothing(held, tokens):
@@ -260,7 +261,10 @@ def test_expert_layer_matches_the_reference_and_drops_nothing(held, tokens):
     assert float(counted["moe_dropped"]) == 0.0
     slots_here = load[held[0]:held[0] + held[1]].sum()
     assert float(counted["moe_slots"][0]) == slots_here
-    fits = slots_here <= ep.compact_rows(4 * tokens, held[1], 32) < 4 * tokens
+    taken = next(rows for rows in ep.compact_rows(4 * tokens, held[1], 32)
+                 if slots_here <= rows)
+    assert [int(n) for n in counted["moe_rows"]] == [slots_here, taken]
+    fits = taken < 4 * tokens
     assert [int(n) for n in counted["moe_compact"]] == [int(fits), int(not fits)]
     w = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
     trees_close(

@@ -257,39 +257,50 @@ def test_eva_attention_calls_at_the_cells_widths(chip, as_on_tpu):
     assert get_registry().value("fdtpu_eva_pairs", "local") == 8392704
 
 
-def test_held_experts_grouped_products(chip):
-    """The same cell's expert layer: 16,384 tokens, 4 of 64 experts a
+@pytest.mark.parametrize("experts,moe_dim,scale", [
+    (64, 1536, 1.8), (32, 1792, 1.0)], ids=["glm47_flash", "lfm2_8b_a1b"])
+def test_held_experts_grouped_products(chip, experts, moe_dim, scale):
+    """The expert cells' layer: 16,384 tokens, 4 of ``experts`` experts a
     token, 8 held; XLA lowers `ragged_dot` to its grouped-matmul kernel,
-    forward and both gradients, under the name the reducer reads.  The
-    backward is a conditional: one branch over the 16,384 rows the chip
-    can expect to hold, one over all 65,536 slots for a step that
-    overflows them, and the first makes no product as long as the
+    forward and both gradients, under the name the reducer reads.  Each
+    direction is one conditional with a branch a rung of the ladder:
+    three products forward, nine backward (the forward's again and six
+    gradients), and only the last rung makes an array as long as the
     slots."""
     import re
 
     from fluxdistributed_tpu.parallel import ep
 
     x = chip((16384, 2048), BF)
-    router = chip((2048, 64), jnp.float32)
-    w_in, w_out = chip((8, 2048, 1536), jnp.float32), chip((8, 1536, 2048), jnp.float32)
+    router = chip((2048, experts), jnp.float32)
+    w_in = chip((8, 2048, moe_dim), jnp.float32)
+    w_out = chip((8, moe_dim, 2048), jnp.float32)
 
     def layer(x, router, w_gate, w_up, w_down):
         chosen, weights, _ = ep.sigmoid_route(
-            x, router, jnp.zeros((64,)), top_k=4, scale=1.8)
-        return ep.held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, 64)
+            x, router, jnp.zeros((experts,)), top_k=4, scale=scale)
+        return ep.held_experts_apply(x, chosen, weights, w_gate, w_up, w_down,
+                                     experts)
 
-    text = _compile(jax.grad(
+    text = _compile(jax.value_and_grad(
         lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)),
         x, router, w_in, w_in, w_out)
-    (names,) = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}", text)
-    whole, bounded = (  # branch 0 is the predicate's False
-        text[text.index(f"\n{name.strip()} ("):].split("\n}\n", 1)[0]
-        for name in names.split(","))
-    for branch in (whole, bounded):
-        # 3 forward again and 6 gradients, each defined and then used
-        assert len(re.findall(r"%ragged-dot-none[.\d]* = ", branch)) == 9
-    assert "[65536,1536]" in whole and "[16384,1536]" not in whole
-    assert "[16384,1536]" in bounded and "[65536," not in bounded
+    ladder = ep.compact_rows(65536, 8, experts)
+    assert len(ladder) == 5 and ladder[-1] == 65536
+    conditionals = re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+    products = []
+    for names in conditionals:
+        branches = [text[text.index(f"\n{name.strip()} ("):].split("\n}\n", 1)[0]
+                    for name in names.split(",")]
+        assert len(branches) == len(ladder)
+        for rows, branch in zip(ladder, branches):
+            assert f"[{rows},{moe_dim}]" in branch
+            assert ("[65536," in branch) == (rows == 65536)
+        (count,) = {len(re.findall(r"%ragged-dot-none[.\d]* = ", branch))
+                    for branch in branches}
+        products.append(count)
+    assert sorted(products) == [3, 9]  # forward; backward with its forward
 
 
 @pytest.mark.parametrize("hkv", [H, HKV], ids=["dense", "gqa"])
