@@ -24,9 +24,11 @@ of that work is inherently per-process — this module makes it durable:
   allocator warm-up is paid before timing or traffic starts.  The serve
   side's analog is :meth:`LMEngine.warmup`.
 
-Everything reports through the obs registry: AOT loads/compiles are
-counters (``fdtpu_aot_loads_total`` / ``fdtpu_aot_compiles_total``) and
-the cache's own hit/miss stream lands via :mod:`obs.jaxmon`.
+Everything reports through the obs registry and the process tracer:
+AOT loads/compiles are counters (``fdtpu_aot_loads_total`` /
+``fdtpu_aot_compiles_total``) and each an ``aot`` span with its
+``source``, a warm-up is a ``warmup`` span, and the cache's own hit/miss
+stream lands via :mod:`obs.jaxmon`.
 """
 
 from __future__ import annotations
@@ -335,37 +337,30 @@ def load_or_compile(
     a jaxlib upgrade, a different device count, or a shape change each
     select a different file, so a mismatch is an automatic miss, not a
     crash.  Outcomes are counted in the obs registry
-    (``fdtpu_aot_loads_total`` / ``fdtpu_aot_compiles_total``) and the
-    load/compile seconds accumulate in
-    ``fdtpu_aot_seconds_total{source=...}``.
+    (``fdtpu_aot_loads_total`` / ``fdtpu_aot_compiles_total``); the
+    seconds are the ``aot`` span's, whose ``source`` says ``"load"`` or
+    ``"compile"`` (a failed look for a file counts with the compile).
     """
-    from .obs import get_registry
+    from .obs import get_registry, get_tracer
 
     reg = registry or get_registry()
     fp = fingerprint or topology_fingerprint()
     sig = abstract_signature(args, kwargs)
     path = os.path.join(directory, f"{name}-{fp}-{sig}{AOT_SUFFIX}")
-    secs = reg.histogram(
-        "fdtpu_aot_seconds_total",
-        "wall seconds loading or compiling AOT executables",
-        labelnames=("source",),
-    )
-    t0 = time.perf_counter()
-    compiled = load_executable(path, fingerprint=fp)
-    if compiled is not None:
-        reg.counter(
-            "fdtpu_aot_loads_total",
-            "AOT executables deserialized from disk (compile skipped)",
-        ).inc()
-        secs.labels(source="load").observe(time.perf_counter() - t0)
-        return compiled
-    t0 = time.perf_counter()
-    compiled = aot_compile(fn, *args, **(kwargs or {}))
+    with get_tracer().span("aot", source="load") as span:
+        compiled = load_executable(path, fingerprint=fp)
+        if compiled is not None:
+            reg.counter(
+                "fdtpu_aot_loads_total",
+                "AOT executables deserialized from disk (compile skipped)",
+            ).inc()
+            return compiled
+        span.args["source"] = "compile"
+        compiled = aot_compile(fn, *args, **(kwargs or {}))
     reg.counter(
         "fdtpu_aot_compiles_total",
         "AOT executables compiled fresh (no matching serialized file)",
     ).inc()
-    secs.labels(source="compile").observe(time.perf_counter() - t0)
     if save:
         try:
             save_executable(path, compiled, fingerprint=fp)
@@ -421,19 +416,20 @@ def warmup_train(task, batch, *, eval_too: bool = True) -> dict:
     """
     import jax
 
-    from .obs import jaxmon
+    from .obs import get_tracer, jaxmon
 
     jaxmon.install()
     c0, s0 = jaxmon.compile_count(), jaxmon.compile_seconds()
     t0 = time.perf_counter()
-    dummy_state = _sharded_zeros_like(task.state)
-    out = task.step_fn(dummy_state, batch)
-    jax.block_until_ready(jax.tree.leaves(out))
-    if eval_too and task.val_batch is not None:
-        # the dummy state was (possibly) donated to the step above —
-        # eval gets its own fresh zeros
-        ev = task.eval_fn(_sharded_zeros_like(task.state), task.val_batch)
-        jax.block_until_ready(jax.tree.leaves(ev))
+    with get_tracer().span("warmup"):
+        dummy_state = _sharded_zeros_like(task.state)
+        out = task.step_fn(dummy_state, batch)
+        jax.block_until_ready(jax.tree.leaves(out))
+        if eval_too and task.val_batch is not None:
+            # the dummy state was (possibly) donated to the step above —
+            # eval gets its own fresh zeros
+            ev = task.eval_fn(_sharded_zeros_like(task.state), task.val_batch)
+            jax.block_until_ready(jax.tree.leaves(ev))
     return {
         "seconds": time.perf_counter() - t0,
         "compiles": jaxmon.compile_count() - c0,

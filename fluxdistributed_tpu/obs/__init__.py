@@ -58,7 +58,6 @@ from .server import MetricsServer, start_metrics_server
 from .spans import (
     CompletionWatcher,
     SpanTracer,
-    current_item,
     current_span,
     get_tracer,
     innermost_active,
@@ -84,7 +83,6 @@ __all__ = [
     "bucket_percentile",
     "collect_profile",
     "comms",
-    "current_item",
     "current_span",
     "get_registry",
     "get_tracer",

@@ -6,10 +6,16 @@ and did the device starve meanwhile" continuously and for pennies.  The
 trainer opens an ``item`` span per loader item with ``data_wait`` /
 ``dispatch`` / ``eval`` / ``checkpoint`` children, the prefetch workers
 record ``assemble`` and ``h2d``, a :class:`CompletionWatcher` closes a
-``device`` span when the item's step has really finished, and
-:mod:`.jaxmon` records every backend ``compile``.  All spans of one
-loader item carry its ``item`` id and the ``parent`` that caused them,
-so a timeline reader joins them without guessing.
+``device`` span when the item's step has really finished (with
+``ahead``: how far the loop had run ahead of the device), and
+:mod:`.jaxmon` records every ``trace``, ``lower`` and backend
+``compile`` with the program's name and what the compile cache did.
+All spans of one loader item carry its ``item`` id and the ``parent``
+that caused them, so a timeline reader joins them without guessing.
+The set-up lies on the same timeline: ``prepare_training`` is a
+``prepare`` span with a child for each phase that ran (``cache_enable``,
+``model_init``, ``step_build``, ``aot``, ``warmup``), each call of
+``train`` a ``train`` span that its items name as their parent.
 
 The same brackets also open ``jax.profiler`` annotations
 (``fdtpu/<name>`` with the ``item`` stat; the ``item`` span is a
@@ -40,8 +46,8 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 __all__ = [
     "CompletionWatcher",
     "SpanTracer",
-    "current_item",
     "current_span",
+    "enclosing",
     "get_tracer",
     "innermost_active",
 ]
@@ -108,16 +114,18 @@ def innermost_active() -> Optional[str]:
     return span.name if span is not None else None
 
 
-def current_item() -> Optional[int]:
-    """The loader item the caller works for: the ``item`` of the
-    innermost open span of the calling context, else of the newest open
-    span anywhere that has one (a compile on a thread without spans
-    falls during the loop's current item)."""
+def enclosing() -> dict:
+    """``parent`` and ``item`` for a span reported from outside any
+    bracket (a compile, a trace, a lowering: :mod:`.jaxmon` learns of
+    each when it is over): the innermost open span of the calling
+    context and its loader item, else the newest open item anywhere."""
     s = _stack.get()
-    if s and s[-1].item is not None:
-        return s[-1].item
+    if s:
+        inner = s[-1]
+        return {"parent": inner.name,
+                **({} if inner.item is None else {"item": inner.item})}
     span = _newest_active(lambda sp: sp.item is not None)
-    return span.item if span is not None else None
+    return {} if span is None else {"parent": "item", "item": span.item}
 
 
 class _Span:
@@ -266,21 +274,37 @@ class CompletionWatcher:
     is recorded on the span and never raised into the loop.
     ``on_value`` gets each completed value in the same thread (the
     trainer feeds a router's counters from the step's metrics there).
+
+    Each ``device`` span also says how far the loop ran ahead of the
+    device: ``ahead`` is the number of items handed over before this one
+    and not yet complete when this one was handed over (0 for a loop in
+    lockstep with the device).  ``on_ahead`` gets the same number in the
+    caller's thread (the trainer sets a gauge with it).
     """
 
     def __init__(self, tracer: SpanTracer,
                  on_done: Optional[Callable[[float], None]] = None,
-                 on_value: Optional[Callable[[object], None]] = None):
+                 on_value: Optional[Callable[[object], None]] = None,
+                 on_ahead: Optional[Callable[[int], None]] = None):
         self._tracer = tracer
         self._on_done = on_done
         self._on_value = on_value
+        self._on_ahead = on_ahead
+        # each written by one thread only (the caller's, the watcher's)
+        # and read by the other: a count a moment old is a count
+        self._handed = 0
+        self._closed = 0
         self._queue: queue.SimpleQueue = queue.SimpleQueue()
         self._thread = threading.Thread(
             target=self._run, name="fdtpu-completion-watcher", daemon=True)
         self._thread.start()
 
     def watch(self, item: int, value, dispatched: float) -> None:
-        self._queue.put((item, value, dispatched))
+        ahead = self._handed - self._closed
+        self._handed += 1
+        if self._on_ahead is not None:
+            self._on_ahead(ahead)
+        self._queue.put((item, value, dispatched, ahead))
 
     def _run(self) -> None:
         import jax
@@ -290,13 +314,14 @@ class CompletionWatcher:
             job = self._queue.get()
             if job is None:
                 return
-            item, value, dispatched = job
-            args = {"item": item, "parent": "dispatch"}
+            item, value, dispatched, ahead = job
+            args = {"item": item, "parent": "dispatch", "ahead": ahead}
             try:
                 jax.block_until_ready(value)
             except Exception as e:  # noqa: BLE001 - the loop meets it itself
                 args["error"] = f"{type(e).__name__}: {e}"[:500]
             done = time.perf_counter()
+            self._closed += 1
             if self._on_value is not None and "error" not in args:
                 # the value is ready: reading it here waits for nothing
                 try:

@@ -29,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import copy
 import dataclasses
+import functools
 import os
 import sys
 import time
@@ -117,6 +118,19 @@ def _eval_view(dataset):
     return dataset
 
 
+def _in_span(name: str, args: Callable[..., dict] = lambda *a, **kw: {}):
+    """The whole of each call of the function as one span of the process
+    tracer (``args`` makes the span's arguments from the call's), so
+    every span the call opens names it as its parent."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def spanned(*a, **kw):
+            with get_tracer().span(name, **args(*a, **kw)):
+                return fn(*a, **kw)
+        return spanned
+    return deco
+
+
 #: the spmd= names that were a second spelling of a layout, each with
 #: the layout= that says the same
 _RETIRED_SPMD = {
@@ -126,6 +140,7 @@ _RETIRED_SPMD = {
 }
 
 
+@_in_span("prepare")
 def prepare_training(
     model,
     dataset,
@@ -279,8 +294,14 @@ def prepare_training(
 
     from .. import compilation
 
+    # the set-up on the program's own timeline: the whole call is a
+    # ``prepare`` span, and what follows opens its children where the
+    # work happens (a phase that does not run leaves no span)
+    span = get_tracer().span
+    jaxmon.install()  # every compile, trace and lowering below is a span
     if cache_dir or os.environ.get(compilation.CACHE_DIR_ENV):
-        compilation.enable_persistent_cache(cache_dir)
+        with span("cache_enable"):
+            compilation.enable_persistent_cache(cache_dir)
 
     if spmd == "dp":  # explicit-name alias for the auto-sharded DP path
         spmd = "jit"
@@ -377,22 +398,24 @@ def prepare_training(
     # those execute it during init, and a batch of 1 cannot shard over
     # a >1 data axis.  Other modes keep the cheap single-sample init.
     ninit = mesh.shape.get(mesh_lib.DATA_AXIS, 1) if spmd in ("sp", "ep") else 1
-    if input_shape is not None:
-        dummy = np.zeros((ninit, *input_shape), np.float32)
-    else:
-        # draw real samples so init sees the dataset's true shape AND
-        # dtype (f32 images, int32 tokens, ...); kept for the pp_1f1b
-        # mask probe below so startup draws only once
-        from ..data.loader import model_input
+    with span("model_init"):
+        if input_shape is not None:
+            dummy = np.zeros((ninit, *input_shape), np.float32)
+        else:
+            # draw real samples so init sees the dataset's true shape AND
+            # dtype (f32 images, int32 tokens, ...); kept for the pp_1f1b
+            # mask probe below so startup draws only once
+            from ..data.loader import model_input
 
-        init_draw = apply_transform(
-            transform, dataset.batch(np.random.default_rng(0), ninit))
-        dummy = model_input(init_draw)
+            init_draw = apply_transform(
+                transform, dataset.batch(np.random.default_rng(0), ninit))
+            dummy = model_input(init_draw)
 
-    p_rng, d_rng = jax.random.split(jax.random.PRNGKey(seed))
-    # 'dropout' stream present at init so stochastic models (ViT dropout,
-    # ConvNeXt drop-path) initialize under train=True
-    variables = model.init({"params": p_rng, "dropout": d_rng}, dummy, train=True)
+        p_rng, d_rng = jax.random.split(jax.random.PRNGKey(seed))
+        # 'dropout' stream present at init so stochastic models (ViT
+        # dropout, ConvNeXt drop-path) initialize under train=True
+        variables = model.init(
+            {"params": p_rng, "dropout": d_rng}, dummy, train=True)
     params = variables["params"]
     model_state = {k: v for k, v in variables.items() if k != "params"}  # e.g. batch_stats
 
@@ -410,10 +433,11 @@ def prepare_training(
         # from the annotations
         from ..parallel import layout as layout_lib
 
-        state, sh = layout_lib.shard_state(
-            model,
-            TrainState.create(params, optimizer, model_state=model_state),
-            layout, mesh)
+        with span("model_init"):
+            state, sh = layout_lib.shard_state(
+                model,
+                TrainState.create(params, optimizer, model_state=model_state),
+                layout, mesh)
         batch_axes = layout.batch_axes
         if batch_size % layout.batch_shards:
             raise ValueError(
@@ -421,13 +445,14 @@ def prepare_training(
                 f"layout's dp x fsdp = {layout.batch_shards} "
                 f"({layout.describe()})")
         batch_quantum = layout.batch_shards
-        step_fn = make_train_step(
-            loss_fn, optimizer, mesh, axis=batch_axes,
-            donate=donate, accum_steps=accum_steps, seed=seed,
-            state_shardings=sh, guard=guard)
-        eval_fn = make_eval_step(
-            loss_fn, mesh, axis=batch_axes, topk=tuple(topk),
-            state_shardings=sh)
+        with span("step_build"):
+            step_fn = make_train_step(
+                loss_fn, optimizer, mesh, axis=batch_axes,
+                donate=donate, accum_steps=accum_steps, seed=seed,
+                state_shardings=sh, guard=guard)
+            eval_fn = make_eval_step(
+                loss_fn, mesh, axis=batch_axes, topk=tuple(topk),
+                state_shardings=sh)
     elif spmd in ("pp", "pp_1f1b"):
         # Pipeline-parallel LM training as a first-class trainer mode:
         # decoder blocks stage-sharded over a 'pipe' axis, composed with
@@ -522,54 +547,59 @@ def prepare_training(
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             w = lm_pp_1f1b(model, mesh, interleave=True)
-            state = TrainState.create(w.split_params(params), optimizer)
-            sh = w.state_shardings(state)
-            state = jax.tree.map(jax.device_put, state, sh)
-            step_fn = make_train_step_1f1b(
-                *w.fns, optimizer, mesh, num_microbatches=M,
-                batch_axis=mesh_lib.DATA_AXIS, interleave=w.interleave,
-                donate=donate, schedule=pipeline_schedule,
-            )(state)
-            eval_run = pipeline_grads_1f1b(
-                *w.fns, mesh, num_microbatches=M,
-                batch_axis=mesh_lib.DATA_AXIS, interleave=w.interleave,
-            )
-
-            def _eval(state, batch):
-                loss, _, _ = eval_run(
-                    state.params["stages"], state.params["outer"],
-                    batch["tokens"], batch["tokens"],
-                )
-                return loss, {}
-
-            eval_fn = jax.jit(
-                _eval,
-                in_shardings=(sh, NamedSharding(mesh, P(mesh_lib.DATA_AXIS))),
-            )
-        else:
-            split_params, pp_loss_fn, shardings_fn = lm_pp(
-                model, mesh, batch_axis=mesh_lib.DATA_AXIS,
-                num_microbatches=M, boundaries=boundaries,
-            )
-            state = TrainState.create(split_params(params), optimizer)
-            sh = shardings_fn(state)
-            state = jax.tree.map(jax.device_put, state, sh)
-            if spmd == "pp":
-                step_fn = make_train_step(
-                    pp_loss_fn, optimizer, mesh, axis=mesh_lib.DATA_AXIS,
-                    donate=donate, state_shardings=sh, guard=guard,
-                )
-            else:
-                w = lm_pp_1f1b(model, mesh, boundaries=boundaries)
+            with span("model_init"):
+                state = TrainState.create(w.split_params(params), optimizer)
+                sh = w.state_shardings(state)
+                state = jax.tree.map(jax.device_put, state, sh)
+            with span("step_build"):
                 step_fn = make_train_step_1f1b(
                     *w.fns, optimizer, mesh, num_microbatches=M,
                     batch_axis=mesh_lib.DATA_AXIS, interleave=w.interleave,
                     donate=donate, schedule=pipeline_schedule,
                 )(state)
-            # eval through the GPipe forward: same tree, same shardings
-            eval_fn = make_eval_step(
-                pp_loss_fn, mesh, topk=tuple(topk), state_shardings=sh
+                eval_run = pipeline_grads_1f1b(
+                    *w.fns, mesh, num_microbatches=M,
+                    batch_axis=mesh_lib.DATA_AXIS, interleave=w.interleave,
+                )
+
+                def _eval(state, batch):
+                    loss, _, _ = eval_run(
+                        state.params["stages"], state.params["outer"],
+                        batch["tokens"], batch["tokens"],
+                    )
+                    return loss, {}
+
+                eval_fn = jax.jit(
+                    _eval,
+                    in_shardings=(sh,
+                                  NamedSharding(mesh, P(mesh_lib.DATA_AXIS))),
+                )
+        else:
+            split_params, pp_loss_fn, shardings_fn = lm_pp(
+                model, mesh, batch_axis=mesh_lib.DATA_AXIS,
+                num_microbatches=M, boundaries=boundaries,
             )
+            with span("model_init"):
+                state = TrainState.create(split_params(params), optimizer)
+                sh = shardings_fn(state)
+                state = jax.tree.map(jax.device_put, state, sh)
+            with span("step_build"):
+                if spmd == "pp":
+                    step_fn = make_train_step(
+                        pp_loss_fn, optimizer, mesh, axis=mesh_lib.DATA_AXIS,
+                        donate=donate, state_shardings=sh, guard=guard,
+                    )
+                else:
+                    w = lm_pp_1f1b(model, mesh, boundaries=boundaries)
+                    step_fn = make_train_step_1f1b(
+                        *w.fns, optimizer, mesh, num_microbatches=M,
+                        batch_axis=mesh_lib.DATA_AXIS, interleave=w.interleave,
+                        donate=donate, schedule=pipeline_schedule,
+                    )(state)
+                # eval through the GPipe forward: same tree, same shardings
+                eval_fn = make_eval_step(
+                    pp_loss_fn, mesh, topk=tuple(topk), state_shardings=sh
+                )
     elif spmd == "ep":
         # MoE expert parallelism as a trainer mode: expert-stacked
         # leaves shard over the 'expert' axis, tokens ride the 'data'
@@ -597,14 +627,19 @@ def prepare_training(
         if not custom_loss_fn:
             loss_fn = lm_loss_fn(model)  # token protocol, not image loss
         topk = ()  # image metrics can never apply to the LM
-        state = TrainState.create(params, optimizer, model_state=model_state)
-        sh = make_shardings(train_state_specs(state, lm_moe_specs(params)), mesh)
-        state = jax.tree.map(jax.device_put, state, sh)
-        step_fn = make_train_step(
-            loss_fn, optimizer, mesh, axis=mesh_lib.DATA_AXIS,
-            donate=donate, seed=seed, state_shardings=sh, guard=guard,
-        )
-        eval_fn = make_eval_step(loss_fn, mesh, topk=(), state_shardings=sh)
+        with span("model_init"):
+            state = TrainState.create(
+                params, optimizer, model_state=model_state)
+            sh = make_shardings(
+                train_state_specs(state, lm_moe_specs(params)), mesh)
+            state = jax.tree.map(jax.device_put, state, sh)
+        with span("step_build"):
+            step_fn = make_train_step(
+                loss_fn, optimizer, mesh, axis=mesh_lib.DATA_AXIS,
+                donate=donate, seed=seed, state_shardings=sh, guard=guard,
+            )
+            eval_fn = make_eval_step(
+                loss_fn, mesh, topk=(), state_shardings=sh)
     else:
         if spmd not in ("jit", "shard_map", "sp"):
             raise ValueError(
@@ -632,73 +667,82 @@ def prepare_training(
             # over the data axis (parallel/zero1.py)
             from ..parallel import zero1 as zero1_lib
 
-            state, z_sh = zero1_lib.zero1_state(
-                params, optimizer, mesh, model_state=model_state
-            )
-            if spmd == "shard_map":
-                step_fn = zero1_lib.make_train_step_zero1_shardmap(
-                    loss_fn, optimizer, mesh, state, donate=donate, seed=seed
+            with span("model_init"):
+                state, z_sh = zero1_lib.zero1_state(
+                    params, optimizer, mesh, model_state=model_state
                 )
-            else:
-                step_fn = zero1_lib.make_train_step_zero1(
-                    loss_fn, optimizer, mesh, z_sh,
-                    donate=donate, accum_steps=accum_steps, seed=seed,
-                    steps_per_call=steps_per_call, guard=guard,
+            with span("step_build"):
+                if spmd == "shard_map":
+                    step_fn = zero1_lib.make_train_step_zero1_shardmap(
+                        loss_fn, optimizer, mesh, state,
+                        donate=donate, seed=seed
+                    )
+                else:
+                    step_fn = zero1_lib.make_train_step_zero1(
+                        loss_fn, optimizer, mesh, z_sh,
+                        donate=donate, accum_steps=accum_steps, seed=seed,
+                        steps_per_call=steps_per_call, guard=guard,
+                    )
+                eval_fn = make_eval_step(
+                    loss_fn, mesh, topk=tuple(topk), state_shardings=z_sh
                 )
-            eval_fn = make_eval_step(
-                loss_fn, mesh, topk=tuple(topk), state_shardings=z_sh
-            )
         else:
-            if spmd == "shard_map":
-                from ..parallel.dp import make_train_step_shardmap as maker
+            with span("step_build"):
+                if spmd == "shard_map":
+                    from ..parallel.dp import (
+                        make_train_step_shardmap as maker)
 
-                step_fn = maker(loss_fn, optimizer, mesh, donate=donate, seed=seed)
-            else:
-                step_fn = make_train_step(
-                    loss_fn, optimizer, mesh,
-                    donate=donate, accum_steps=accum_steps, seed=seed,
-                    steps_per_call=steps_per_call, guard=guard,
+                    step_fn = maker(
+                        loss_fn, optimizer, mesh, donate=donate, seed=seed)
+                else:
+                    step_fn = make_train_step(
+                        loss_fn, optimizer, mesh,
+                        donate=donate, accum_steps=accum_steps, seed=seed,
+                        steps_per_call=steps_per_call, guard=guard,
+                    )
+                eval_fn = make_eval_step(loss_fn, mesh, topk=tuple(topk))
+
+            with span("model_init"):
+                state = TrainState.create(
+                    sharding_lib.replicate(params, mesh),
+                    optimizer,
+                    model_state=sharding_lib.replicate(model_state, mesh),
                 )
-            eval_fn = make_eval_step(loss_fn, mesh, topk=tuple(topk))
 
-            state = TrainState.create(
-                sharding_lib.replicate(params, mesh),
-                optimizer,
-                model_state=sharding_lib.replicate(model_state, mesh),
+    with span("step_build"):
+        loader = PrefetchLoader(
+            dataset,
+            mesh,
+            batch_size,
+            cycles=cycles,
+            epochs=epochs,
+            buffersize=buffersize,
+            seed=seed,
+            axis=batch_axes,
+            transform=transform,
+            chunk=steps_per_call,
+        )
+
+        val_batch = None
+        if val_dataset is not None:
+            # divisible val slice: a data-axis multiple, and for pipeline
+            # modes a multiple of data_size x microbatches (the compiled
+            # eval reshapes each data shard into M microbatches)
+            q = batch_quantum or mesh.shape[mesh_lib.DATA_AXIS]
+            nval = max(q, (val_samples // q) * q)
+            # Validation must go through the eval pipeline even when the
+            # val dataset was carved from an augmenting train table.
+            vdraw = apply_transform(
+                transform,
+                _eval_view(val_dataset).batch(
+                    np.random.default_rng(seed + 1), nval),
             )
+            from ..data.loader import batch_to_dict
 
-    loader = PrefetchLoader(
-        dataset,
-        mesh,
-        batch_size,
-        cycles=cycles,
-        epochs=epochs,
-        buffersize=buffersize,
-        seed=seed,
-        axis=batch_axes,
-        transform=transform,
-        chunk=steps_per_call,
-    )
-
-    val_batch = None
-    if val_dataset is not None:
-        # divisible val slice: a data-axis multiple, and for pipeline
-        # modes a multiple of data_size x microbatches (the compiled
-        # eval reshapes each data shard into M microbatches)
-        q = batch_quantum or mesh.shape[mesh_lib.DATA_AXIS]
-        nval = max(q, (val_samples // q) * q)
-        # Validation must go through the eval pipeline even when the val
-        # dataset was carved from an augmenting train table.
-        vdraw = apply_transform(
-            transform,
-            _eval_view(val_dataset).batch(np.random.default_rng(seed + 1), nval),
-        )
-        from ..data.loader import batch_to_dict
-
-        val_batch = sharding_lib.shard_batch(
-            batch_to_dict(vdraw, getattr(val_dataset, "nclasses", None)),
-            mesh, axis=batch_axes,
-        )
+            val_batch = sharding_lib.shard_batch(
+                batch_to_dict(vdraw, getattr(val_dataset, "nclasses", None)),
+                mesh, axis=batch_axes,
+            )
 
     task = TrainTask(
         state=state,
@@ -720,12 +764,15 @@ def prepare_training(
     # device, while the step RETURNS them committed to the replicated
     # sharding: left alone, the second step call sees a new input
     # signature and compiles the whole train step a second time
-    task.state = _commit_replicated_stragglers(task.state, mesh)
+    with span("model_init"):
+        task.state = _commit_replicated_stragglers(task.state, mesh)
 
     if aot or warmup:
-        dummy = _dummy_batch(
-            dataset, transform, batch_size, mesh, steps_per_call, seed,
-            axis=batch_axes)
+        # the batch both compile against counts with the first to use it
+        with span("aot" if aot else "warmup"):
+            dummy = _dummy_batch(
+                dataset, transform, batch_size, mesh, steps_per_call, seed,
+                axis=batch_axes)
         if aot:
             # the tag covers everything that changes the compiled
             # program WITHOUT changing argument shapes: mode/schedule
@@ -1286,6 +1333,8 @@ class _PhaseClock:
                 self.watchdog.note_headroom(memstats.min_headroom_ratio())
 
 
+@_in_span("train", lambda task, **kw: {
+    "start_item": int(getattr(task.loader, "start", 0))})
 def train(
     task: TrainTask,
     *,
@@ -1436,7 +1485,11 @@ def train(
     # a thread of its own: the loop is never blocked to learn it
     watcher = CompletionWatcher(
         phases.tracer, phases.hist.labels(phase="device").observe,
-        on_value=_RouterCounters(reg))
+        on_value=_RouterCounters(reg),
+        on_ahead=reg.gauge(
+            "fdtpu_train_items_ahead",
+            "loader items handed to the device and not yet complete when "
+            "the newest was handed over (how far the loop runs ahead)").set)
 
     it = iter(task.loader)
     _end = object()

@@ -452,6 +452,262 @@ def test_jaxmon_counts_compiles_and_flags_steady_recompiles():
 
 
 # ---------------------------------------------------------------------------
+# the set-up on the timeline: compiles by program and cache outcome, traces
+# and lowerings, prepare_training's phases, the loop's lead over the device
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cache_dir(tmp_path, monkeypatch):
+    """A persistent compile cache of this test's own; the process's
+    cache config is left as it was found."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from fluxdistributed_tpu import compilation
+
+    monkeypatch.delenv(compilation.CACHE_DIR_ENV, raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    yield compilation.enable_persistent_cache(str(tmp_path / "cache"))
+    jax.config.update("jax_compilation_cache_dir", prev)
+    cc.reset_cache()
+    compilation._cache_dir = None
+
+
+def _probe_program(k):
+    """A program no other test compiles, under a name of its own."""
+    def fdtpu_obs_probe(x):
+        return x * k + 4099
+    return fdtpu_obs_probe
+
+
+def _spans_of(events, fun):
+    return {e["name"]: e for e in events
+            if fun in (e.get("args") or {}).get("fun_name", "")}
+
+
+def test_compile_span_names_the_program_and_what_the_cache_did(cache_dir):
+    import jax
+    import jax.numpy as jnp
+
+    from fluxdistributed_tpu.obs import get_tracer
+
+    tracer, reg = get_tracer(), get_registry()
+    loaded = reg.value("fdtpu_jax_cache_load_seconds_total")
+    x = jnp.ones(11)
+    rows = []
+    for _ in range(2):
+        tracer.clear()
+        # a fresh lowering compiles anew: jax's in-memory caches hold
+        # nothing of it, so the second meets the persistent cache
+        with tracer.span("warmup"):
+            jax.jit(_probe_program(3)).lower(x).compile()
+        rows.append(_spans_of(tracer.trace_events(), "fdtpu_obs_probe"))
+    miss, hit = (r["compile"]["args"] for r in rows)
+    assert miss == {"parent": "warmup", "cache": "miss",
+                    "fun_name": "jit(fdtpu_obs_probe)"}
+    assert hit["cache"] == "hit" and hit["fun_name"] == miss["fun_name"]
+    # a hit's span is the fetch and the load, and says how long they took
+    assert 0 < hit["load_s"] <= rows[1]["compile"]["dur"] / 1e6 + 1e-3
+    assert reg.value("fdtpu_jax_cache_load_seconds_total") == pytest.approx(
+        loaded + hit["load_s"])
+    # the next compile starts from nothing: no outcome is carried over
+    tracer.clear()
+    jax.jit(_probe_program(5)).lower(x).compile()
+    again = _spans_of(tracer.trace_events(), "fdtpu_obs_probe")["compile"]
+    assert again["args"] == {"cache": "miss",
+                             "fun_name": "jit(fdtpu_obs_probe)"}
+
+
+def test_compile_span_says_off_where_no_cache_served_or_kept_it(cache_dir):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from fluxdistributed_tpu.obs import get_tracer
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    cc.reset_cache()
+    get_tracer().clear()
+    jax.jit(_probe_program(7)).lower(jnp.ones(13)).compile()
+    got = _spans_of(get_tracer().trace_events(), "fdtpu_obs_probe")
+    assert got["compile"]["args"] == {"cache": "off",
+                                      "fun_name": "jit(fdtpu_obs_probe)"}
+
+
+def test_trace_and_lower_spans_carry_the_program_and_their_parent():
+    import jax
+    import jax.numpy as jnp
+
+    from fluxdistributed_tpu.obs import get_tracer
+
+    jaxmon.install()
+    tracer, reg = get_tracer(), get_registry()
+    inner = jax.jit(lambda x: x - 8191)
+
+    def fdtpu_obs_outer(x):
+        return inner(x) * 2
+
+    x = jnp.ones(17)
+    traced = reg.value("fdtpu_jax_trace_seconds_total")
+    tracer.clear()
+    with tracer.span("item", item=23):
+        with tracer.span("dispatch"):
+            jax.jit(fdtpu_obs_outer)(x).block_until_ready()
+    events = tracer.trace_events()
+    got = _spans_of(events, "fdtpu_obs_outer")
+    assert set(got) == {"trace", "lower", "compile"}
+    assert got["trace"]["args"] == {"parent": "dispatch", "item": 23,
+                                    "fun_name": "fdtpu_obs_outer"}
+    assert got["lower"]["args"] == {"parent": "dispatch", "item": 23,
+                                    "fun_name": "jit(fdtpu_obs_outer)"}
+    # in the order jax does them, each inside the dispatch that caused it
+    ends = [got[n]["ts"] + got[n]["dur"] for n in ("trace", "lower", "compile")]
+    assert ends == sorted(ends)
+    # the jitted function traced inside the outer trace is inside its span,
+    # not a span or seconds of its own
+    assert [e["args"]["fun_name"] for e in events if e["name"] == "trace"] \
+        == ["fdtpu_obs_outer"]
+    assert reg.value("fdtpu_jax_trace_seconds_total") == pytest.approx(
+        traced + got["trace"]["dur"] / 1e6, abs=1e-3)
+
+
+class _Gate:
+    """A value that is ready when the test says so."""
+
+    def __init__(self):
+        self.open = threading.Event()
+
+    def block_until_ready(self):
+        self.open.wait(10)
+
+
+def _wait_for(tracer, n):
+    deadline = time.monotonic() + 10
+    while len(tracer) < n and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert len(tracer) == n
+
+
+def test_device_spans_say_how_far_the_loop_ran_ahead():
+    from fluxdistributed_tpu.obs import CompletionWatcher
+
+    tracer, gauge = SpanTracer(), []
+    w = CompletionWatcher(tracer, on_ahead=gauge.append)
+    gates = [_Gate() for _ in range(5)]
+    # fed faster than it completes: each item finds one more in flight
+    for j, g in enumerate(gates[:4]):
+        w.watch(j, g, time.perf_counter())
+    for g in gates[:4]:
+        g.open.set()
+    _wait_for(tracer, 4)
+    # and after a wait the loop is in lockstep again
+    w.watch(4, gates[4], time.perf_counter())
+    gates[4].open.set()
+    assert w.close()
+    devs = tracer.trace_events()
+    assert [e["args"]["item"] for e in devs] == [0, 1, 2, 3, 4]
+    assert [e["args"]["ahead"] for e in devs] == [0, 1, 2, 3, 0] == gauge
+    assert all(e["args"]["parent"] == "dispatch" for e in devs)
+
+
+def _tiny_task(classes=4, **kw):
+    from fluxdistributed_tpu import mesh as mesh_lib, optim
+    from fluxdistributed_tpu.data import SyntheticDataset
+    from fluxdistributed_tpu.models import SimpleCNN
+    from fluxdistributed_tpu.train import prepare_training
+
+    ds = SyntheticDataset(nsamples=64, nclasses=classes, shape=(16, 16, 3))
+    return prepare_training(
+        SimpleCNN(num_classes=classes), ds, optim.momentum(0.05, 0.9),
+        mesh=mesh_lib.data_mesh(8), batch_size=16, cycles=4, **kw)
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-3)
+
+
+@pytest.mark.parametrize("how", ["plain", "cache_warmup", "aot"])
+def test_prepare_training_leaves_its_phases_as_children_of_prepare(
+        how, tmp_path, monkeypatch, request):
+    from fluxdistributed_tpu.obs import get_tracer
+
+    kw = {"plain": {}, "aot": {"aot": str(tmp_path / "aot")},
+          "cache_warmup": {"warmup": True}}[how]
+    if how == "cache_warmup":
+        kw["cache_dir"] = request.getfixturevalue("cache_dir")
+    ran = {"plain": {"model_init", "step_build"},
+           "cache_warmup": {"cache_enable", "model_init", "step_build",
+                            "warmup"},
+           "aot": {"model_init", "step_build", "aot"}}[how]
+    # a head no other test's model has: its init compiles here
+    kw["classes"] = {"plain": 41, "cache_warmup": 43, "aot": 47}[how]
+    get_tracer().clear()
+    _tiny_task(**kw)
+    events = get_tracer().trace_events()
+    (prepare,) = [e for e in events if e["name"] == "prepare"]
+    assert "args" not in prepare  # the outermost: no parent, no item
+    children = [e for e in events
+                if (e.get("args") or {}).get("parent") == "prepare"]
+    # a phase that did not run left no span
+    assert {e["name"] for e in children} == ran
+    assert all(_inside(e, prepare) for e in children)
+    # the children lie apart from each other and cover the call
+    tiles = sorted(children, key=lambda e: e["ts"])
+    for a, b in zip(tiles, tiles[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3
+    assert sum(e["dur"] for e in children) >= 0.9 * prepare["dur"]
+    # the un-jitted init's programs are compiled inside model_init, and
+    # say so
+    where = {e["args"].get("parent") for e in events if e["name"] == "compile"}
+    assert "model_init" in where and where <= ran
+    if how == "aot":
+        (aot,) = [e for e in children
+                  if e["name"] == "aot" and "source" in e["args"]]
+        assert aot["args"]["source"] == "compile"
+        step = [e for e in events if e["name"] == "compile"
+                and e["args"]["parent"] == "aot"]
+        assert step and all(_inside(e, aot) for e in step)
+        # the next process finds the file: a load, and no compile in it
+        get_tracer().clear()
+        _tiny_task(**kw)
+        events = get_tracer().trace_events()
+        (aot,) = [e for e in events
+                  if e["name"] == "aot" and "source" in e["args"]]
+        assert aot["args"] == {"parent": "prepare", "source": "load"}
+        assert not [e for e in events if e["name"] == "compile"
+                    and e["args"].get("parent") == "aot"]
+        # its seconds are the span's: the histogram under a counter's
+        # name is gone
+        assert get_registry().get("fdtpu_aot_seconds_total") is None
+
+
+def test_train_is_a_span_and_its_items_name_it():
+    from fluxdistributed_tpu.obs import get_tracer
+    from fluxdistributed_tpu.train import NullLogger, train
+
+    task = _tiny_task()
+    task.loader.start = 1  # as a resumed run, or the benchmark's window
+    get_tracer().clear()
+    train(task, print_every=0, eval_every=0, logger=NullLogger())
+    events = get_tracer().trace_events()
+    (call,) = [e for e in events if e["name"] == "train"]
+    assert call["args"] == {"start_item": 1}
+    items = [e for e in events if e["name"] == "item"]
+    assert [e["args"]["item"] for e in items] == [1, 2, 3, 4]
+    assert all(e["args"]["parent"] == "train" and _inside(e, call)
+               for e in items)
+    # the watcher is closed inside the call: every completion lies in it
+    devs = [e for e in events if e["name"] == "device"]
+    assert len(devs) == 3 and all(_inside(e, call) for e in devs)
+    assert all(e["args"]["ahead"] >= 0 for e in devs)
+    # the gauge an operator scrapes holds the newest item's lead
+    assert get_registry().value("fdtpu_train_items_ahead") \
+        == devs[-1]["args"]["ahead"]
+    assert events[-1] is call or events[-1]["name"] == "train"
+
+
+# ---------------------------------------------------------------------------
 # metrics endpoint (the trainer-side /metrics + /healthz)
 # ---------------------------------------------------------------------------
 

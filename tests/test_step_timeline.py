@@ -23,11 +23,11 @@ from fluxdistributed_tpu.obs import (
     Observation,
     Registry,
     SpanTracer,
-    current_item,
     get_registry,
     get_tracer,
     jaxmon,
 )
+from fluxdistributed_tpu.obs.spans import enclosing
 from fluxdistributed_tpu.train import NullLogger, prepare_training, train
 
 PER_ITEM = ("item", "data_wait", "dispatch", "device", "assemble", "h2d")
@@ -223,7 +223,7 @@ def test_compile_span_carries_the_item_during_which_it_fell():
     tracer.clear()
     f = jax.jit(lambda x: x * 3 + 41)
     with tracer.span("item", item=17):
-        assert current_item() == 17
+        assert enclosing() == {"parent": "item", "item": 17}
         with tracer.span("dispatch"):
             f(jnp.ones(5)).block_until_ready()
         # a compile on a thread with no span of its own falls during the
@@ -232,10 +232,14 @@ def test_compile_span_carries_the_item_during_which_it_fell():
             jnp.ones(6)).block_until_ready())
         t.start()
         t.join(60)
-    assert current_item() is None
+    assert enclosing() == {}
     compiles = [e for e in tracer.trace_events() if e["name"] == "compile"]
     assert len(compiles) >= 2
-    assert all(e["args"] == {"item": 17, "parent": "item"} for e in compiles)
+    assert all(e["args"]["item"] == 17 for e in compiles)
+    # each names the span during which it fell: the loop's own phase, or
+    # the item where the compiling thread had no span open
+    assert [e["args"]["parent"] for e in compiles
+            if "<lambda>" in e["args"]["fun_name"]] == ["dispatch", "item"]
     disp = next(e for e in tracer.trace_events() if e["name"] == "dispatch")
     first = compiles[0]
     assert disp["ts"] <= first["ts"] + first["dur"] <= disp["ts"] + disp["dur"] + 1e3
