@@ -29,10 +29,18 @@ the path for a model with many experts).  The router scores every token
 over all experts in float32 and balances by a selection bias, not by a
 loss term.  The layer is told which experts it holds (``first``, and the
 leading size of its weights): the token-slots are sorted by expert, the
-held experts' slots come first, one ``jax.lax.ragged_dot`` a projection
-runs over the sorted rows (on the TPU XLA lowers it to a grouped-matmul
-kernel that visits the live row tiles only), and the weighted results
-are gathered back to their tokens.  The buffer is as long as the step's
+held experts' slots come first, one grouped product a projection runs
+over the sorted rows, and the weighted results are gathered back to
+their tokens.  On a TPU the product and its two gradients are the
+Pallas kernels of ``ops/pallas_gmm.py`` (``fdtpu_gmm`` in a trace),
+which visit the live row tiles only, on tiles derived from the call's
+own shapes: 512 rows, and the contraction whole in VMEM wherever it
+fits, so that a group's weights are read once and not once a row tile.
+XLA's own kernel under ``jax.lax.ragged_dot`` (``ragged-dot-none``)
+takes 512 x 512 x 256 by divisibility alone and is bound by HBM at the
+expert models' widths (PERF.md §6, PR 36); ``ragged_dot`` stays as the
+plain path off the TPU, which the CPU tests compare the kernels and the
+layer against.  The buffer is as long as the step's
 held rows need: ``compact_rows`` is a short ladder of lengths, multiples
 of the held experts' even share of the token-slots and the last a row
 for every slot, and a ``lax.switch`` on the step's own load takes the
@@ -77,6 +85,7 @@ __all__ = [
 # a re-declared literal drifts silently on rename); re-exported here for
 # the callers that import it from the ep module
 from ..mesh import EXPERT_AXIS
+from ..ops import pallas_attention, pallas_gmm
 
 
 def stack_expert_params(per_expert: list, mesh: Mesh, axis: str = EXPERT_AXIS) -> Pytree:
@@ -331,7 +340,7 @@ def sigmoid_route(x, router_w, bias, *, top_k: int, scale: float = 1.0,
 #: 2.5 (the last, where PR 28's two lengths took every slot).
 COMPACT_OVER_EXPECTED = (Fraction(9, 8), Fraction(3, 2), Fraction(2),
                          Fraction(3))
-_COMPACT_TILE = 512  # a rung is a whole number of row tiles
+_COMPACT_TILE = pallas_gmm.ROW_TILE  # a rung is a whole number of row tiles
 
 
 def compact_rows(slots, held: int, experts: int) -> tuple:
@@ -412,14 +421,22 @@ _from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
 
 
 def _sorted_experts(rows, x, weights, w_gate, w_up, w_down, order, inverse,
-                    sizes):
+                    sizes, walk):
     """The layer over the first ``rows`` rows of the sorted order, which
-    must hold every held slot (``sum(sizes) <= rows``)."""
+    must hold every held slot (``sum(sizes) <= rows``).  ``walk`` is
+    :func:`_walk`'s: the kernels' walk over the groups, or None for the
+    plain path."""
     order = order[:rows]
     live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
     xs = jnp.where(live, _to_sorted(x, order, inverse), 0)
 
     def grouped(a, w):
+        """``a``'s rows times their group's ``w``.  ``ragged_dot`` is
+        kept as the plain path: what runs off the TPU (and where the
+        kernels' tiles do not divide the shapes), and what the CPU
+        tests hold the kernels and this layer to."""
+        if walk is not None:
+            return pallas_gmm.grouped_dot(a, w.astype(a.dtype), walk)
         return jax.lax.ragged_dot(
             a, w.astype(a.dtype), sizes,
             preferred_element_type=a.dtype)
@@ -428,6 +445,17 @@ def _sorted_experts(rows, x, weights, w_gate, w_up, w_down, order, inverse,
     h = jnp.where(live, jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up), 0)
     y = jnp.where(live, grouped(h, w_down), 0)
     return _from_sorted(y, weights, order, inverse)
+
+
+def _walk(sizes, slots, w_gate):
+    """The grouped-product kernels' walk over the groups (one for every
+    rung: ``pallas_gmm.group_metadata`` over all ``slots``), where the
+    backend is a TPU and the kernels' tiles divide the layer's shapes;
+    else None, and the products are ``ragged_dot``'s."""
+    if (pallas_attention.interpret_mode()
+            or not pallas_gmm.tileable(slots, *w_gate.shape[1:])):
+        return None
+    return pallas_gmm.group_metadata(sizes, slots)
 
 
 def _fits(ladder, sizes, path):
@@ -440,7 +468,7 @@ def _fits(ladder, sizes, path):
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _bounded_experts(ladder, x, weights, w_gate, w_up, w_down, order, inverse,
-                     sizes):
+                     sizes, walk):
     """:func:`_sorted_experts` over the first rung of ``ladder`` that
     the step fits.  A ``switch``'s own transpose keeps the union of all
     branches' residuals and writes noughts for the branches not taken,
@@ -449,7 +477,7 @@ def _bounded_experts(ladder, x, weights, w_gate, w_up, w_down, order, inverse,
     backward chooses again and recomputes its branch's products (under
     ``jax.checkpoint`` the forward's then fall away)."""
     return _rung_forward(ladder, x, weights, w_gate, w_up, w_down, order,
-                         inverse, sizes)
+                         inverse, sizes, walk)
 
 
 # jitted, so that a model's expert layers, whose shapes are the same, are
@@ -458,16 +486,16 @@ def _bounded_experts(ladder, x, weights, w_gate, w_up, w_down, order, inverse,
 # ``switch`` would else be lowered and loaded as a program of its own
 @partial(jax.jit, static_argnums=(0,))
 def _rung_forward(ladder, x, weights, w_gate, w_up, w_down, order, inverse,
-                  sizes):
+                  sizes, walk):
     return _fits(ladder, sizes, lambda rows: _sorted_experts(
-        rows, x, weights, w_gate, w_up, w_down, order, inverse, sizes))
+        rows, x, weights, w_gate, w_up, w_down, order, inverse, sizes, walk))
 
 
 @partial(jax.jit, static_argnums=(0,))
 def _rung_backward(ladder, args, g):
-    *diff, order, inverse, sizes = args
+    *diff, order, inverse, sizes, walk = args
     return _fits(ladder, sizes, lambda rows: jax.vjp(
-        lambda *a: _sorted_experts(rows, *a, order, inverse, sizes),
+        lambda *a: _sorted_experts(rows, *a, order, inverse, sizes, walk),
         *diff)[1](g))
 
 
@@ -476,7 +504,7 @@ def _bounded_fwd(ladder, *args):
 
 
 def _bounded_bwd(ladder, args, g):
-    return (*_rung_backward(ladder, args, g), None, None, None)
+    return (*_rung_backward(ladder, args, g), None, None, None, None)
 
 
 _bounded_experts.defvjp(_bounded_fwd, _bounded_bwd)
@@ -509,7 +537,8 @@ def held_experts_apply(x, chosen, weights, w_gate, w_up, w_down, experts, *,
     sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
                     axis=0, dtype=jnp.int32)
     ladder = compact_rows(n * k, held, experts)
-    args = (x, weights, w_gate, w_up, w_down, order, inverse, sizes)
+    args = (x, weights, w_gate, w_up, w_down, order, inverse, sizes,
+            _walk(sizes, n * k, w_gate))
     if len(ladder) == 1:
         return _sorted_experts(n * k, *args)
     return _bounded_experts(ladder, *args)

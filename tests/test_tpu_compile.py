@@ -257,18 +257,54 @@ def test_eva_attention_calls_at_the_cells_widths(chip, as_on_tpu):
     assert get_registry().value("fdtpu_eva_pairs", "local") == 8392704
 
 
-@pytest.mark.parametrize("experts,moe_dim,scale", [
-    (64, 1536, 1.8), (32, 1792, 1.0)], ids=["glm47_flash", "lfm2_8b_a1b"])
-def test_held_experts_grouped_products(chip, experts, moe_dim, scale):
-    """The expert cells' layer: 16,384 tokens, 4 of ``experts`` experts a
-    token, 8 held; XLA lowers `ragged_dot` to its grouped-matmul kernel,
-    forward and both gradients, under the name the reducer reads.  Each
-    direction is one conditional with a branch a rung of the ladder:
-    three products forward, nine backward (the forward's again and six
-    gradients), and only the last rung makes an array as long as the
-    slots."""
+def _window_bounds(call: str) -> list:
+    """The blocks of a Pallas call's operands and results, in order,
+    from its line of HLO text: the Mosaic body is MLIR bytecode, base64
+    under ``backend_config``."""
+    import base64
     import re
 
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    body = call.split('"body":"', 1)[1].split('"', 1)[0]
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        text = str(ir.Module.parse(base64.b64decode(body)))
+    return [tuple(int(n) for n in bounds.split(","))
+            for bounds in re.findall(r"window_bounds = array<i64: ([\d, ]+)>",
+                                     text)]
+
+
+@pytest.fixture
+def fresh_rungs():
+    """The rungs are jitted and keep their traces: drop the ones made
+    here as on a TPU, so that no later test of this worker finds them."""
+    from fluxdistributed_tpu.parallel import ep
+
+    yield
+    ep._rung_forward.clear_cache()
+    ep._rung_backward.clear_cache()
+
+
+@pytest.mark.parametrize("experts,moe_dim,scale", [
+    (64, 1536, 1.8), (32, 1792, 1.0)], ids=["glm47_flash", "lfm2_8b_a1b"])
+def test_held_experts_grouped_products(chip, as_on_tpu, fresh_rungs, experts,
+                                       moe_dim, scale):
+    """The expert cells' layer: 16,384 tokens, 4 of ``experts`` experts a
+    token, 8 held; every grouped product, forward and both gradients, is
+    the repo's own kernel under the name the reducer reads, and none is
+    left under XLA's ``ragged-dot-none``.  Each direction is one
+    conditional with a branch a rung of the ladder: three products
+    forward, nine backward (the forward's again and six gradients), and
+    only the last rung makes an array as long as the slots.  The block
+    of a product's weights spans the whole contraction at both widths:
+    a group's weights are fetched once, not once a row tile."""
+    import re
+
+    from fluxdistributed_tpu.obs import get_registry
+    from fluxdistributed_tpu.ops import pallas_gmm
     from fluxdistributed_tpu.parallel import ep
 
     x = chip((16384, 2048), BF)
@@ -285,11 +321,13 @@ def test_held_experts_grouped_products(chip, experts, moe_dim, scale):
     text = _compile(jax.value_and_grad(
         lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)),
         x, router, w_in, w_in, w_out)
+    assert "%ragged-dot-none" not in text
     ladder = ep.compact_rows(65536, 8, experts)
     assert len(ladder) == 5 and ladder[-1] == 65536
     conditionals = re.findall(
         r" conditional\(.*branch_computations=\{([^}]*)\}", text)
     products = []
+    kernel = rf"%{pallas_gmm.KERNEL_NAME}[.\d]* = (\S+)\[([\d,]+)\].*"
     for names in conditionals:
         branches = [text[text.index(f"\n{name.strip()} ("):].split("\n}\n", 1)[0]
                     for name in names.split(",")]
@@ -297,10 +335,24 @@ def test_held_experts_grouped_products(chip, experts, moe_dim, scale):
         for rows, branch in zip(ladder, branches):
             assert f"[{rows},{moe_dim}]" in branch
             assert ("[65536," in branch) == (rows == 65536)
-        (count,) = {len(re.findall(r"%ragged-dot-none[.\d]* = ", branch))
-                    for branch in branches}
+            for call in re.finditer(kernel, branch):
+                shape = tuple(int(n) for n in call.group(2).split(","))
+                lhs, rhs, out = _window_bounds(call.group(0))
+                if len(shape) == 2:  # gmm, gmm_t: [1, K, tn] or [1, tn, K]
+                    assert shape[0] == rows and lhs[1] in rhs[1:]
+                    assert sorted(rhs[1:]) == sorted((lhs[1], out[1]))
+                    assert lhs[1] in (2048, moe_dim)  # the contraction whole
+                else:  # tgmm: both row operands a tile of 512 rows
+                    assert lhs[0] == rhs[0] == pallas_gmm.ROW_TILE
+                    assert out == (1, lhs[1], rhs[1])
+        (count,) = {len(re.findall(kernel, branch)) for branch in branches}
         products.append(count)
     assert sorted(products) == [3, 9]  # forward; backward with its forward
+    reg = get_registry()
+    for product in pallas_gmm.PRODUCTS:
+        assert reg.value("fdtpu_gmm_tiles", product, "m") == 512
+        assert reg.value("fdtpu_gmm_operand_reads", product, "rows") == 2
+        assert reg.value("fdtpu_gmm_operand_reads", product, "weights") == 1
 
 
 @pytest.mark.parametrize("hkv", [H, HKV], ids=["dense", "gqa"])
