@@ -10,15 +10,16 @@ num_heads``: the exact part is ``T / window`` causal squares of ``window
 chunk) * window * (0 + 1 + .. + (T / window - 1))`` pairs of a query
 with a chunk's summary.  Over those pairs the forward kernel makes 2
 products, the dQ kernel 3, the dK/dV kernel 4, as the sibling counts
-them; the forward kernel runs ONCE a layer, rematerialised or not (a
-rematerialised block keeps what its flash calls made; the sibling's
-``forwards = 2`` is not loaded).  Bytes are each operand and result
-once, in bfloat16: for the exact part q, k, v, o (and dO, dQ or dK, dV)
-at ``T`` rows; for the summarised part q and o (dO, dQ) at the ``T -
-window`` queries that have summaries to see, and the summaries (their
-gradients) at the ``T / chunk - window / chunk`` chunks that are seen.
+them: each kernel's work once a step, a rematerialised layer's forward
+too.  Bytes are each operand and result once, in bfloat16: for the
+exact part q, k, v, o (and dO, dQ or dK, dV) at ``T`` rows; for the
+summarised part q and o (dO, dQ) at the ``T - window`` queries that have
+summaries to see, and the summaries (their gradients) at the ``T /
+chunk - window / chunk`` chunks that are seen.
 Nothing to read where the configuration has no such attention or the
-trace holds none of the kernels among its ten kinds of operation."""
+trace holds none of the kernels; every one it holds is read, however
+little time it took (``fdtpu_flash_dq`` is not among the cell's ten
+kinds of operation with most time)."""
 
 import os
 
@@ -55,12 +56,5 @@ def step_work(config: dict, rows: int) -> dict:
 
 
 def read(ctx):
-    t = ctx["trace"]
-    if not t or not t["steps"]:
-        return None
-    work = step_work(ctx["config"], ctx["traffic"]["global_batch"] // ctx["chips"])
-    seen = {n: s for n, s in t["device_ops"] if n in work and s > 0}
-    if not seen:
-        return None
-    least = sum(MHA.least_seconds(work[n], ctx["peaks"]) for n in seen)
-    return 100.0 * least * t["steps"] / sum(seen.values())
+    return MHA.share(ctx, step_work(
+        ctx["config"], ctx["traffic"]["global_batch"] // ctx["chips"]))

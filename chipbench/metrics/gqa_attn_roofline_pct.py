@@ -9,9 +9,10 @@ The operations are those of as many full heads: every query head makes
 its own products over a causal square of ``T (T + 1) / 2`` pairs at head
 size ``dim / num_heads``.  The bytes are the sibling's with ``k``,
 ``v`` and their gradients at ``num_kv_heads``: 2 such tensors in the
-forward and the dQ kernel, 4 in the dK/dV kernel.  Nothing to read
-where the configuration has no such layers or the trace holds none of
-the kernels among its ten kinds of operation."""
+forward and the dQ kernel, 4 in the dK/dV kernel.  Each kernel's work
+counts once a step, a rematerialised layer's forward too, as the sibling
+says why.  Nothing to read where the configuration has no such layers
+or the trace holds none of the kernels."""
 
 import os
 
@@ -35,22 +36,13 @@ def step_work(config: dict, rows: int) -> dict:
     # the sibling's count of as many layers of full heads of this size
     full = MHA.step_work({"input": config["input"], "model": {"kwargs": {
         "qk_nope_head_dim": d, "qk_rope_head_dim": 0, "num_heads": h,
-        "num_layers": layers, "remat": kw.get("remat")}}}, rows)
+        "num_layers": layers}}}, rows)
     tensor = rows * config["input"]["seq_len"] * h * d * 2 * layers  # bf16
     absent = tensor * (1.0 - kw["num_kv_heads"] / h)
-    forwards = 2 if kw.get("remat") else 1
-    calls = (forwards, 1, 1)
-    return {name: (full[name][0], full[name][1] - n * kv * absent)
-            for name, n, kv in zip(KERNELS, calls, KV_TENSORS)}
+    return {name: (full[name][0], full[name][1] - kv * absent)
+            for name, kv in zip(KERNELS, KV_TENSORS)}
 
 
 def read(ctx):
-    t = ctx["trace"]
-    if not t or not t["steps"]:
-        return None
-    work = step_work(ctx["config"], ctx["traffic"]["global_batch"] // ctx["chips"])
-    seen = {n: s for n, s in t["device_ops"] if n in work and s > 0}
-    if not seen:
-        return None
-    least = sum(MHA.least_seconds(work[n], ctx["peaks"]) for n in seen)
-    return 100.0 * least * t["steps"] / sum(seen.values())
+    return MHA.share(ctx, step_work(
+        ctx["config"], ctx["traffic"]["global_batch"] // ctx["chips"]))

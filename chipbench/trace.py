@@ -1,5 +1,7 @@
 """From a profiler trace to numbers: device busy time, idle share, the
-collectives' exposed time, the operations that took most time and the
+whole steps the slice holds, with the first device's busy time, the
+collectives' exposed time and the seconds of every kernel of the
+program's own inside them, the operations that took most time and the
 longest idle gaps.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote with
@@ -21,6 +23,9 @@ MODULES_LINE = "XLA Modules"
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)")
 ALL_REDUCE = re.compile(r"^all-reduce")
+#: the prefix the program gives each of its own kernels
+#: (``ops/pallas_attention.py::KERNEL_NAMES``, ``ops/pallas_gmm.py``)
+KERNEL_PREFIX = "fdtpu_"
 
 
 def short_name(name: str) -> str:
@@ -110,6 +115,25 @@ def window_of(devices: dict):
     return min(starts), max(ends)
 
 
+def intersect(a, b) -> list:
+    """The part of the merged intervals ``a`` that the merged ``b``
+    covers."""
+    return subtract(a, subtract(a, b))
+
+
+def step_cycles(runs, win) -> list:
+    """The whole steps of the slice ``win``, as intervals: each run of
+    the step program that starts after the slice's first operation, from
+    its start to the next run's start, so the device's work between two
+    runs counts with the step before it.  The first run may be one the
+    session cut (the profiler records it clipped to the session: 668 of
+    a 712 ms step, PERF.md, PR 37) and the last has no next: neither
+    counts, so a kernel's seconds and a step's work are read over the
+    same whole runs, whatever their lengths and wherever the cut falls."""
+    starts = sorted(s for s, _ in runs)
+    return [(a, b) for a, b in zip(starts, starts[1:]) if a > win[0]]
+
+
 def reduce(trace: dict) -> dict | None:
     """The numbers the per-layer readers take.  None where no operation
     ran on a device in the trace."""
@@ -126,17 +150,20 @@ def reduce(trace: dict) -> dict | None:
     by_module: dict = {}
     for name, s, e in d0["modules"]:
         by_module.setdefault(name, []).append((s, e))
-    step_module, steps = None, 0
+    step_module, cycles = None, []
     if by_module:
         step_module = max(by_module, key=lambda n: total(by_module[n]))
-        steps = len(by_module[step_module])
+        cycles = step_cycles(by_module[step_module], win)
     coll = union((s, e) for n, s, e in d0["ops"] if ALL_REDUCE.match(n))
     other = union((s, e) for n, s, e in d0["ops"] if not COLLECTIVE.match(n))
-    exposed_ns = total(subtract(coll, other))
+    exposed_ns = total(intersect(subtract(coll, other), cycles))
     by_op: dict = {}
     for name, s, e in d0["ops"]:
         by_op[kind_of(name)] = by_op.get(kind_of(name), 0) + (e - s)
     top = sorted(by_op.items(), key=lambda kv: -kv[1])[:10]
+    kernels = {n: total(intersect(union((s, e) for m, s, e in d0["ops"]
+                                        if kind_of(m) == n), cycles)) / 1e9
+               for n in by_op if n.startswith(KERNEL_PREFIX)}
     # idle gaps on the first device, named by the operation that ended them
     ops_sorted = sorted(d0["ops"], key=lambda ev: ev[1])
     gaps, edge = [], win[0]
@@ -152,10 +179,12 @@ def reduce(trace: dict) -> dict | None:
         "window_s": window_ns / 1e9,
         "busy_s": sum(busy_ns.values()) / len(busy_ns) / 1e9,
         "busy0_s": busy_ns[first] / 1e9,
-        "steps": steps,
+        "steps": len(cycles),
+        "steps_busy0_s": total(intersect(busy[first], cycles)) / 1e9,
         "step_module": step_module,
         "has_all_reduce": bool(coll),
         "allreduce_exposed_s": exposed_ns / 1e9,
+        "kernels": kernels,
         "device_ops": [[n, t / 1e9] for n, t in top],
         "idle_gaps": [[n, t / 1e9] for n, t in top_gaps],
     }

@@ -158,3 +158,25 @@ def test_peaks_table_and_unknown_kind():
     for kind in ("cpu", "TPU v9", "source"):
         with pytest.raises(harness.BenchError):
             harness.load_peaks(kind)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_an_appended_entry_is_owed_by_its_cell_alone(tmp_path, cell):
+    """What a later configuration does: append a per-layer entry that
+    lists its cell, with a reader of its own.  Nothing else in the
+    manifest or its tests moves, and the entries found by name stay."""
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "appended_layer_pct.py").write_text(
+        "def read(ctx):\n    return 42.0\n")
+    entry = {"name": "appended_layer_pct", "unit": "%", "better": "higher",
+             "source": "program_counter", "layer": "expert layer",
+             "moves": "images_per_s_per_chip", "workloads": [cell]}
+    copy = dict(M, paths=M["paths"] + [str(tmp_path)],
+                per_layer=M["per_layer"] + [entry])
+    for other in CELLS:
+        before = [m["name"] for m in harness.load_cell(other).metrics["per_layer"]]
+        after = [m["name"] for m in harness.load_cell(
+            other, manifest=copy).metrics["per_layer"]]
+        assert after == before + ["appended_layer_pct"] * (other == cell)
+    assert harness.read_metric(ROOT, copy, "appended_layer_pct", {}) == 42.0
+    assert len(json.dumps(copy)) <= 64 * 1024

@@ -109,6 +109,20 @@ def test_pool_is_seeded_and_a_batch_holds_no_row_twice():
         a.batch(np.random.default_rng(0), 33)
 
 
+def test_two_images_with_one_first_pixel_are_told_apart():
+    """As seed 3500101 holds at ResNet-50's 1,024 rows: a row is known by
+    all of its bytes, so the shadowed row is fed and checked as itself."""
+    pool = PoolDataset(3, 16, (4, 4, 3), 10)
+    pool.images[9, 0, 0, 0] = pool.images[4, 0, 0, 0]
+    imgs, labels = pool.batch(None, 4, indices=[9, 4, 2, 11])
+    assert pool.rows_of({"image": imgs}).tolist() == [9, 4, 2, 11]
+    onehot = np.eye(10, dtype=np.float32)[labels]
+    assert pool.fed_rows([{"image": imgs, "label": onehot}])[1] == 0
+    # another type or shape is no pool row
+    assert pool.rows_of({"image": imgs.astype(np.float64)}).tolist() == [-1] * 4
+    assert pool.rows_of({"image": imgs[:, :2]}).tolist() == [-1] * 4
+
+
 # read at the parent of the commit that gave the pools one maker: the
 # accepted cells' rows are these numbers, bit for bit
 @pytest.mark.parametrize("seed,rows,batch", [

@@ -1,11 +1,10 @@
 """The set-up's split and the loop's lead on the CPU: the eight readers
 over `setup_split.py` on a timeline written by hand (the sum rule among
 them), their silence on a program without the spans, their entries'
-fields, what each cell owes once the entries are in the manifest, and a
-traced run of the three listed cells at the tiny size."""
+fields as the manifest has them, what each cell owes, and a traced run of
+every cell at the tiny size."""
 
 import json
-import os
 
 import pytest
 
@@ -15,18 +14,15 @@ from chipbench_tiny import ROOT, harness, run_tiny
 from chipbench import steplog
 
 M = harness.load_manifest()
-with open(os.path.join(ROOT, "tests", "chipbench", "setup_entries.json")) as f:
-    ENTRIES = json.load(f)["per_layer"]
-NAMES = [e["name"] for e in ENTRIES]
-LISTED = ["resnet50_b256_x1", "vit_l16_b32_x1", "glm47_flash_t4096_b4_x1"]
-# the manifest with the entries at the end of its list, as the issue that
-# adds them leaves it (those it already has are not added twice)
-WITH = dict(M, per_layer=M["per_layer"] + [
-    e for e in ENTRIES if e["name"] not in {m["name"] for m in M["per_layer"]}])
+NAMES = ["setup_model_init_s", "setup_warmup_s", "setup_first_step_s",
+         "setup_trace_lower_s", "setup_cache_load_s", "setup_cache_misses",
+         "setup_outside_program_s", "loop_ahead_steps"]
+ENTRIES = [next(m for m in M["per_layer"] if m["name"] == n) for n in NAMES]
+LISTED = [w["name"] for w in M["workloads"]]
 
 
 def read(name, ctx):
-    return harness.read_metric(ROOT, WITH, name, ctx)
+    return harness.read_metric(ROOT, M, name, ctx)
 
 
 def span(name, t0, t1, **args):
@@ -165,19 +161,14 @@ def test_an_entry_keeps_to_the_manifests_rules(entry):
     assert (entry["layer"], entry["moves"]) == (
         ("trainer loop", "images_per_s_per_chip")
         if entry["name"] == "loop_ahead_steps" else ("cold start", "setup_s"))
-    assert entry["layer"] in {m["layer"] for m in M["per_layer"]}
-    assert len(json.dumps(WITH)) <= 64 * 1024
+    assert entry["layer"] in {m["layer"] for m in M["per_layer"]
+                              if m["name"] not in NAMES}
 
 
-def test_the_three_listed_cells_owe_them_and_the_other_two_what_they_did():
-    for cell in LISTED:
-        owed = [m["name"] for m in harness.load_cell(
-            cell, manifest=WITH).metrics["per_layer"]]
-        assert owed[-8:] == NAMES
-    for cell, n in (("lfm2_8b_a1b_t4096_b4_x1", 17), ("evabyte_t8192_b2_x1", 15)):
-        owed = [m["name"] for m in harness.load_cell(
-            cell, manifest=WITH).metrics["per_layer"]]
-        assert len(owed) == n and not set(NAMES) & set(owed)
+@pytest.mark.parametrize("cell", LISTED)
+def test_every_cell_owes_them(cell):
+    owed = [m["name"] for m in harness.load_cell(cell).metrics["per_layer"]]
+    assert [n for n in owed if n in NAMES] == NAMES
 
 
 @pytest.mark.parametrize("cell", LISTED)
@@ -190,7 +181,6 @@ def test_a_traced_tiny_run_reads_all_eight(monkeypatch, cell):
         seen.update(setup_s=ctx["setup_s"])
         return real(root, manifest, name, ctx)
 
-    monkeypatch.setattr(harness, "load_manifest", lambda root=ROOT: WITH)
     monkeypatch.setattr(harness, "read_metric", spy)
     # a run of the benchmark is a process of its own; the ring of a test
     # process that ran other cells before may have no room left for this
