@@ -49,8 +49,8 @@ from .experts import ExpertMLP, SwiGLU, router_step_metrics
 from .transformer_lm import rope
 
 __all__ = ["Lfm2Config", "Lfm2Moe", "ShortConv", "GroupedQueryAttention",
-           "short_conv_core", "lfm2_moe", "NO_DECODE", "LAYER_KINDS",
-           "PUBLISHED_LAYER_TYPES"]
+           "short_conv_core", "causal_depthwise", "lfm2_moe", "NO_DECODE",
+           "LAYER_KINDS", "PUBLISHED_LAYER_TYPES"]
 
 NO_DECODE = (
     "lfm2_moe has no decode path: serving it needs a cache that holds a "
@@ -110,17 +110,21 @@ class Lfm2Config:
                 f"unknown layer kind {unknown} ({'|'.join(LAYER_KINDS)})")
 
 
+def causal_depthwise(u, w):
+    """``conv_t = sum_j w[:, j] * u_{t - (L - 1) + j}``: ``u`` [rows, T,
+    D], ``w`` [D, L], ``u`` nought before position 0.  L shifted slices
+    of one padded array, so XLA makes one fused pass of it."""
+    taps, t = w.shape[-1], u.shape[1]
+    u = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(u[:, j:j + t] * w[:, j] for j in range(taps))
+
+
 def short_conv_core(b, c, z, w):
     """``C * conv(B * z)``: ``b``, ``c``, ``z`` [rows, T, D], ``w`` [D, L]
-    with ``conv_t = sum_j w[:, j] * u_{t - (L - 1) + j}`` and ``u``
-    nought before position 0.  Float32 inside (the chip's vector unit has
-    no narrower arithmetic), the result in ``b``'s type; L shifted
-    slices of one padded array, so XLA makes one fused pass of it."""
-    taps, t = w.shape[-1], b.shape[1]
+    with :func:`causal_depthwise`.  Float32 inside (the chip's vector
+    unit has no narrower arithmetic), the result in ``b``'s type."""
     f32 = jnp.float32
-    u = jnp.pad(b.astype(f32) * z.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(f32)
-    conv = sum(u[:, j:j + t] * w[:, j] for j in range(taps))
+    conv = causal_depthwise(b.astype(f32) * z.astype(f32), w.astype(f32))
     return (c.astype(f32) * conv).astype(b.dtype)
 
 

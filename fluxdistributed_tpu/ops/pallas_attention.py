@@ -528,7 +528,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k,
     # so it cannot enter jit/AOT signature digests (see interpret_mode)
     interpret = interpret_mode()
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     h, hkv, group = _gqa_dims(q, k)
     scale = 1.0 / (d**0.5)
     block_q = min(block_q, tq)
@@ -547,28 +547,29 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k,
     band = _band(tq, tk, block_k, causal, window, sinks)
     _publish_census(
         KERNEL_NAMES[:1], tq, tk, block_q, block_k, causal, window, sinks)
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (
-        kv_bh(bh), _kv_block_index(i, j, block_q, block_k, nk, band), 0))
+    kv_spec = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block_k, width), lambda bh, i, j: (
+            kv_bh(bh), _kv_block_index(i, j, block_q, block_k, nk, band), 0))
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, band=band),
         grid=(b * h, tq_p // block_q, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            kv_spec,
-            kv_spec,
+            kv_spec(d),
+            kv_spec(dv),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
             # per-row stats ride as [BH, 1, Tq] rows: a (1, block_q)
             # block of a 2-D [BH, Tq] array is not (8, 128)-tileable
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, tq_p), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -586,7 +587,7 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
                     g_lse=None, window=None, sinks=0):
     interpret = interpret_mode()
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     h, hkv, group = _gqa_dims(q, k)
     scale = 1.0 / (d**0.5)
     block_q = min(block_q, tq)
@@ -629,19 +630,21 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     def inner_q(j, t):  # dK/dV step → the query block it names
         return _q_block_index(j, t % nq, block_q, block_k, nq, band)
 
-    q_spec_i = pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0))
-    kv_spec_j = pl.BlockSpec((1, block_k, d), lambda bh_, i, j: (
-        kv_bh(bh_), _kv_block_index(i, j, block_q, block_k, nk, band), 0))
+    q_spec_i = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block_q, width), lambda bh_, i, j: (bh_, i, 0))
+    kv_spec_j = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block_k, width), lambda bh_, i, j: (
+            kv_bh(bh_), _kv_block_index(i, j, block_q, block_k, nk, band), 0))
     # lse/delta as [BH, 1, Tq] rows (see the forward's LSE out_spec)
     row_spec_i = pl.BlockSpec(
         (1, 1, block_q), lambda bh_, i, j: (bh_, 0, i))
     # dKV grid is (b*hkv, j, t) where the inner axis t enumerates the
     # nq q-blocks of each of the `group` query heads sharing this KV
     # head: t = member * nq + qi.
-    q_spec_inner = pl.BlockSpec(
-        (1, block_q, d), lambda bh_, j, t: (q_bh(bh_, t), inner_q(j, t), 0))
-    kv_spec_outer = pl.BlockSpec(
-        (1, block_k, d), lambda bh_, j, t: (bh_, j, 0))
+    q_spec_inner = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block_q, width), lambda bh_, j, t: (q_bh(bh_, t), inner_q(j, t), 0))
+    kv_spec_outer = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block_k, width), lambda bh_, j, t: (bh_, j, 0))
     row_spec_inner = pl.BlockSpec(
         (1, 1, block_q), lambda bh_, j, t: (q_bh(bh_, t), 0, inner_q(j, t)))
 
@@ -649,9 +652,9 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **common),
         grid=(bh, nq, nk),
-        in_specs=[q_spec_i, kv_spec_j, kv_spec_j, q_spec_i,
+        in_specs=[q_spec_i(d), kv_spec_j(d), kv_spec_j(dv), q_spec_i(dv),
                   row_spec_i, row_spec_i],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
+        out_specs=q_spec_i(d),
         out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
@@ -661,19 +664,16 @@ def _flash_bwd_impl(q, k, v, o, lse, g, causal, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **common, nq=nq),
         grid=(b * hkv, nk, nq * group),
-        in_specs=[q_spec_inner, kv_spec_outer, kv_spec_outer, q_spec_inner,
-                  row_spec_inner, row_spec_inner],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh_, j, t: (bh_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, j, t: (bh_, j, 0)),
-        ],
+        in_specs=[q_spec_inner(d), kv_spec_outer(d), kv_spec_outer(dv),
+                  q_spec_inner(dv), row_spec_inner, row_spec_inner],
+        out_specs=[kv_spec_outer(d), kv_spec_outer(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b * hkv, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, tk_p, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hkv, tk_p, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         name=KERNEL_NAMES[2],
@@ -697,13 +697,15 @@ def flash_attention(
     window: int | None = None,
     sinks: int = 0,
 ) -> jax.Array:
-    """Fused flash attention, [B, T, H, D] → [B, T, H, D].
+    """Fused flash attention, [B, T, H, D] → [B, T, H, Dv].
 
     Runs the Pallas TPU kernels on TPU and the same kernels under the
     Pallas interpreter elsewhere (so CPU tests cover the real kernels),
     forward and backward.  Numerics match ``dot_product_attention`` to
     f32 accumulation.  Grouped-query KV ([B, T, Hkv, D]) is consumed
-    natively (never repeated in HBM).  ``window`` (requires ``causal``)
+    natively (never repeated in HBM).  ``v`` may have a feature width of
+    its own (``Dv``), which the output and ``v``'s gradient take; ``q``
+    and ``k`` share ``D``, which sets the scale.  ``window`` (requires ``causal``)
     restricts each query to its ``window`` most recent keys — KV blocks
     outside the band are neither fetched nor computed, so long-T cost is
     O(T·window), not O(T²).  ``sinks`` (StreamingLLM attention sinks;
@@ -764,7 +766,7 @@ def flash_attention_lse(
 ) -> tuple[jax.Array, jax.Array]:
     """Flash attention that ALSO returns the per-row logsumexp.
 
-    → ``(out [B, Tq, H, D], lse [B, H, Tq] f32)`` where
+    → ``(out [B, Tq, H, Dv], lse [B, H, Tq] f32)`` where
     ``lse = log Σ_k exp(q·kᵀ/√D)``.  The LSE output is differentiable
     (its gradient folds into the same Pallas backward kernels), which is
     what lets ring attention use this kernel as its per-hop block

@@ -401,3 +401,58 @@ def test_fused_adam(chip):
     f = chip((ADAM_N,), jnp.float32)
     _compile(lambda p, g, m, v: zf.fused_adam_update(
         p, g, m, v, jnp.int32(3), impl="pallas"), f, f, f, f)
+
+
+@pytest.fixture
+def scan_on_tpu(monkeypatch):
+    """The selective scan's wrappers, steered to the compiled kernels;
+    the traces made here are dropped after the test."""
+    from fluxdistributed_tpu.ops import pallas_scan as ps
+
+    monkeypatch.setattr(ps, "interpret_mode", lambda: False)
+    yield ps
+    ps._scan_fwd.clear_cache()
+    ps._scan_bwd.clear_cache()
+
+
+def test_selective_scan_at_the_cells_widths(chip, scan_on_tpu):
+    """The `phi4_mini_flash` cell's Mamba scan as the step makes it: 2
+    rows of 4,096 positions, d_inner 5,120, 16 states, float32; forward
+    and the six gradients, under the names the trace reducer reads
+    (`chipbench/metrics/scan_roofline_pct.py`).  The forward keeps one
+    state of [16, 5,120] a chunk of 256: 1 / 256 of every position's."""
+    ps = scan_on_tpu
+    f32 = jnp.float32
+    wide, narrow = chip((2, 4096, 5120), f32), chip((2, 4096, 16), f32)
+    a, d = chip((5120, 16), f32), chip((5120,), f32)
+    fn = jax.grad(lambda u, dt, a, b, c, d: ps.selective_scan(
+        u, dt, a, b, c, d).sum(), argnums=range(6))
+    text = _compile(fn, wide, wide, a, narrow, narrow, d)
+    assert [x.shape for x in jax.eval_shape(fn, wide, wide, a, narrow, narrow, d)] \
+        == [wide.shape, wide.shape, a.shape, narrow.shape, narrow.shape, d.shape]
+    for name in ps.KERNEL_NAMES:
+        assert f"%{name}" in text, name
+    from fluxdistributed_tpu.obs import get_registry
+
+    reg = get_registry()
+    assert reg.value("fdtpu_scan_state_bytes", "kept") == 2 * 16 * 16 * 5120 * 4
+    assert reg.value("fdtpu_scan_state_bytes", "all") == 2 * 4096 * 16 * 5120 * 4
+    assert [reg.value("fdtpu_scan_tiles", dim) for dim in (
+        "chunk", "channels_fwd", "channels_bwd")] == [256, 512, 256]
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window", "causal"])
+def test_differential_attention_calls_at_the_cells_widths(chip, as_on_tpu, window):
+    """One of a `phi4_mini_flash` layer's two flash calls: 2 rows of
+    4,096 positions, 20 query heads over 10 key heads of 64 and a value
+    of 128 (a pair's two heads side by side), 512 x 512 blocks, a window
+    of 512 or fully causal; the output and the value's gradient at 128."""
+    q, k = chip((2, 4096, 20, 64), BF), chip((2, 4096, 10, 64), BF)
+    v = chip((2, 4096, 10, 128), BF)
+    fn = _grads(lambda q, k, v: pa.flash_attention(q, k, v, True, 512, 512, window))
+    text = _compile(fn, q, k, v)
+    assert [x.shape for x in jax.eval_shape(fn, q, k, v)] == [q.shape, k.shape, v.shape]
+    assert jax.eval_shape(lambda q, k, v: pa.flash_attention(
+        q, k, v, True, 512, 512, window), q, k, v).shape == (2, 4096, 20, 128)
+    for name in pa.KERNEL_NAMES:
+        assert f"%{name}" in text, name
