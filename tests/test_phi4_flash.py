@@ -35,21 +35,12 @@ from chipbench import harness, refcommon  # noqa: E402
 pf = importlib.import_module("fluxdistributed_tpu.models.phi4_flash")
 REF = harness.load_module(os.path.join(ROOT, "chipbench", "configs",
                                        "phi4_mini_flash.py"))
+BENCH = harness.load_module(os.path.join(ROOT, "benchmarks", "scan_bench.py"))
 SCAN_TOL, TOL = 1e-5, 5e-5
 
 
 def rel(a, b):
     return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
-
-
-def scan_inputs(t=40, c=256, n=16, rows=2, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
-    u = jax.random.normal(ks[0], (rows, t, c))
-    delta = jax.nn.softplus(jax.random.normal(ks[1], (rows, t, c)) - 2.0)
-    a = -jnp.exp(0.5 * jax.random.normal(ks[2], (c, n)))
-    b, cc = (jax.random.normal(k, (rows, t, n)) for k in ks[3:5])
-    d = jax.random.normal(ks[5], (c,))
-    return (u, delta, a, b, cc, d), jax.random.normal(ks[6], (rows, t, c))
 
 
 @pytest.fixture
@@ -65,14 +56,21 @@ def tiles(monkeypatch):
     ps._scan_bwd.clear_cache()
 
 
-@pytest.mark.parametrize("t,chunk", [(64, 256), (40, 16), (64, 8)],
-                         ids=["one_chunk", "padded_chunks", "eight_chunks"])
-def test_selective_scan_kernels_match_the_plain_scan(tiles, t, chunk):
+@pytest.mark.parametrize("t,chunk,c,n", [
+    (64, 256, 256, 16), (40, 16, 256, 16), (64, 8, 256, 16),
+    (45, 16, 256, 16), (600, 256, 256, 16), (40, 16, 96, 16), (40, 16, 256, 4)],
+    ids=["one_chunk", "padded_chunks", "eight_chunks", "partial_loop_step",
+         "three_chunks_in_reverse", "narrow_channels", "four_states"])
+def test_selective_scan_kernels_match_the_plain_scan(tiles, t, chunk, c, n):
     """Forward and all six gradients, over chunks (the kept states, the
-    state's gradient carried back across them), padded positions and
-    two channel blocks."""
+    state's gradient carried back across them), padded positions, two
+    channel blocks or one narrower than a block; a loop step whose last
+    positions are padding (45 = 5 x 8 + 5); 4 states, fewer than the
+    8 sublanes of a tile; the gradients of ``B`` and ``C``, which
+    leave a loop step as one ``[N, UNROLL]`` tile, also element by
+    element."""
     tiles(chunk)
-    args, w = scan_inputs(t=t)
+    args, w = BENCH.inputs(2, t, c, n)
     y = ps.selective_scan(*args)
     assert rel(y, ps.selective_scan_xla(*args)) < SCAN_TOL
     loss = lambda fn: lambda *a: jnp.sum(fn(*a) * w)  # noqa: E731
@@ -80,11 +78,36 @@ def test_selective_scan_kernels_match_the_plain_scan(tiles, t, chunk):
     want = jax.grad(loss(ps.selective_scan_xla), argnums=range(6))(*args)
     for name, g, h in zip(("u", "delta", "A", "B", "C", "D"), got, want):
         assert g.shape == h.shape and rel(g, h) < SCAN_TOL, name
+    for g, h in zip(got[3:5], want[3:5]):
+        np.testing.assert_allclose(g, h, rtol=SCAN_TOL,
+                                   atol=SCAN_TOL * float(jnp.max(jnp.abs(h))))
     reg = get_registry()
     kept = reg.value("fdtpu_scan_state_bytes", "kept")
-    assert kept == 2 * -(-t // min(chunk, -(-t // 8) * 8)) * 16 * 256 * 4
-    assert reg.value("fdtpu_scan_state_bytes", "all") == 2 * t * 16 * 256 * 4
-    assert reg.value("fdtpu_scan_tiles", "channels_fwd") == 128
+    assert kept == 2 * -(-t // min(chunk, -(-t // 8) * 8)) * n * c * 4
+    assert reg.value("fdtpu_scan_state_bytes", "all") == 2 * t * n * c * 4
+    assert reg.value("fdtpu_scan_tiles", "channels_fwd") == min(c, 128)
+    assert reg.value("fdtpu_scan_tiles", "columns_per_load") == ps.UNROLL == 8
+
+
+def test_the_scan_micro_benchmark(monkeypatch, capsys):
+    """``benchmarks/scan_bench.py`` times a copy of the kernels' module
+    loaded by path (as it times another commit's), holds ``y`` and each
+    gradient to the plain scan by name and reads its gauges; on a backend
+    that is no TPU it refuses."""
+    bench = BENCH
+    mod = bench.load(os.path.join(ROOT, "fluxdistributed_tpu", "ops", "pallas_scan.py"))
+    assert mod is not ps and mod.KERNEL_NAMES == ps.KERNEL_NAMES
+    args, w = bench.inputs(1, 40, 256, 16)
+    row = bench.bench(mod, args, w, reps=1, check=(1, 45, 256))
+    assert row["fwd_ms"] > 0 and row["fwdbwd_ms"] > row["bwd_ms"]
+    assert list(row["rel_err"]) == ["y", "u", "delta", "A", "B", "C", "D"]
+    assert max(row["rel_err"].values()) == row["max_rel_err"] < SCAN_TOL
+    assert row["tiles"] == {"chunk": 40, "channels_fwd": 256,
+                            "channels_bwd": 256, "columns_per_load": 8}
+    monkeypatch.setattr("sys.argv", ["scan_bench.py"])
+    assert bench.main() == 1
+    assert json.loads(capsys.readouterr().out) == {"error": "no TPU",
+                                                   "platform": "cpu"}
 
 
 def test_scan_tiles_at_the_cells_widths():

@@ -420,7 +420,8 @@ def test_selective_scan_at_the_cells_widths(chip, scan_on_tpu):
     rows of 4,096 positions, d_inner 5,120, 16 states, float32; forward
     and the six gradients, under the names the trace reducer reads
     (`chipbench/metrics/scan_roofline_pct.py`).  The forward keeps one
-    state of [16, 5,120] a chunk of 256: 1 / 256 of every position's."""
+    state of [16, 5,120] a chunk of 256: 1 / 256 of every position's; one
+    load brings a loop step's 8 columns of `B` and of `C`."""
     ps = scan_on_tpu
     f32 = jnp.float32
     wide, narrow = chip((2, 4096, 5120), f32), chip((2, 4096, 16), f32)
@@ -438,7 +439,8 @@ def test_selective_scan_at_the_cells_widths(chip, scan_on_tpu):
     assert reg.value("fdtpu_scan_state_bytes", "kept") == 2 * 16 * 16 * 5120 * 4
     assert reg.value("fdtpu_scan_state_bytes", "all") == 2 * 4096 * 16 * 5120 * 4
     assert [reg.value("fdtpu_scan_tiles", dim) for dim in (
-        "chunk", "channels_fwd", "channels_bwd")] == [256, 512, 256]
+        "chunk", "channels_fwd", "channels_bwd", "columns_per_load")] \
+        == [256, 512, 256, 8]
 
 
 @pytest.mark.parametrize("window", [512, None], ids=["window", "causal"])
