@@ -46,7 +46,7 @@ from ..obs.metrics import get_registry
 from ..ops.attention import dot_product_attention
 from .common import json_kwargs, maybe_remat, rms_norm
 from .experts import ExpertMLP, SwiGLU, router_step_metrics
-from .transformer_lm import rope
+from .transformer_lm import YaRN, rope
 
 __all__ = ["Lfm2Config", "Lfm2Moe", "ShortConv", "GroupedQueryAttention",
            "short_conv_core", "causal_depthwise", "lfm2_moe", "NO_DECODE",
@@ -150,8 +150,15 @@ class ShortConv(nn.Module):
 
 
 class GroupedQueryAttention(nn.Module):
-    """Causal grouped-query attention with a per-head RMSNorm of ``q``
-    and ``k`` before the rotary positions; training forward only."""
+    """Causal grouped-query attention; training forward only.  As LFM2
+    has it by default: heads of ``dim / num_heads``, a per-head RMSNorm
+    of ``q`` and ``k`` before rotary positions on every feature.  What
+    Laguna's layers set: a ``head_dim`` of its own, no norm
+    (``qk_norm``), a causal band of the ``window`` newest keys, rotary
+    positions on the first ``rotary_dim`` features with ``yarn``'s
+    frequencies (``transformer_lm.rope``), and ``head_gate``: each
+    head's output times ``sigmoid(x W_g)`` of that head, before the
+    output projection (arXiv:2505.06708's head-wise gate)."""
 
     num_heads: int
     num_kv_heads: int
@@ -161,32 +168,52 @@ class GroupedQueryAttention(nn.Module):
     attention_impl: str = "xla"  # xla | pallas (the flash kernels)
     block_q: int = 128
     block_k: int = 128
+    head_dim: Optional[int] = None  # None: dim // num_heads
+    qk_norm: bool = True
+    window: Optional[int] = None
+    rotary_dim: Optional[int] = None  # None: every feature
+    yarn: Optional[YaRN] = None
+    head_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
                 f"unknown attention_impl {self.attention_impl!r} (xla|pallas)")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads ({self.num_heads}) must be a multiple of "
+                f"num_kv_heads ({self.num_kv_heads})")
         d, t = x.shape[-1], x.shape[1]
-        if d % self.num_heads:
-            raise ValueError(f"dim ({d}) must be a multiple of num_heads "
-                             f"({self.num_heads})")
+        hd = self.head_dim
+        if hd is None:
+            if d % self.num_heads:
+                raise ValueError(f"dim ({d}) must be a multiple of num_heads "
+                                 f"({self.num_heads})")
+            hd = d // self.num_heads
         heads = lambda n, name: nn.DenseGeneral(  # noqa: E731
-            (n, d // self.num_heads), axis=-1, dtype=self.dtype,
-            use_bias=False, name=name)
-        norm = partial(rms_norm, self.dtype, self.norm_eps)
+            (n, hd), axis=-1, dtype=self.dtype, use_bias=False, name=name)
+        norm = (partial(rms_norm, self.dtype, self.norm_eps) if self.qk_norm
+                else lambda name: lambda y: y)
         pos = jnp.arange(t)
-        q = rope(norm("q_norm")(heads(self.num_heads, "q")(x)), pos,
-                 base=self.rope_theta)
-        k = rope(norm("k_norm")(heads(self.num_kv_heads, "k")(x)), pos,
-                 base=self.rope_theta)
+        turn = partial(rope, base=self.rope_theta, rotary_dim=self.rotary_dim,
+                       yarn=self.yarn)
+        q = turn(norm("q_norm")(heads(self.num_heads, "q")(x)), pos)
+        k = turn(norm("k_norm")(heads(self.num_kv_heads, "k")(x)), pos)
         v = heads(self.num_kv_heads, "v")(x)
         if self.attention_impl == "pallas":
             from ..ops.pallas_attention import flash_attention
 
-            out = flash_attention(q, k, v, True, self.block_q, self.block_k)
+            out = flash_attention(q, k, v, True, self.block_q, self.block_k,
+                                  self.window)
         else:
-            out = dot_product_attention(q, k, v, causal=True)
+            out = dot_product_attention(q, k, v, causal=True, window=self.window)
+        if self.head_gate:
+            with jax.named_scope("fdtpu/head_gate"):
+                gate = jax.nn.sigmoid(nn.Dense(
+                    self.num_heads, dtype=self.dtype, use_bias=False,
+                    name="gate")(x).astype(jnp.float32))
+                out = (out.astype(jnp.float32) * gate[..., None]).astype(out.dtype)
         return nn.DenseGeneral(d, axis=(-2, -1), dtype=self.dtype,
                                use_bias=False, name="out")(out)
 

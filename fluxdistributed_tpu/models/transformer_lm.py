@@ -26,11 +26,13 @@ next-token cross-entropy.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from ..mesh import EXPERT_AXIS, PIPE_AXIS
@@ -42,6 +44,8 @@ __all__ = [
     "lm_loss_fn",
     "next_token_loss",
     "rope",
+    "YaRN",
+    "yarn_inv_freq",
     "generate",
     "make_decode_cache",
     "lm_pp",
@@ -56,7 +60,43 @@ __all__ = [
 AttnFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 
 
-def rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array:
+class YaRN(NamedTuple):
+    """YaRN's rotary frequencies (arXiv:2309.00071), as the published
+    recipe (transformers' ``_compute_yarn_parameters``) takes them from a
+    config: the ``factor`` by which the context was stretched over
+    ``original_max_positions``, the ramp's ends in rotations over that
+    length (``beta_fast``, ``beta_slow``), and the factor on ``cos`` and
+    ``sin`` (``attention_factor``)."""
+
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, yarn: YaRN) -> np.ndarray:
+    """The ``dim / 2`` inverse frequencies of a rotary over ``dim``
+    features: pair ``i`` keeps its own ``base^(-2i/dim)`` below the
+    ramp, takes it over ``factor`` above it, and blends the two along a
+    linear ramp from the pair that turns ``beta_fast`` times over the
+    original positions (floored) to the one that turns ``beta_slow``
+    times (ceiled).  Computed on the host, float64, handed on as
+    float32."""
+    def pair_of(turns):
+        return (dim * math.log(yarn.original_max_positions / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(pair_of(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    own = 1.0 / base ** (np.arange(0, dim, 2) / dim)
+    return (own / yarn.factor * ramp + own * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, base: float = 10000.0,
+         rotary_dim: Optional[int] = None,
+         yarn: Optional[YaRN] = None) -> jax.Array:
     """Rotary position embedding on ``x``: [B, T, H, D] with D even.
 
     ``positions``: [T] (or [B, T]) global token indices.  Pairs feature
@@ -64,15 +104,28 @@ def rope(x: jax.Array, positions: jax.Array, base: float = 10000.0) -> jax.Array
     offsets become phase differences, so attention scores depend only on
     key/query distance.  Computed in f32 and cast back (bf16 phase
     accumulation loses precision at long context).
+
+    ``rotary_dim`` (even): only the first ``rotary_dim`` features turn,
+    over frequencies of that width, and the rest pass unchanged (a
+    partial rotary).  ``yarn``: the frequencies of :func:`yarn_inv_freq`,
+    and ``cos`` and ``sin`` times its ``attention_factor``.
     """
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = rope(x[..., :rotary_dim], positions, base, yarn=yarn)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     assert d % 2 == 0, "rope needs an even head dim"
-    inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if yarn is None:
+        inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(d, base, yarn))
     ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., T, D/2]
     # broadcast over batch/head axes: positions [T] -> [1, T, 1, D/2]
     while ang.ndim < x.ndim:
         ang = ang[None] if ang.ndim < x.ndim - 1 else ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., 0::2], x32[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)

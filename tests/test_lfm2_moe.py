@@ -166,8 +166,8 @@ def test_the_flash_path_gets_its_keys_and_values_at_their_own_heads(monkeypatch)
 
     seen = {}
 
-    def spy(q, k, v, causal, block_q, block_k):
-        seen.update(q=q.shape, k=k.shape, v=v.shape, causal=causal)
+    def spy(q, k, v, causal, block_q, block_k, window):
+        seen.update(q=q.shape, k=k.shape, v=v.shape, causal=causal, window=window)
         return q
 
     monkeypatch.setattr(pallas_attention, "flash_attention", spy)
@@ -176,7 +176,7 @@ def test_the_flash_path_gets_its_keys_and_values_at_their_own_heads(monkeypatch)
     jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0),
                                        jnp.zeros((2, 16, 32))))
     assert seen == {"q": (2, 16, 8, 4), "k": (2, 16, 2, 4),
-                    "v": (2, 16, 2, 4), "causal": True}
+                    "v": (2, 16, 2, 4), "causal": True, "window": None}
 
 
 # -- the block: an operator by layer kind, dense layers first -----------------

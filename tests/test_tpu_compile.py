@@ -458,3 +458,18 @@ def test_differential_attention_calls_at_the_cells_widths(chip, as_on_tpu, windo
         q, k, v, True, 512, 512, window), q, k, v).shape == (2, 4096, 20, 128)
     for name in pa.KERNEL_NAMES:
         assert f"%{name}" in text, name
+
+
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)],
+                         ids=["sliding", "full"])
+def test_laguna_attention_calls_at_the_cells_widths(chip, as_on_tpu, heads, window):
+    """A `laguna_s21` layer's flash call: 2 rows of 4,096 positions, 72
+    (sliding, a band of 512) or 48 (full, causal) query heads over 8
+    key-value heads of 128, groups of 9 and 6, 512 x 512 blocks; `k`,
+    `v` and their gradients stay at 8 heads."""
+    q, kv = chip((2, 4096, heads, 128), BF), chip((2, 4096, 8, 128), BF)
+    fn = _grads(lambda q, k, v: pa.flash_attention(q, k, v, True, 512, 512, window))
+    text = _compile(fn, q, kv, kv)
+    assert [x.shape for x in jax.eval_shape(fn, q, kv, kv)] == [q.shape, kv.shape, kv.shape]
+    for name in pa.KERNEL_NAMES:
+        assert f"%{name}" in text, name
